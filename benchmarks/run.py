@@ -7,6 +7,7 @@
 import argparse
 import json
 import sys
+import traceback
 
 
 def _bench_config(quick: bool):
@@ -52,6 +53,9 @@ def main() -> None:
                          "(e.g. BENCH_pr4.json)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (bench_batch, bench_bfs, bench_kernels,
                             bench_phase1, bench_recovery, bench_service,
                             bench_spectral, fig5_linearity, roofline,
@@ -72,14 +76,17 @@ def main() -> None:
     }
     chosen = (args.only.split(",") if args.only else list(suites))
     all_rows = []
+    failed = []
     print("name,us_per_call,derived")
     for name in chosen:
         try:
             rows = suites[name](quick=args.quick)
-        except Exception as e:  # report but keep the suite going
+        except Exception as e:  # report, run the other suites, fail at exit
+            traceback.print_exc()
             print(f"{name}.ERROR,0,{e!r}", file=sys.stdout)
             all_rows.append({"name": f"{name}.ERROR", "us_per_call": 0.0,
                              "derived": repr(e)})
+            failed.append(name)
             continue
         for row in rows:
             n, us, derived = row
@@ -91,6 +98,8 @@ def main() -> None:
         with open(args.json, "w") as f:
             json.dump(doc, f, indent=2)
         print(f"wrote {args.json}", file=sys.stderr)
+    if failed:
+        sys.exit(f"failed suites: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

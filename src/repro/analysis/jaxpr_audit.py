@@ -2,7 +2,7 @@
 
 Traces each public jit program (`jax.make_jaxpr` — no compile, no
 device) over the bucket signatures `SparsifyService` actually serves,
-then walks the closed jaxpr (recursively through pjit / while / scan /
+then walks the closed jaxpr (recursively through jit / while / scan /
 cond sub-jaxprs) and asserts the pipeline's contracts:
 
   * **one dispatch** — the program traces as a single closed jaxpr with
@@ -41,6 +41,7 @@ import dataclasses
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core as jcore
 import jax.numpy as jnp
 import numpy as np
 
@@ -55,7 +56,8 @@ from repro.analysis.ranges import (
 # Host-transition primitives: any of these inside a "single dispatch"
 # program means the dispatch is not actually single.
 FORBIDDEN_PRIMITIVES = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "infeed", "outfeed", "host_callback_call", "outside_call",
 })
 
@@ -81,13 +83,13 @@ def _sub_jaxprs(eqn) -> Iterable[Any]:
     for v in eqn.params.values():
         vs = v if isinstance(v, (list, tuple)) else [v]
         for sub in vs:
-            if isinstance(sub, (jax.core.ClosedJaxpr, jax.core.Jaxpr)):
+            if isinstance(sub, (jcore.ClosedJaxpr, jcore.Jaxpr)):
                 yield sub
 
 
 def collect_eqns(closed_or_jaxpr) -> List[Any]:
     """Every equation of the program, recursively through all
-    sub-jaxprs (pjit bodies, while cond/body, scan body, cond branches)."""
+    sub-jaxprs (jit bodies, while cond/body, scan body, cond branches)."""
     jx = getattr(closed_or_jaxpr, "jaxpr", closed_or_jaxpr)
     out: List[Any] = []
 

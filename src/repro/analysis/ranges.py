@@ -38,6 +38,7 @@ import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core as jcore
 import jax.numpy as jnp
 import numpy as np
 
@@ -238,7 +239,7 @@ class _Env:
         self.eq_facts: Dict[Any, Tuple[Any, int]] = {}
 
     def read(self, atom) -> Interval:
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, jcore.Literal):
             return _const_interval(atom.val)
         return self.vals.get(atom, Interval.top())
 
@@ -279,12 +280,12 @@ def _refine_select(env: _Env, eqn) -> Optional[Interval]:
     return branches[0].union(branches[1])
 
 
-def propagate(closed_jaxpr: jax.core.ClosedJaxpr,
+def propagate(closed_jaxpr: jcore.ClosedJaxpr,
               seeds: Sequence[Interval]) -> List[RangeFinding]:
     """Walk `closed_jaxpr` with input intervals `seeds` (one per invar,
     Interval.top() for "unknown"); return every provable range finding.
 
-    Sub-jaxprs of inlined jits (pjit) and custom_jvp wrappers are
+    Sub-jaxprs of inlined jits (the `jit` primitive) and custom_jvp wrappers are
     recursed into with their operand intervals; loop bodies (while /
     scan / cond) are NOT — their carries are TOP by construction, so
     in-loop invariants need dedicated witness programs.
@@ -301,11 +302,11 @@ def _inner_eq_facts(env: _Env, outer_atoms, inner_vars) -> Dict:
     """Translate eq-against-constant facts across a call boundary:
     when both the predicate and its operand are passed into the
     sub-jaxpr, rebind the fact onto the callee's invars (jnp.where
-    lowers its select_n inside a pjit, so refinement must follow)."""
+    lowers its select_n inside a jit, so refinement must follow)."""
     pos = {id(a): i for i, a in enumerate(outer_atoms)}
     facts = {}
     for i, atom in enumerate(outer_atoms):
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, jcore.Literal):
             continue
         fact = env.eq_facts.get(atom)
         if fact is None:
@@ -418,14 +419,14 @@ def _propagate_open(jaxpr, const_ivs, seed_ivs, findings, counter,
                                        (eqn.invars[1], eqn.invars[0])):
                     kiv = env.read(k_atom)
                     if not kiv.unknown and kiv.lo == kiv.hi \
-                            and not isinstance(x_atom, jax.core.Literal):
+                            and not isinstance(x_atom, jcore.Literal):
                         env.eq_facts[eqn.outvars[0]] = (x_atom, kiv.lo)
                         break
-        elif name in ("pjit", "closed_call", "custom_jvp_call",
+        elif name in ("jit", "closed_call", "custom_jvp_call",
                       "custom_vjp_call", "remat", "checkpoint"):
             sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
             if sub is not None:
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jcore.ClosedJaxpr):
                     inner = sub.jaxpr
                     facts = _inner_eq_facts(env, eqn.invars, inner.invars)
                     outs = _propagate_open(

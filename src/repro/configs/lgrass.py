@@ -1,9 +1,13 @@
 """The paper's own workload: LGRASS graph sparsification cases.
 
 Each "shape" is a graph size; the dry-run lowers the distributed phase-1
-(repro.core.distributed) over the production mesh for each case.
+(repro.core.distributed) over the production mesh for each case. Sizes
+come from the generators' own shape functions, so they are what
+`official_case` and `powergrid_like_graph` actually produce.
 """
 import dataclasses
+
+from repro.core.graph import OFFICIAL_CASE_SHAPES, powergrid_shape
 
 
 @dataclasses.dataclass(frozen=True)
@@ -13,9 +17,15 @@ class GraphCase:
     n_edges: int
 
 
+def _grid_case(name: str, n_side: int, chord_frac: float,
+               seed: int = 0) -> GraphCase:
+    return GraphCase(name, *powergrid_shape(n_side, chord_frac))
+
+
 CASES = {
-    "case1_4k": GraphCase("case1_4k", 4_096, 13_056),
-    "case2_7k": GraphCase("case2_7k", 7_056, 22_344),
-    "case3_16k": GraphCase("case3_16k", 16_129, 51_200),
-    "rand_1m": GraphCase("rand_1m", 1_048_576, 3_145_728),
+    "case1_4k": _grid_case("case1_4k", **OFFICIAL_CASE_SHAPES["case1"]),
+    "case2_7k": _grid_case("case2_7k", **OFFICIAL_CASE_SHAPES["case2"]),
+    "case3_16k": _grid_case("case3_16k", **OFFICIAL_CASE_SHAPES["case3"]),
+    # powergrid_like_graph(1024, 0.25): the 10^6-node grid
+    "grid_1m": _grid_case("grid_1m", n_side=1024, chord_frac=0.25),
 }

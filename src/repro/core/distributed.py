@@ -27,9 +27,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.lca import LiftingTables, lca
 from repro.core.marking import _ball_pair_covered
 
@@ -47,7 +46,7 @@ def batch_mesh(n_devices: int | None = None, axis: str = "batch") -> Mesh:
     n = len(devs) if n_devices is None else int(n_devices)
     if n > len(devs):
         raise ValueError(f"batch_mesh({n}) but only {len(devs)} devices")
-    return compat.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def mesh_size(mesh: Mesh) -> int:
@@ -151,7 +150,7 @@ def _local_lockstep(up, depth, su, sv, sbeta, gstart, gsize, active, k_cap,
         # under shard_map the carries become device-varying on first write;
         # the initial values must carry the same varying type.
         acc_u, acc_v, acc_b, cnt, ovf, out = jax.tree.map(
-            lambda a: compat.pvary(a, vary_axes),
+            lambda a: jax.lax.pcast(a, vary_axes, to="varying"),
             (acc_u, acc_v, acc_b, cnt, ovf, out),
         )
 
@@ -210,7 +209,7 @@ def make_phase1_sharded(mesh: Mesh, shard_axes: Tuple[str, ...], k_cap: int = 32
         )
 
     return jax.jit(
-        compat.shard_map_unchecked(
+        jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(spec_r, spec_r, spec_e, spec_e, spec_e, spec_e, spec_e,
@@ -245,7 +244,7 @@ def lgrass_phase1_distributed(g, mesh: Mesh, shard_axes=("data",),
     sbeta = jnp.asarray(d["beta"][eid], jnp.int32)
     act = jnp.asarray(plan.slot_edge >= 0)
     fn = make_phase1_sharded(mesh, tuple(shard_axes), k_cap)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, ovf = fn(
             jnp.asarray(d["up"]),
             jnp.asarray(d["depth_t"]),

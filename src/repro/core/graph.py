@@ -273,11 +273,18 @@ def feeder_like_graph(
     return g
 
 
+def powergrid_shape(n_side: int, chord_frac: float = 0.25) -> Tuple[int, int]:
+    """(nodes, edges) of `powergrid_like_graph(n_side, chord_frac)`,
+    without generating it: the grid's 2·s·(s−1) edges plus its chords."""
+    n = n_side * n_side
+    return n, 2 * n_side * (n_side - 1) + int(chord_frac * n)
+
+
 def powergrid_like_graph(n_side: int, chord_frac: float = 0.25,
                          seed: int = 0) -> Graph:
     """2-D grid (power-grid-ish topology, as in the IPCC cases) + chords."""
     rng = np.random.default_rng(seed)
-    n = n_side * n_side
+    n, m = powergrid_shape(n_side, chord_frac)
     idx = np.arange(n).reshape(n_side, n_side)
     hu = idx[:, :-1].ravel()
     hv = idx[:, 1:].ravel()
@@ -286,7 +293,7 @@ def powergrid_like_graph(n_side: int, chord_frac: float = 0.25,
     u = np.concatenate([hu, vu])
     v = np.concatenate([hv, vv])
     existing = set(zip(np.minimum(u, v).tolist(), np.maximum(u, v).tolist()))
-    n_chords = int(chord_frac * n)
+    n_chords = m - len(u)
     cu, cv = [], []
     while len(cu) < n_chords:
         x, y = int(rng.integers(0, n)), int(rng.integers(0, n))

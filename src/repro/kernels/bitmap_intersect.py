@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 
 def _bitmap_kernel(m1_ref, m2_ref, out_ref):
@@ -40,7 +39,7 @@ def bitmap_intersect_any(m1: jax.Array, m2: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((l,), jnp.bool_),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(m1, m2)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NB = 256
 
@@ -73,7 +72,7 @@ def bucket_rank_hist(digits: jax.Array, *, chunk: int = 1024,
             jax.ShapeDtypeStruct((NB,), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((NB,), jnp.int32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(digits)

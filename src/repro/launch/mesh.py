@@ -10,9 +10,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro import compat
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 TP_SIZE = 16  # 'model' axis extent on both meshes
 
@@ -20,13 +18,13 @@ TP_SIZE = 16  # 'model' axis extent on both meshes
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> Mesh:
     """Whatever devices exist, as a 1-D 'data' mesh (CPU tests, examples)."""
     n = len(jax.devices())
-    return compat.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
 
 
 def batch_axes_for(global_batch: int, mesh: Mesh):

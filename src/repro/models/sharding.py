@@ -70,9 +70,7 @@ def use_mesh(mesh: Optional[Mesh]):
     _state.mesh = mesh
     try:
         if mesh is not None:
-            from repro import compat
-
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 yield
         else:
             yield

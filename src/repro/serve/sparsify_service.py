@@ -56,8 +56,8 @@ The serving plane on top of the bucketing (PR 6):
   * **batch-axis sharding** (`mesh=...`) — `lgrass_device_batched` is
     embarrassingly parallel over its leading (graph) axis, so a chunk's
     batch axis is sharded across the mesh
-    (`core.distributed.shard_batch_leading`, built on the
-    `repro.compat` shims); one pod serves one mega-bucket. The batch
+    (`core.distributed.shard_batch_leading`); one pod serves one
+    mega-bucket. The batch
     pad target rounds up to a multiple of the mesh size so every shard
     gets equal rows.
   * **on-path compile accounting** — every dispatch signature

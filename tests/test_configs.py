@@ -74,3 +74,23 @@ def test_param_counts_match_nameplates():
     for name, plate in plates.items():
         got = ARCHS[name].n_params()
         assert abs(got - plate) / plate < 0.15, (name, got, plate)
+
+
+@pytest.mark.parametrize("case,name", [("case1_4k", "case1"),
+                                       ("case2_7k", "case2"),
+                                       ("case3_16k", "case3")])
+def test_lgrass_cases_match_generator(case, name):
+    """The config's sizes are what official_case() generates."""
+    from repro.configs.lgrass import CASES
+    from repro.core.graph import official_case
+
+    g = official_case(name)
+    assert (CASES[case].n_nodes, CASES[case].n_edges) == (g.n, g.m)
+
+
+@pytest.mark.parametrize("side,frac", [(5, 0.3), (17, 0.25), (40, 0.2)])
+def test_powergrid_shape_matches_generator(side, frac):
+    from repro.core.graph import powergrid_like_graph, powergrid_shape
+
+    g = powergrid_like_graph(side, frac, seed=side)
+    assert powergrid_shape(side, frac) == (g.n, g.m)

@@ -93,8 +93,7 @@ def test_elastic_remesh_and_compressed_psum():
         mesh = jax.make_mesh((8,), ('d',))
         xs = np.random.default_rng(0).standard_normal((8, 64)).astype(
             np.float32)
-        from repro.compat import shard_map
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda a: compressed_psum(a[0], 'd')[None],
             mesh=mesh, in_specs=P('d'), out_specs=P('d')))
         got = np.asarray(f(xs))[0]
@@ -111,12 +110,13 @@ def test_reduced_mesh_dryrun_machinery():
     an 8-device (2,2,2) pod/data/model mesh for two architectures."""
     out = _run("""
         import jax, jax.numpy as jnp
-        from repro import compat
+        from jax.sharding import AxisType
         import repro.launch.mesh as M
         # shrink the production mesh for the 8-device CI environment
-        M.make_production_mesh = lambda multi_pod=False: compat.make_mesh(
+        M.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
             (2, 2, 2) if multi_pod else (4, 2),
-            ('pod', 'data', 'model') if multi_pod else ('data', 'model'))
+            ('pod', 'data', 'model') if multi_pod else ('data', 'model'),
+            axis_types=(AxisType.Auto,) * (3 if multi_pod else 2))
         from repro.launch import dryrun
         import repro.launch.dryrun as D
         rec1 = D.run_cell('mamba2-370m', 'train_4k', True, '/tmp/ci_dry',

@@ -222,3 +222,21 @@ def test_spmv_kernel_through_estimator():
                                          n_probes=32, n_iters=32, seed=1,
                                          use_spmv_kernel=True))
     np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("kernel", ["tree_dist", "spmv"])
+def test_kernel_vmem_guard_raises_at_trace_time(kernel):
+    """A size past the kernel's VMEM regime is refused while tracing,
+    with a message naming the kernel — never handed to the compiler."""
+    i32 = jax.ShapeDtypeStruct((256,), jnp.int32)
+    if kernel == "tree_dist":
+        n = 1 << 17
+        args = (jax.ShapeDtypeStruct((18, n), jnp.int32),
+                jax.ShapeDtypeStruct((n,), jnp.int32), i32, i32)
+        fn = ops.tree_dist_pairs
+    else:
+        args = (i32, i32, jax.ShapeDtypeStruct((256,), jnp.float32),
+                jax.ShapeDtypeStruct((1 << 16, 64), jnp.float32))
+        fn = ops.laplacian_spmv_edges
+    with pytest.raises(ValueError, match=fn.__name__):
+        jax.eval_shape(lambda *a: fn(*a, interpret=False), *args)

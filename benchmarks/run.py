@@ -17,7 +17,7 @@ def _bench_config(quick: bool):
     NOT pin an engine — the configuration every default-path row (e.g.
     the e2e recovery rows) ran under. Rows that deliberately pin a
     different engine (bench_phase1's scan_basic/scan_parallel/lifting
-    rows, fig5's scan schedule, table2's k_cap=8 probe) say so in their
+    rows, fig5's scan schedule) say so in their
     name or `derived` field; those annotations, not this block, are
     authoritative for such rows.
     """
@@ -46,7 +46,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="small sizes (CI)")
     ap.add_argument("--only", default=None,
-                    help="comma list: table3,table2,fig5,kernels,roofline,"
+                    help="comma list: table3,fig5,kernels,roofline,"
                          "batch,recovery,phase1,bfs,service,spectral")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows + config as JSON "
@@ -59,11 +59,10 @@ def main() -> None:
     from benchmarks import (bench_batch, bench_bfs, bench_kernels,
                             bench_phase1, bench_recovery, bench_service,
                             bench_spectral, fig5_linearity, roofline,
-                            table2_breakdown, table3_execution_time)
+                            table3_execution_time)
 
     suites = {
         "table3": table3_execution_time.run,
-        "table2": table2_breakdown.run,
         "fig5": fig5_linearity.run,
         "kernels": bench_kernels.run,
         "roofline": roofline.run,

@@ -1,0 +1,10 @@
+"""mst_ms.batch: device ms per batch call of the MST stage's operations
+(effective-weight rank sort and Borůvka spanning tree) in the traced window, read through the
+program's stage scopes (stages.py)."""
+from chipbench import stages
+
+prepare = stages.prepare
+
+
+def read(run):
+    return stages.stage_ms(run, "MST", per_graph=False)

@@ -1,0 +1,10 @@
+"""rec_ms.single: device ms per graph of the REC stage's operations
+(recovery replay and the output reductions) in the traced window, read through the
+program's stage scopes (stages.py)."""
+from chipbench import stages
+
+prepare = stages.prepare
+
+
+def read(run):
+    return stages.stage_ms(run, "REC", per_graph=True)

@@ -67,8 +67,10 @@ _WIDE_DTYPES = ("float64", "int64", "uint64", "complex128")
 # The documented while-loop budget per program family × BFS engine
 # (schedule-independent; `parallel` uses a while in both engines):
 #   phase-1 = graph BFS (1 while doubling / 2 while levels for the two
-#   passes) + Borůvka rounds (1) + MARK scheduler (1) + group-layout
-#   compaction (1); the fused program adds the recovery outer loop (1).
+#   passes) + Borůvka rounds (1) + the pointer jumping of each round's
+#   contraction (1, nested) + MARK scheduler (1); the fused program adds
+#   the recovery outer loop (1). Each counts its rounds in its carry
+#   (`core.sparsify.LOOPS`).
 EXPECTED_WHILE: Dict[Tuple[str, str], int] = {
     ("phase1", "doubling"): 4,
     ("phase1", "levels"): 5,

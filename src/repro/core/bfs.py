@@ -114,11 +114,23 @@ def bfs(
         depth:  (n,) int32, INF for unreachable.
         parent: (n,) int32, -1 for root / unreachable.
     """
+    return bfs_counted(u, v, n, root, edge_mask, engine)[:2]
+
+
+def bfs_counted(
+    u: jax.Array,
+    v: jax.Array,
+    n: int,
+    root: jax.Array,
+    edge_mask: Optional[jax.Array] = None,
+    engine: str = "doubling",
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """`bfs` plus the int32 number of rounds its while loop ran."""
     if engine == "doubling":
-        return bfs_doubling(u, v, n, root, edge_mask)
+        return _bfs_doubling(u, v, n, root, edge_mask)
     if engine != "levels":
         raise ValueError(f"unknown BFS engine {engine!r}")
-    return bfs_levels(u, v, n, root, edge_mask)
+    return _bfs_levels(u, v, n, root, edge_mask)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -130,6 +142,11 @@ def bfs_levels(
     edge_mask: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Level-synchronous BFS: one edge-parallel relaxation per level."""
+    return _bfs_levels(u, v, n, root, edge_mask)[:2]
+
+
+def _bfs_levels(u, v, n, root, edge_mask):
+    """`bfs_levels` plus its round count."""
     src = jnp.concatenate([u, v])
     dst = jnp.concatenate([v, u])
     if edge_mask is not None:
@@ -156,10 +173,11 @@ def bfs_levels(
         depth = jnp.where(newly, level + 1, depth)
         return depth, parent, newly, level + 1
 
-    depth, parent, _, _ = jax.lax.while_loop(
+    # the level counter is the round count
+    depth, parent, _, rounds = jax.lax.while_loop(
         cond, body, (depth0, parent0, frontier0, jnp.int32(0))
     )
-    return depth, parent
+    return depth, parent, rounds
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -213,6 +231,11 @@ def bfs_doubling(
     parent[v] = smallest-id neighbour u with depth[u] == depth[v] - 1 —
     exactly the level-sync rule, evaluated on exact depths.
     """
+    return _bfs_doubling(u, v, n, root, edge_mask)[:2]
+
+
+def _bfs_doubling(u, v, n, root, edge_mask):
+    """`bfs_doubling` plus its round count."""
     src = jnp.concatenate([u, v])
     dst = jnp.concatenate([v, u])
     if edge_mask is not None:
@@ -264,7 +287,7 @@ def bfs_doubling(
         return mnb, wit
 
     def body(state):
-        dist, pl, ol, pr, orr, _ = state
+        dist, pl, ol, pr, orr, _, rounds = state
         d_in = dist
         # edge-parallel relaxation + climb re-anchor, one scatter-min
         mnb, wit = relax_witness(dist)
@@ -286,13 +309,14 @@ def bfs_doubling(
             dist = pull(dist, jmp, joff)
             joff = jnp.minimum(joff + joff[jmp], nn)
             jmp = jmp[jmp]
-        return dist, pl, ol, pr, orr, jnp.any(dist != d_in)
+        return dist, pl, ol, pr, orr, jnp.any(dist != d_in), rounds + 1
 
     def cond(state):
-        return state[-1]
+        return state[-2]
 
-    dist, *_ = jax.lax.while_loop(
-        cond, body, (dist0, pl0, ol0, pr0, or0, jnp.bool_(True))
+    dist, *_, rounds = jax.lax.while_loop(
+        cond, body,
+        (dist0, pl0, ol0, pr0, or0, jnp.bool_(True), jnp.int32(0))
     )
 
     # one edge-parallel pass: smallest-id neighbour one level up
@@ -302,7 +326,7 @@ def bfs_doubling(
         jnp.where(prev, src, INF)
     )
     parent = jnp.where((dist > 0) & (dist < INF) & (cand < INF), cand, -1)
-    return dist, parent.astype(jnp.int32)
+    return dist, parent.astype(jnp.int32), rounds
 
 
 def _euler_tables(tour: jax.Array, T: jax.Array, depth: jax.Array,

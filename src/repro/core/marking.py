@@ -267,12 +267,14 @@ def _ball_pair_covered(
 class Phase1Result(NamedTuple):
     accept: jax.Array          # (L,) bool — per *sorted slot*
     group_overflow: jax.Array  # (L,) bool — per dense group index
+    rounds: jax.Array          # int32 — steps of the schedule's loop
 
 
 def _empty_phase1() -> "Phase1Result":
     """The L == 0 result (isolated-node graphs; see build_group_layout)."""
     return Phase1Result(accept=jnp.zeros((0,), bool),
-                        group_overflow=jnp.zeros((0,), bool))
+                        group_overflow=jnp.zeros((0,), bool),
+                        rounds=jnp.int32(0))
 
 
 @jax.jit
@@ -346,7 +348,8 @@ def phase1_basic(
     (acc_u, acc_v, acc_b, cnt, ovf), accept = jax.lax.scan(
         step, (acc_u, acc_v, acc_b, cnt, ovf), jnp.arange(m, dtype=jnp.int32)
     )
-    return Phase1Result(accept=accept, group_overflow=ovf)
+    return Phase1Result(accept=accept, group_overflow=ovf,
+                        rounds=jnp.int32(m))
 
 
 @functools.partial(jax.jit, static_argnames=("k_cap",))
@@ -413,10 +416,10 @@ def phase1_parallel(
         out = out.at[write_i].set(accept, mode="drop")
         return r + 1, acc_u, acc_v, acc_b, cnt, ovf, out
 
-    _, acc_u, acc_v, acc_b, cnt, ovf, out = jax.lax.while_loop(
+    rounds, acc_u, acc_v, acc_b, cnt, ovf, out = jax.lax.while_loop(
         cond, body, (jnp.int32(0), acc_u, acc_v, acc_b, cnt, ovf, out)
     )
-    return Phase1Result(accept=out, group_overflow=ovf)
+    return Phase1Result(accept=out, group_overflow=ovf, rounds=rounds)
 
 
 @functools.partial(jax.jit,
@@ -528,8 +531,8 @@ def phase1_chunked(
         jnp.zeros((m,), bool),
         jnp.zeros((n_blocks * c,), bool),
     )
-    _, _, _, _, _, ovf, out = jax.lax.while_loop(cond, body, init)
-    return Phase1Result(accept=out[:m], group_overflow=ovf)
+    rounds, _, _, _, _, ovf, out = jax.lax.while_loop(cond, body, init)
+    return Phase1Result(accept=out[:m], group_overflow=ovf, rounds=rounds)
 
 
 def run_phase1(

@@ -41,24 +41,32 @@ def boruvka_mst(
     padding edges are never inter-component candidates, so they can never
     enter the tree, and the termination test ignores them.
     """
+    return boruvka_mst_counted(u, v, rank, n, edge_valid)[0]
+
+
+def boruvka_mst_counted(u, v, rank, n, edge_valid=None):
+    """`boruvka_mst` plus two int32 round counts: the Borůvka rounds, and
+    the pointer-jumping rounds of their contractions, summed."""
     if edge_valid is None:
         edge_valid = jnp.ones_like(u, dtype=bool)
 
-    def pointer_jump(ptr):
-        def cond(p):
+    def pointer_jump(ptr, jumps):
+        def cond(state):
+            p, _ = state
             return jnp.any(p[p] != p)
 
-        def body(p):
-            return p[p]
+        def body(state):
+            p, k = state
+            return p[p], k + 1
 
-        return jax.lax.while_loop(cond, body, ptr)
+        return jax.lax.while_loop(cond, body, (ptr, jumps))
 
     def round_cond(state):
-        comp, _ = state
+        comp = state[0]
         return jnp.any((comp[u] != comp[v]) & edge_valid)
 
     def round_body(state):
-        comp, tree_mask = state
+        comp, tree_mask, rounds, jumps = state
         cu, cv = comp[u], comp[v]
         inter = (cu != cv) & edge_valid
         key = jnp.where(inter, rank, INF)
@@ -76,13 +84,15 @@ def boruvka_mst(
         ids = jnp.arange(n, dtype=jnp.int32)
         mutual = (ptr[ptr] == ids) & (ptr != ids)
         ptr = jnp.where(mutual & (ids < ptr), ids, ptr)
-        ptr = pointer_jump(ptr)
-        return ptr[comp], tree_mask
+        ptr, jumps = pointer_jump(ptr, jumps)
+        return ptr[comp], tree_mask, rounds + 1, jumps
 
     comp0 = jnp.arange(n, dtype=jnp.int32)
     mask0 = jnp.zeros_like(u, dtype=bool)
-    _, tree_mask = jax.lax.while_loop(round_cond, round_body, (comp0, mask0))
-    return tree_mask
+    zero = jnp.int32(0)
+    _, tree_mask, rounds, jumps = jax.lax.while_loop(
+        round_cond, round_body, (comp0, mask0, zero, zero))
+    return tree_mask, rounds, jumps
 
 
 def kruskal_mst_numpy(u, v, rank, n):

@@ -194,17 +194,25 @@ def _recover_scan(
     loop runs the union of the lanes' needed blocks, with finished
     lanes' carries frozen by the batching rule.
 
-    Returns (accepted (L,) bool, n_accepted int32).
+    The loop also stops after the last block that holds an off-tree
+    edge: later blocks hold tree and padding slots only, which decide
+    nothing.
+
+    Returns (accepted (L,) bool, n_accepted int32, rounds int32: blocks
+    the outer loop ran).
     """
     L = u.shape[0]
     if L == 0:  # isolated-node graph: nothing to replay
-        return jnp.zeros((0,), bool), jnp.int32(0)
+        return jnp.zeros((0,), bool), jnp.int32(0), jnp.int32(0)
     budget = jnp.minimum(jnp.asarray(budget, jnp.int32), jnp.int32(b_cap))
     c = max(min(chunk, L), 1)
     n_blocks = -(-L // c)
     order_pad = block_view(order.astype(jnp.int32), c, 0)
     svalid_pad = block_view(jnp.ones((L,), bool), c, False)
     occ_iota = jnp.arange(b_cap, dtype=jnp.int32)
+    last_slot = jnp.max(jnp.where(offtree[order],
+                                  jnp.arange(L, dtype=jnp.int32), -1))
+    blocks_needed = jnp.minimum(last_slot // c + 1, n_blocks)
 
     def inner(carry, xs):
         buf_u, buf_v, buf_b, buf_nc, buf_idx, cnt, gflag, out = carry
@@ -245,7 +253,7 @@ def _recover_scan(
 
     def cond(state):
         blk, _, _, _, _, cnt, _, _ = state
-        return (blk < n_blocks) & (cnt < budget)
+        return (blk < blocks_needed) & (cnt < budget)
 
     def outer(state):
         blk, buf_u, buf_v, buf_b, buf_nc, cnt, gflag, out = state
@@ -279,8 +287,8 @@ def _recover_scan(
         jnp.zeros((L + 1,), bool),             # per-group flip flag
         jnp.zeros((L,), bool),                 # out
     )
-    _, _, _, _, _, cnt, _, out = jax.lax.while_loop(cond, outer, init)
-    return out, cnt
+    rounds, _, _, _, _, cnt, _, out = jax.lax.while_loop(cond, outer, init)
+    return out, cnt, rounds
 
 
 def _euler_from_lifting(up: jax.Array, depth_t: jax.Array):
@@ -345,7 +353,7 @@ def recover_device(
         t, u, v, beta, offtree, crossing, order, phase1_accept,
         group_of_edge, dirty0, jnp.asarray(budget, jnp.int32), b_cap,
         use_tree_kernel, chunk, euler,
-    )
+    )[:2]
 
 
 @functools.partial(jax.jit,
@@ -389,7 +397,7 @@ def recover_device_batched(
         return _recover_scan(
             t, bu, bv, bbeta, (~btree) & bev, bcross, border, bacc, bgrp,
             bdirty, bb, b_cap, use_tree_kernel, chunk, euler,
-        )
+        )[:2]
 
     if edge_valid is None:  # all-true mask ≡ the unmasked offtree
         edge_valid = jnp.ones_like(tree_mask, dtype=bool)

@@ -14,12 +14,21 @@ single dispatch, and `lgrass_device_batched` vmaps it over a padded
 graph batch, so the serving path never syncs to host between phases.
 The host recovery tail (`recovery.recover_host`) is retained as the
 fidelity oracle behind `recovery="host"`.
+
+Measurement lives inside the program. Every operation of the device
+programs sits in one `jax.named_scope` per stage (`STAGES`), so the
+compiled HLO's `op_name` metadata, and a profiler trace through it,
+name the stage of each device op. Each while loop counts its own rounds
+in its carry; the programs return the counts as one int32 vector in
+`LOOPS` order (`SparsifyResult.loop_rounds`). `lgrass_sparsify` marks
+its host phases with `jax.profiler.TraceAnnotation` spans (`lgrass.*`),
+which a profiler records on the device planes' clock.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +38,7 @@ from repro.core import _host as H
 from repro.core.baseline import default_budget
 from repro.core.bfs import (
     bfs,
+    bfs_counted,
     effective_weights,
     finite_depth,
     root_tree_euler,
@@ -52,7 +62,7 @@ from repro.core.marking import (
     phase1_parallel,
     run_phase1,
 )
-from repro.core.mst import boruvka_mst
+from repro.core.mst import boruvka_mst_counted
 from repro.core.pow2 import log2_ceil, next_pow2
 from repro.core.recovery import _recover_scan, recover_host
 from repro.core.resistance import (
@@ -65,6 +75,26 @@ from repro.core.sort import sort_f32_desc_stable
 # Device recovery holds accepted edges in a (b_cap,) buffer; b_cap is a
 # compiled constant, so small budgets share one bucketed program.
 B_CAP_FLOOR = 8
+
+# The paper's stages, one `jax.named_scope` each, in pipeline order.
+STAGES = ("EFF", "MST", "LCA", "RES", "SORT", "MARK", "REC")
+
+# The device programs' while loops, in the order of their `loop_rounds`
+# vector: the graph BFS (EFF); the tree BFS (LCA, "levels" engine only,
+# 0 under "doubling"); the Borůvka rounds and the pointer-jumping rounds
+# of their contractions, summed (MST); the MARK schedule's steps; the
+# recovery replay's blocks (REC, 0 where the host replays). Each loop
+# but "mst_jump", which runs inside "mst", is called under a named
+# scope of its own name, so its `while` instruction's op_name ends in
+# "<loop>/while" (jit(...) components may come between).
+LOOPS = ("bfs", "tree", "mst", "mst_jump", "mark", "rec")
+
+
+def _loop_vector(rounds: dict) -> jax.Array:
+    """(len(LOOPS),) int32 round counts; loops absent from `rounds` did
+    not run."""
+    return jnp.stack([jnp.asarray(rounds.get(k, 0), jnp.int32)
+                      for k in LOOPS])
 
 
 def _bucket_b_cap(budgets) -> int:
@@ -82,6 +112,8 @@ class SparsifyResult:
     n_groups: int
     n_overflow_groups: int
     n_dirty: int
+    # rounds each while loop of the device program ran, by `LOOPS` name
+    loop_rounds: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 def _phase1_program(
@@ -130,59 +162,74 @@ def _phase1_program(
     every backend, so full-pipeline parity is only promised where the
     pipeline is defined.
     """
-    root = select_root(u, v, n, edge_valid)
-    depth_g, _ = bfs(u, v, n, root, edge_mask=edge_valid,
-                     engine=bfs_engine)
-    eff = effective_weights(u, v, w, depth_g, n, edge_valid)
+    with jax.named_scope("EFF"):
+        root = select_root(u, v, n, edge_valid)
+        with jax.named_scope("bfs"):
+            depth_g, _, bfs_rounds = bfs_counted(u, v, n, root,
+                                                 edge_valid, bfs_engine)
+        eff = effective_weights(u, v, w, depth_g, n, edge_valid)
 
-    perm_eff = sort_f32_desc_stable(eff, valid=edge_valid)
-    rank_eff = (
-        jnp.zeros_like(perm_eff)
-        .at[perm_eff]
-        .set(jnp.arange(perm_eff.shape[0], dtype=jnp.int32))
-    )
-    tree_mask = boruvka_mst(u, v, rank_eff, n, edge_valid)
+    with jax.named_scope("MST"):
+        perm_eff = sort_f32_desc_stable(eff, valid=edge_valid)
+        rank_eff = (
+            jnp.zeros_like(perm_eff)
+            .at[perm_eff]
+            .set(jnp.arange(perm_eff.shape[0], dtype=jnp.int32))
+        )
+        with jax.named_scope("mst"):
+            tree_mask, mst_rounds, jump_rounds = boruvka_mst_counted(
+                u, v, rank_eff, n, edge_valid)
+    rounds = dict(bfs=bfs_rounds, mst=mst_rounds, mst_jump=jump_rounds)
 
     # the Pallas kernel path takes precedence inside ball_pair_table, so
     # skip the (then-unused) Euler build when it is selected. Built for
     # ANY schedule: the fused recovery replay consumes it too.
     want_euler = use_euler_lca and not use_tree_kernel
     euler = None
-    if bfs_engine == "doubling":
-        # exact O(log n) tree rooting via the Euler tour — the tree's
-        # depth/parent are unique, so no fixpoint iteration is needed;
-        # the rooted tour doubles as the O(1)-LCA tables (no second
-        # tour construction via build_euler)
-        depth_t, parent_t, euler = root_tree_euler(
-            u, v, n, root, tree_mask, with_euler=want_euler)
-    else:
-        depth_t, parent_t = bfs(u, v, n, root, edge_mask=tree_mask,
-                                engine=bfs_engine)
-        if want_euler:
-            euler = build_euler(parent_t, depth_t, root, n)
-    t = build_lifting(parent_t, depth_t, n, levels=lift_levels)
-    if euler is not None:
-        # O(1) gathers per edge instead of L-wide lifting climbs; the
-        # LCA of two reachable nodes is backend-independent, so every
-        # downstream value is bit-identical
-        elca = lca_euler(euler, u, v)
-    else:
-        elca = lca_with_shortcut(t, root, u, v)
-    inv_w = node_parent_inv_w(u, v, w, tree_mask, parent_t, n)
-    r = root_path_sums(t, inv_w)
-    crit = criticality(t, r, u, v, w, elca)
-    beta = jnp.maximum(
-        jnp.minimum(depth_t[u], depth_t[v]) - depth_t[elca], 1
-    ).astype(jnp.int32)
+    with jax.named_scope("LCA"):
+        if bfs_engine == "doubling":
+            # exact O(log n) tree rooting via the Euler tour — the tree's
+            # depth/parent are unique, so no fixpoint iteration is
+            # needed; the rooted tour doubles as the O(1)-LCA tables (no
+            # second tour construction via build_euler)
+            depth_t, parent_t, euler = root_tree_euler(
+                u, v, n, root, tree_mask, with_euler=want_euler)
+        else:
+            with jax.named_scope("tree"):
+                depth_t, parent_t, rounds["tree"] = bfs_counted(
+                    u, v, n, root, tree_mask, bfs_engine)
+            if want_euler:
+                euler = build_euler(parent_t, depth_t, root, n)
+        t = build_lifting(parent_t, depth_t, n, levels=lift_levels)
+        if euler is not None:
+            # O(1) gathers per edge instead of L-wide lifting climbs; the
+            # LCA of two reachable nodes is backend-independent, so every
+            # downstream value is bit-identical
+            elca = lca_euler(euler, u, v)
+        else:
+            elca = lca_with_shortcut(t, root, u, v)
 
-    is_offtree = ~tree_mask if edge_valid is None else (~tree_mask) & edge_valid
-    hi, lo, crossing = group_keys(t, root, u, v, elca, is_offtree)
-    layout = build_group_layout(crit, hi, lo, crossing, edge_valid)
-    su, sv, sbeta = u[layout.perm], v[layout.perm], beta[layout.perm]
-    p1 = run_phase1(t, su, sv, sbeta, layout, k_cap=k_cap,
-                    schedule=schedule, parallel=parallel, chunk=p1_chunk,
-                    use_tree_kernel=use_tree_kernel,
-                    euler=euler if schedule == "chunked" else None)
+    with jax.named_scope("RES"):
+        inv_w = node_parent_inv_w(u, v, w, tree_mask, parent_t, n)
+        r = root_path_sums(t, inv_w)
+        crit = criticality(t, r, u, v, w, elca)
+        beta = jnp.maximum(
+            jnp.minimum(depth_t[u], depth_t[v]) - depth_t[elca], 1
+        ).astype(jnp.int32)
+
+    with jax.named_scope("SORT"):
+        is_offtree = (~tree_mask if edge_valid is None
+                      else (~tree_mask) & edge_valid)
+        hi, lo, crossing = group_keys(t, root, u, v, elca, is_offtree)
+        layout = build_group_layout(crit, hi, lo, crossing, edge_valid)
+        su, sv, sbeta = u[layout.perm], v[layout.perm], beta[layout.perm]
+
+    with jax.named_scope("MARK"), jax.named_scope("mark"):
+        p1 = run_phase1(t, su, sv, sbeta, layout, k_cap=k_cap,
+                        schedule=schedule, parallel=parallel,
+                        chunk=p1_chunk, use_tree_kernel=use_tree_kernel,
+                        euler=euler if schedule == "chunked" else None)
+    rounds["mark"] = p1.rounds
     d = dict(
         tree_mask=tree_mask,
         parent_t=parent_t,
@@ -197,7 +244,7 @@ def _phase1_program(
         group_overflow=p1.group_overflow,
         n_groups=layout.n_groups,
     )
-    return d, euler
+    return d, euler, rounds
 
 
 @functools.partial(jax.jit,
@@ -220,12 +267,20 @@ def phase1_device(
 ):
     """The phase-1 device program: EFF→MST→LCA→RES→SORT→MARK.
 
-    Returns everything the host recovery tail needs. This function is the
-    unit the multi-pod dry-run lowers and compiles.
+    Returns everything the host recovery tail needs, and the phase-1
+    loops' `loop_rounds`. This function is the unit the multi-pod dry-run
+    lowers and compiles.
     """
-    d, _ = _phase1_program(u, v, w, n, k_cap, parallel, lift_levels, None,
+    return _phase1_outputs(u, v, w, n, k_cap, parallel, lift_levels, None,
                            schedule, p1_chunk, use_euler_lca,
                            use_tree_kernel, bfs_engine)
+
+
+def _phase1_outputs(*args):
+    """`_phase1_program`'s outputs with the round counts attached."""
+    d, _, rounds = _phase1_program(*args)
+    with jax.named_scope("MARK"):
+        d["loop_rounds"] = _loop_vector(rounds)
     return d
 
 
@@ -255,11 +310,11 @@ def phase1_device_batched(
     covers the whole batch — the amortisation the serving path needs.
     """
     return jax.vmap(
-        lambda bu, bv, bw, bev: _phase1_program(
+        lambda bu, bv, bw, bev: _phase1_outputs(
             bu, bv, bw, n, k_cap, parallel, lift_levels, bev,
             schedule, p1_chunk, use_euler_lca, use_tree_kernel,
             bfs_engine
-        )[0]
+        )
     )(u, v, w, edge_valid)
 
 
@@ -289,34 +344,42 @@ def _lgrass_program(
     host round-trip anywhere. Only scalars and the final masks leave the
     device.
     """
-    d, euler = _phase1_program(u, v, w, n, k_cap, parallel, lift_levels,
-                               edge_valid, schedule, p1_chunk,
-                               use_euler_lca, use_tree_kernel, bfs_engine)
+    d, euler, rounds = _phase1_program(
+        u, v, w, n, k_cap, parallel, lift_levels, edge_valid, schedule,
+        p1_chunk, use_euler_lca, use_tree_kernel, bfs_engine)
     t = LiftingTables(up=d["up"], depth=d["depth_t"])
     tree_mask = d["tree_mask"]
     crossing = d["crossing"]
-    accept_by_edge, group_of_edge, dirty0 = phase1_edge_views(
-        d["perm"], d["gidx"], d["accept_sorted"], d["group_overflow"],
-        crossing,
-    )
-    offtree = ~tree_mask if edge_valid is None else (~tree_mask) & edge_valid
-    keys = jnp.where(offtree, d["crit"], -jnp.inf)
-    order = sort_f32_desc_stable(keys)
-    accepted, n_accepted = _recover_scan(
-        t, u, v, d["beta"], offtree, crossing, order, accept_by_edge,
-        group_of_edge, dirty0, jnp.asarray(budget, jnp.int32), b_cap,
-        use_tree_kernel, chunk, euler,
-    )
-    depth_fin = finite_depth(d["depth_t"])
-    return dict(
-        tree_mask=tree_mask,
-        accepted=accepted,
-        n_accepted=n_accepted,
-        n_groups=d["n_groups"],
-        n_overflow_groups=jnp.sum(d["group_overflow"].astype(jnp.int32)),
-        n_dirty=jnp.sum(dirty0.astype(jnp.int32)),
-        tree_depth_max=jnp.max(depth_fin),
-    )
+    with jax.named_scope("MARK"):
+        accept_by_edge, group_of_edge, dirty0 = phase1_edge_views(
+            d["perm"], d["gidx"], d["accept_sorted"], d["group_overflow"],
+            crossing,
+        )
+    with jax.named_scope("SORT"):
+        offtree = (~tree_mask if edge_valid is None
+                   else (~tree_mask) & edge_valid)
+        keys = jnp.where(offtree, d["crit"], -jnp.inf)
+        order = sort_f32_desc_stable(keys)
+    with jax.named_scope("REC"):
+        with jax.named_scope("rec"):
+            accepted, n_accepted, rounds["rec"] = _recover_scan(
+                t, u, v, d["beta"], offtree, crossing, order,
+                accept_by_edge, group_of_edge, dirty0,
+                jnp.asarray(budget, jnp.int32), b_cap, use_tree_kernel,
+                chunk, euler,
+            )
+        depth_fin = finite_depth(d["depth_t"])
+        return dict(
+            tree_mask=tree_mask,
+            accepted=accepted,
+            n_accepted=n_accepted,
+            n_groups=d["n_groups"],
+            n_overflow_groups=jnp.sum(
+                d["group_overflow"].astype(jnp.int32)),
+            n_dirty=jnp.sum(dirty0.astype(jnp.int32)),
+            tree_depth_max=jnp.max(depth_fin),
+            loop_rounds=_loop_vector(rounds),
+        )
 
 
 @functools.partial(jax.jit,
@@ -344,8 +407,9 @@ def lgrass_device(
     """The full device program: phase 1 fused with the recovery replay.
 
     `budget` is a traced int32 scalar (one compile serves any budget up
-    to the static buffer bound `b_cap`). Returns final masks + scalar
-    stats only — the first point data leaves the device.
+    to the static buffer bound `b_cap`). Returns final masks, scalar
+    stats and the `loop_rounds` vector only — the first point data
+    leaves the device.
     """
     return _lgrass_program(u, v, w, budget, n, k_cap, parallel,
                            lift_levels, b_cap, None, use_tree_kernel, chunk,
@@ -388,7 +452,9 @@ lgrass_device_batched = jax.jit(
 lgrass_device_batched.__doc__ = (
     """`lgrass_device` vmapped over a padded batch: ONE dispatch runs
     phase 1 *and* recovery for every graph — no host round-trip between
-    phases. `budget` is a (B,) int32 vector (per-graph budgets)."""
+    phases. `budget` is a (B,) int32 vector (per-graph budgets).
+    `loop_rounds` is (B, len(LOOPS)): under vmap each lane's loop carry
+    stops at its own condition, so every row counts its own graph."""
 )
 
 # The serving plane's steady-state variant: the padded edge arrays and
@@ -416,7 +482,13 @@ def _result_from_device(d: dict, i: Optional[int], L: int) -> SparsifyResult:
         n_groups=int(pick(d["n_groups"])),
         n_overflow_groups=int(pick(d["n_overflow_groups"])),
         n_dirty=int(pick(d["n_dirty"])),
+        loop_rounds=_rounds_by_loop(pick(d["loop_rounds"])),
     )
+
+
+def _rounds_by_loop(row) -> Dict[str, int]:
+    """One graph's `loop_rounds` vector as {LOOPS name: rounds}."""
+    return dict(zip(LOOPS, np.asarray(row).astype(int).tolist()))
 
 
 def lgrass_sparsify(
@@ -464,17 +536,29 @@ def lgrass_sparsify(
     pow2 bucket of `budget` so nearby budgets share compiled programs.
     """
     n, L = g.n, g.m
-    if budget is None:
-        budget = default_budget(n)
-    budget = int(budget)
-    u = jnp.asarray(g.u, jnp.int32)
-    v = jnp.asarray(g.v, jnp.int32)
-    w = jnp.asarray(g.w, jnp.float32)
+    budget = default_budget(n) if budget is None else int(budget)
+    if recovery == "device":
+        fn, args, kwargs = lgrass_program(
+            g, budget, b_cap=b_cap, k_cap=k_cap, parallel=parallel,
+            use_tree_kernel=use_tree_kernel, chunk=chunk,
+            schedule=schedule, p1_chunk=p1_chunk,
+            use_euler_lca=use_euler_lca, bfs_engine=bfs_engine)
+    elif recovery == "host":
+        with jax.profiler.TraceAnnotation("lgrass.upload"):
+            args = (jnp.asarray(g.u, jnp.int32), jnp.asarray(g.v, jnp.int32),
+                    jnp.asarray(g.w, jnp.float32))
+        fn, kwargs = phase1_device, dict(
+            n=n, k_cap=k_cap, parallel=parallel, schedule=schedule,
+            p1_chunk=p1_chunk, use_euler_lca=use_euler_lca,
+            use_tree_kernel=use_tree_kernel, bfs_engine=bfs_engine)
+    else:
+        raise ValueError(f"unknown recovery mode {recovery!r}")
 
     lift_levels = None
     if auto_lift_bound:
         # estimate from graph BFS depth ×4 (tree paths stretch); the
         # post-hoc check below guarantees correctness regardless.
+        u, v = args[0], args[1]
         root = select_root(u, v, n)
         depth_g, _ = bfs(u, v, n, root, engine=bfs_engine)
         # finite_depth: unreachable (INF) depths must not inflate the
@@ -485,37 +569,57 @@ def lgrass_sparsify(
             safe += 1
         lift_levels = min(safe, log2_ceil(n + 1))
 
-    if recovery == "device":
-        if b_cap is None:
-            b_cap = _bucket_b_cap([budget])
-        if b_cap < budget:
-            raise ValueError(f"b_cap {b_cap} < budget {budget}")
-        d = jax.device_get(lgrass_device(
-            u, v, w, jnp.int32(budget), n, k_cap, parallel, lift_levels,
-            b_cap, use_tree_kernel, chunk, schedule, p1_chunk,
-            use_euler_lca, bfs_engine))
-        if lift_levels is not None:
-            if int(d["tree_depth_max"]) >= (1 << lift_levels):
-                d = jax.device_get(lgrass_device(
-                    u, v, w, jnp.int32(budget), n, k_cap, parallel, None,
-                    b_cap, use_tree_kernel, chunk, schedule, p1_chunk,
-                    use_euler_lca, bfs_engine))
+    kwargs["lift_levels"] = lift_levels
+    while True:
+        with jax.profiler.TraceAnnotation("lgrass.dispatch"):
+            out = fn(*args, **kwargs)
+        with jax.profiler.TraceAnnotation("lgrass.fetch"):
+            d = jax.device_get(out)
+        lift = kwargs["lift_levels"]
+        depth_max = (d["tree_depth_max"] if recovery == "device"
+                     else d["depth_t"].max())
+        if lift is None or int(depth_max) < (1 << lift):
+            break
+        kwargs["lift_levels"] = None  # bound violated: redo safely
+    if recovery == "host":
+        return _recovery_tail(g, d, budget)
+    with jax.profiler.TraceAnnotation("lgrass.unpack"):
         return _result_from_device(d, None, L)
-    if recovery != "host":
-        raise ValueError(f"unknown recovery mode {recovery!r}")
 
-    d = jax.device_get(phase1_device(u, v, w, n, k_cap, parallel,
-                                     lift_levels, schedule, p1_chunk,
-                                     use_euler_lca, use_tree_kernel,
-                                     bfs_engine))
-    if lift_levels is not None:
-        tree_dmax = int(d["depth_t"].max())
-        if tree_dmax >= (1 << lift_levels):  # bound violated: redo safely
-            d = jax.device_get(phase1_device(u, v, w, n, k_cap, parallel,
-                                             None, schedule, p1_chunk,
-                                             use_euler_lca,
-                                             use_tree_kernel, bfs_engine))
-    return _recovery_tail(g, d, budget)
+
+def lgrass_program(
+    g: Graph,
+    budget: Optional[int] = None,
+    b_cap: Optional[int] = None,
+    lift_levels: Optional[int] = None,
+    k_cap: int = 32,
+    parallel: bool = True,
+    use_tree_kernel: bool = False,
+    chunk: int = 32,
+    schedule: str = "chunked",
+    p1_chunk: Optional[int] = None,
+    use_euler_lca: bool = True,
+    bfs_engine: str = "doubling",
+):
+    """The fused dispatch `lgrass_sparsify(g, budget, ...)` makes with
+    recovery="device": (jitted program, device arguments, static keyword
+    arguments), the arguments uploaded under the `lgrass.upload` span.
+    `fn(*args, **kwargs)` runs the call's program, and
+    `fn.lower(*args, **kwargs).compile()` is that program compiled."""
+    budget = default_budget(g.n) if budget is None else int(budget)
+    if b_cap is None:
+        b_cap = _bucket_b_cap([budget])
+    if b_cap < budget:
+        raise ValueError(f"b_cap {b_cap} < budget {budget}")
+    with jax.profiler.TraceAnnotation("lgrass.upload"):
+        args = (jnp.asarray(g.u, jnp.int32), jnp.asarray(g.v, jnp.int32),
+                jnp.asarray(g.w, jnp.float32), jnp.int32(budget))
+    kwargs = dict(n=g.n, k_cap=k_cap, parallel=parallel,
+                  lift_levels=lift_levels, b_cap=b_cap,
+                  use_tree_kernel=use_tree_kernel, chunk=chunk,
+                  schedule=schedule, p1_chunk=p1_chunk,
+                  use_euler_lca=use_euler_lca, bfs_engine=bfs_engine)
+    return lgrass_device, args, kwargs
 
 
 def phase1_views_np(d: dict, L: int):
@@ -588,6 +692,7 @@ def _recovery_tail(g: Graph, d: dict, budget: int) -> SparsifyResult:
         n_groups=int(d["n_groups"]),
         n_overflow_groups=int(ovf_groups.sum()),
         n_dirty=int(dirty0.sum()),
+        loop_rounds=_rounds_by_loop(d["loop_rounds"]),
     )
 
 

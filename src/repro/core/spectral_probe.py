@@ -130,6 +130,7 @@ def _solve_cheby(spmv, dinv, y, n_iters: int, lam_min) -> jax.Array:
     jax.jit,
     static_argnames=("n", "n_probes", "n_iters", "method",
                      "use_spmv_kernel"))
+@jax.named_scope("PROBE")  # every op of the estimator, for the trace
 def _probe_er_program(
     u: jax.Array,
     v: jax.Array,

@@ -73,6 +73,12 @@ The serving plane on top of the bucketing (PR 6):
 Results come back in request order and are bit-identical to per-graph
 `lgrass_sparsify` under every mode — sync, async, donated, sharded
 (tests/test_batch.py, tests/test_service_plane.py).
+
+Measurement: the host phases of a request are
+`jax.profiler.TraceAnnotation` spans (`svc.bucket`, `svc.stage`,
+`svc.dispatch`, `svc.drain`), and `ServiceStats` sums the programs'
+per-graph loop round counts (`loop_rounds`) beside what the vmapped
+loops ran in lockstep (`loop_lane_rounds`).
 """
 from __future__ import annotations
 
@@ -91,6 +97,7 @@ from repro.core.graph import (PAD_ENDPOINT, PAD_WEIGHT, Graph, GraphBatch,
                               trivial_graph)
 from repro.core.pow2 import auto_chunk, next_pow2
 from repro.core.sparsify import (
+    LOOPS,
     SparsifyResult,
     _bucket_b_cap,
     _result_from_device,
@@ -126,6 +133,24 @@ class ServiceStats:
     # per signature (XLA caches the compile); see the module docstring for
     # the b_cap-widening policy that makes this nonzero.
     n_on_path_compiles: int = 0
+    # per while loop (`core.sparsify.LOOPS`), over the request path's
+    # dispatches: the rounds each real graph needed, summed, and the
+    # rounds the batch ran for them — B_real x the dispatch's most
+    # rounds, since a vmapped loop runs until its slowest lane is done.
+    # 1 - loop_rounds / loop_lane_rounds is the share of a loop's lane
+    # rounds spent waiting in lockstep.
+    loop_rounds: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(LOOPS, 0))
+    loop_lane_rounds: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(LOOPS, 0))
+
+    def count_loops(self, rows, n_real: int):
+        """Add one dispatch's (B_pad, len(LOOPS)) round counts, whose
+        first `n_real` rows are real graphs."""
+        rows = np.asarray(rows, np.int64).reshape(-1, len(LOOPS))
+        for j, name in enumerate(LOOPS):
+            self.loop_rounds[name] += int(rows[:n_real, j].sum())
+            self.loop_lane_rounds[name] += n_real * int(rows[:, j].max())
 
     @property
     def padding_overhead(self) -> float:
@@ -466,17 +491,19 @@ class SparsifyService:
         output dict WITHOUT blocking (JAX dispatch is async). The single
         funnel for the request path AND warmup, so the donated/sharded
         program variants are exactly the ones warmup compiles."""
-        entry = self._pool.acquire(B_pad, L_bucket)
-        u, v, w, ev, bb = self._pool.fill(entry[0], graphs)
-        bb[: len(budgets)] = np.asarray(budgets, np.int32)
-        # jnp.array (copy=True) — NOT asarray/device_put, which zero-copy
-        # aligned host buffers on CPU PJRT and would alias the staging
-        # pool into live device arrays (see _StagingPool)
-        arrs = (jnp.array(u), jnp.array(v), jnp.array(w),
-                jnp.array(ev), jnp.array(bb))
-        if self.mesh is not None:
-            arrs = shard_batch_leading(arrs, self.mesh)
-        with warnings.catch_warnings():
+        with jax.profiler.TraceAnnotation("svc.stage"):
+            entry = self._pool.acquire(B_pad, L_bucket)
+            u, v, w, ev, bb = self._pool.fill(entry[0], graphs)
+            bb[: len(budgets)] = np.asarray(budgets, np.int32)
+            # jnp.array (copy=True) — NOT asarray/device_put, which
+            # zero-copy aligned host buffers on CPU PJRT and would alias
+            # the staging pool into live device arrays (see _StagingPool)
+            arrs = (jnp.array(u), jnp.array(v), jnp.array(w),
+                    jnp.array(ev), jnp.array(bb))
+            if self.mesh is not None:
+                arrs = shard_batch_leading(arrs, self.mesh)
+        with jax.profiler.TraceAnnotation("svc.dispatch"), \
+                warnings.catch_warnings():
             # only edge_valid/budget can alias a same-shape output; XLA's
             # "donated buffers were not usable" note for u/v/w is expected
             warnings.filterwarnings(
@@ -490,15 +517,28 @@ class SparsifyService:
         entry[1] = d["n_accepted"]
         return d
 
-    @staticmethod
-    def _drain(pending: _PendingChunk, results: List[Optional[SparsifyResult]]):
-        """Block on one chunk's device outputs and scatter its rows into
-        `results` at their request indices (placeholder tail dropped)."""
-        host = jax.device_get(pending.device)
-        for row, (i, L) in enumerate(zip(pending.idxs, pending.Ls)):
-            results[i] = _result_from_device(host, row, L)
+    def _drain(self, pending: _PendingChunk,
+               results: List[Optional[SparsifyResult]]):
+        """Block on one chunk's device outputs, scatter its rows into
+        `results` at their request indices (placeholder tail dropped),
+        and count its loop rounds."""
+        with jax.profiler.TraceAnnotation("svc.drain"):
+            host = jax.device_get(pending.device)
+            for row, (i, L) in enumerate(zip(pending.idxs, pending.Ls)):
+                results[i] = _result_from_device(host, row, L)
+            self.stats.count_loops(host["loop_rounds"], len(pending.idxs))
 
     # ---------------------------------------------------------- serving
+
+    def _by_bucket(self, graphs: Sequence[Graph]
+                   ) -> Dict[Tuple[int, int], List[int]]:
+        """Request indices grouped by bucket, under the `svc.bucket`
+        span."""
+        by_bucket: Dict[Tuple[int, int], List[int]] = {}
+        with jax.profiler.TraceAnnotation("svc.bucket"):
+            for i, g in enumerate(graphs):
+                by_bucket.setdefault(self.bucket_key(g), []).append(i)
+        return by_bucket
 
     def sparsify(
         self,
@@ -519,9 +559,7 @@ class SparsifyService:
             if len(budgets) != len(graphs):
                 raise ValueError("one budget per graph required")
 
-        by_bucket: Dict[Tuple[int, int], List[int]] = {}
-        for i, g in enumerate(graphs):
-            by_bucket.setdefault(self.bucket_key(g), []).append(i)
+        by_bucket = self._by_bucket(graphs)
 
         results: List[Optional[SparsifyResult]] = [None] * len(graphs)
         pending: List[_PendingChunk] = []
@@ -547,9 +585,12 @@ class SparsifyService:
                     self.stats.n_on_path_compiles += 1
                 self._seen.add(sig)
                 if self.recovery == "host":
-                    self._sparsify_host_chunk(
+                    out = self._sparsify_host_chunk(
                         graphs, chunk, resolved, n_bucket, L_bucket, B_pad,
                         b_cap, results)
+                    self.stats.count_loops(
+                        [[r.loop_rounds[k] for k in LOOPS] for r in out],
+                        len(chunk))
                 else:
                     d = self._dispatch(
                         [graphs[i] for i in chunk], resolved,
@@ -577,7 +618,8 @@ class SparsifyService:
     def _sparsify_host_chunk(self, graphs, chunk, resolved, n_bucket,
                              L_bucket, B_pad, b_cap, results):
         """The oracle tail (recovery='host'): per-chunk blocking batch
-        call through lgrass_sparsify_batch — kept for fidelity checks."""
+        call through lgrass_sparsify_batch — kept for fidelity checks.
+        Returns every row's result, placeholders included."""
         from repro.core.sparsify import lgrass_sparsify_batch
 
         n_fill = B_pad - len(chunk)
@@ -598,6 +640,7 @@ class SparsifyService:
         )
         for i, r in zip(chunk, out):  # placeholder tail dropped
             results[i] = r
+        return out
 
     def warmup(
         self,

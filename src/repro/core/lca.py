@@ -23,7 +23,10 @@ Two query engines live here:
     and range-minimum queries over the tour's depth sequence answer LCA
     with two sparse-table gathers. Worth building once per graph when a
     stage issues many batched distance queries (the chunked phase-1
-    marking scheduler's cover tables).
+    marking scheduler's cover tables). A distance needs only the LCA's
+    depth, so a second table of depth minima (`dmin`) answers it with
+    two gathers per pair once the endpoints' `first`/`depth` are known
+    (`euler_endpoints`, `euler_distance`).
 """
 from __future__ import annotations
 
@@ -144,6 +147,8 @@ class EulerLCA(NamedTuple):
     table: jax.Array  # (LOGP, P) int32 — position of the depth min in
     #                   [i, i + 2^k) (clamped at the tour end)
     depth: jax.Array  # (n,) int32 — node depths (distance arithmetic)
+    dmin: jax.Array   # (LOGP, P) int32 — the depth min itself over the
+    #                   same ranges: dseq[table[k][i]]
 
 
 def tables_from_tour(tour: jax.Array, T: jax.Array, depth: jax.Array,
@@ -165,13 +170,20 @@ def tables_from_tour(tour: jax.Array, T: jax.Array, depth: jax.Array,
     first = jnp.full((n,), P - 1, jnp.int32).at[
         jnp.where(real, tour, n)].min(piota, mode="drop")
     tabs = [piota]
+    mins = [dseq]
     for k in range(1, _log2_ceil(P) + 1 if P > 1 else 1):
         h = 1 << (k - 1)
         prev = tabs[-1]
         other = prev[jnp.minimum(piota + h, P - 1)]
         tabs.append(jnp.where(dseq[other] < dseq[prev], other, prev))
+        # the same recurrence on values, from a static shifted slice
+        # (h < P always): no gather
+        m = mins[-1]
+        shifted = jnp.concatenate([m[h:], jnp.broadcast_to(m[-1:], (h,))])
+        mins.append(jnp.minimum(m, shifted))
     return EulerLCA(tour=tour, dseq=dseq, first=first,
-                    table=jnp.stack(tabs), depth=depth)
+                    table=jnp.stack(tabs), depth=depth,
+                    dmin=jnp.stack(mins))
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -250,26 +262,60 @@ def build_euler(parent: jax.Array, depth: jax.Array, root: jax.Array,
     return tables_from_tour(tour, T, depth, n)
 
 
+def _rmq_rows(logp: int, P: int, l: jax.Array, r: jax.Array):
+    """Flat indices of the two sparse-table cells covering [l, r]."""
+    span = r - l + 1
+    # floor(log2(span)) without clz: count the powers of two <= span
+    k = jnp.zeros_like(span)
+    for j in range(1, logp):
+        k = k + (span >= (1 << j)).astype(span.dtype)
+    return k * P + l, k * P + (r + 1 - jnp.left_shift(jnp.int32(1), k))
+
+
 @jax.jit
 def lca_euler(e: EulerLCA, a: jax.Array, b: jax.Array) -> jax.Array:
     """Vectorised LCA in O(1) gathers per query (any query shape)."""
     logp, P = e.table.shape
     l = jnp.minimum(e.first[a], e.first[b])
     r = jnp.maximum(e.first[a], e.first[b])
-    span = r - l + 1
-    # floor(log2(span)) without clz: count the powers of two <= span
-    k = jnp.zeros_like(span)
-    for j in range(1, logp):
-        k = k + (span >= (1 << j)).astype(span.dtype)
+    j1, j2 = _rmq_rows(logp, P, l, r)
     flat = e.table.reshape(-1)
-    i1 = flat[k * P + l]
-    i2 = flat[k * P + (r + 1 - jnp.left_shift(jnp.int32(1), k))]
+    i1, i2 = flat[j1], flat[j2]
     w = jnp.where(e.dseq[i2] < e.dseq[i1], i2, i1)
     return e.tour[w]
+
+
+def euler_endpoints(e: EulerLCA, a: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(first tour position, depth) of each node in `a` — the only
+    per-endpoint values `euler_distance` needs. Gather them before any
+    broadcast to pair shape, so each endpoint is looked up once."""
+    return e.first[a], e.depth[a]
+
+
+def euler_distance(e: EulerLCA, fa: jax.Array, da: jax.Array,
+                   fb: jax.Array, db: jax.Array) -> jax.Array:
+    """Tree distance from the endpoints' `euler_endpoints` values: two
+    `dmin` gathers per pair give the LCA's depth directly.
+
+    The result is bit-identical to depth[a] + depth[b] - 2 * depth[w]
+    with w = `lca_euler(a, b)`. For a real tour position dmin holds
+    exactly depth[tour[w]]; the one range whose minimum is INF is
+    l = r = P - 1 past the tour's end (both endpoints off the tour),
+    where the position table answers depth[tour[P - 1]], used here
+    as the same scalar. The int32 sum wraps identically for INF depths.
+    """
+    logp, P = e.dmin.shape
+    l = jnp.minimum(fa, fb)
+    r = jnp.maximum(fa, fb)
+    j1, j2 = _rmq_rows(logp, P, l, r)
+    flat = e.dmin.reshape(-1)
+    dl = jnp.minimum(flat[j1], flat[j2])
+    dl = jnp.where(dl == jnp.iinfo(jnp.int32).max, e.depth[e.tour[P - 1]],
+                   dl)
+    return da + db - 2 * dl
 
 
 @jax.jit
 def tree_distance_euler(e: EulerLCA, a: jax.Array,
                         b: jax.Array) -> jax.Array:
-    w = lca_euler(e, a, b)
-    return e.depth[a] + e.depth[b] - 2 * e.depth[w]
+    return euler_distance(e, *euler_endpoints(e, a), *euler_endpoints(e, b))

@@ -58,11 +58,12 @@ import jax.numpy as jnp
 from repro.core.lca import (
     EulerLCA,
     LiftingTables,
+    euler_distance,
+    euler_endpoints,
     kth_ancestor,
     lca,
     subroot,
     tree_distance,
-    tree_distance_euler,
 )
 from repro.core.pow2 import auto_chunk
 from repro.core.sort import (
@@ -199,14 +200,38 @@ def ball_pair_table(
         cover <=> (d(x,u_j) <= b_j and d(y,v_j) <= b_j) or swapped.
 
     The 4·C·K tree distances are ONE fused batched query — a binary-
-    lifting climb by default, the Euler-tour O(1)-LCA sparse table when
+    lifting climb by default, the Euler-tour depth-minimum table when
     `euler` is given, or the Pallas tree-distance kernel under
     `use_tree_kernel`. This is where chunked schedules pay for their
     blocks: the climb's sequential latency is amortised over the whole
-    (C, K) table instead of one edge's row.
+    (C, K) table instead of one edge's row. On the Euler path each
+    endpoint's (first, depth) is gathered once per row and per column
+    (C·K for per-row candidates), so a pair costs two gathers.
     """
     c = xs.shape[0]
     k = cols_u.shape[-1]
+
+    def cover(d):
+        return ((d[0] <= cols_b) & (d[1] <= cols_b)) | (
+            (d[2] <= cols_b) & (d[3] <= cols_b)
+        )
+
+    if euler is not None and not use_tree_kernel:
+        # endpoint values once per row and per column, before anything
+        # is broadcast to pair shape: only the two `dmin` reads are per
+        # pair
+        def rows(x, y):
+            return jnp.broadcast_to(jnp.stack([x, y, x, y])[:, :, None],
+                                    (4, c, k))
+
+        def cols(u, v):
+            return jnp.broadcast_to(
+                jnp.stack([u, v, v, u]).reshape(4, -1, k), (4, c, k))
+
+        (fx, dx), (fy, dy), (fu, du), (fv, dv) = (
+            euler_endpoints(euler, a) for a in (xs, ys, cols_u, cols_v))
+        return cover(euler_distance(euler, rows(fx, fy), rows(dx, dy),
+                                    cols(fu, fv), cols(du, dv)))
     if cols_u.ndim == 1:
         cols_u = jnp.broadcast_to(cols_u[None, :], (c, k))
         cols_v = jnp.broadcast_to(cols_v[None, :], (c, k))
@@ -220,13 +245,9 @@ def ball_pair_table(
         d = tree_dist_pairs(t.up, t.depth, qa.ravel(),
                             jnp.broadcast_to(qb, (4, c, k)).ravel())
         d = d.reshape(4, c, k)
-    elif euler is not None:
-        d = tree_distance_euler(euler, qa, qb)
     else:
         d = tree_distance(t, qa, qb)
-    return ((d[0] <= cols_b) & (d[1] <= cols_b)) | (
-        (d[2] <= cols_b) & (d[3] <= cols_b)
-    )
+    return cover(d)
 
 
 def _ball_pair_covered(

@@ -55,12 +55,18 @@ def mesh_size(mesh: Mesh) -> int:
     return int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
 
 
+def batch_sharding(mesh: Mesh) -> NamedSharding:
+    """The leading axis split across every axis of `mesh`, the other
+    dims replicated: shard s holds rows [s*B/S, (s+1)*B/S) of a B-row
+    array, S = `mesh_size(mesh)`."""
+    return NamedSharding(mesh, P(tuple(mesh.axis_names)))
+
+
 def shard_batch_leading(arrays, mesh: Mesh):
-    """device_put each array with its leading axis sharded across every
-    axis of `mesh` (remaining dims replicated). The leading dim must be
-    divisible by `mesh_size(mesh)` — the service pads the batch axis to
-    guarantee that."""
-    sh = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    """device_put each array with `batch_sharding(mesh)`. The leading
+    dim must be divisible by `mesh_size(mesh)` — the service pads the
+    batch axis to guarantee that."""
+    sh = batch_sharding(mesh)
     return tuple(jax.device_put(a, sh) for a in arrays)
 
 

@@ -59,7 +59,9 @@ The serving plane on top of the bucketing (PR 6):
     (`core.distributed.shard_batch_leading`); one pod serves one
     mega-bucket. The batch
     pad target rounds up to a multiple of the mesh size so every shard
-    gets equal rows.
+    gets equal rows. The graphs never meet, but each batch-dependent
+    while loop's predicate is one `pred[]` all-reduce a round, so every
+    chip runs as many rounds as the slowest lane of the whole batch.
   * **on-path compile accounting** — every dispatch signature
     (n_bucket, L_bucket, B_pad, b_cap) is checked against the set
     `warmup` compiled; signatures first seen on the request path count
@@ -75,10 +77,12 @@ Results come back in request order and are bit-identical to per-graph
 (tests/test_batch.py, tests/test_service_plane.py).
 
 Measurement: the host phases of a request are
-`jax.profiler.TraceAnnotation` spans (`svc.bucket`, `svc.stage`,
-`svc.dispatch`, `svc.drain`), and `ServiceStats` sums the programs'
-per-graph loop round counts (`loop_rounds`) beside what the vmapped
-loops ran in lockstep (`loop_lane_rounds`).
+`jax.profiler.TraceAnnotation` spans (`svc.bucket`, `svc.stage`, inside
+it `svc.shard` when sharding, `svc.dispatch`, `svc.drain`), and
+`ServiceStats` sums the programs' per-graph loop round counts
+(`loop_rounds`) beside what the vmapped loops ran in lockstep
+(`loop_lane_rounds`) and what they would run if each shard's loops
+stopped with its own lanes (`loop_chip_rounds`).
 """
 from __future__ import annotations
 
@@ -92,7 +96,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.baseline import default_budget
-from repro.core.distributed import mesh_size, shard_batch_leading
+from repro.core.distributed import (batch_sharding, mesh_size,
+                                    shard_batch_leading)
 from repro.core.graph import (PAD_ENDPOINT, PAD_WEIGHT, Graph, GraphBatch,
                               trivial_graph)
 from repro.core.pow2 import auto_chunk, next_pow2
@@ -138,19 +143,30 @@ class ServiceStats:
     # rounds the batch ran for them — B_real x the dispatch's most
     # rounds, since a vmapped loop runs until its slowest lane is done.
     # 1 - loop_rounds / loop_lane_rounds is the share of a loop's lane
-    # rounds spent waiting in lockstep.
+    # rounds spent waiting in lockstep. loop_chip_rounds: over the shards
+    # of a sharded dispatch, each shard's real rows x its own slowest
+    # row, what the shards would run if each stopped with its own lanes;
+    # 1 - loop_chip_rounds / loop_lane_rounds is the share spent waiting
+    # on another shard. Without a mesh it equals loop_lane_rounds.
     loop_rounds: Dict[str, int] = dataclasses.field(
         default_factory=lambda: dict.fromkeys(LOOPS, 0))
     loop_lane_rounds: Dict[str, int] = dataclasses.field(
         default_factory=lambda: dict.fromkeys(LOOPS, 0))
+    loop_chip_rounds: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(LOOPS, 0))
 
-    def count_loops(self, rows, n_real: int):
+    def count_loops(self, rows, n_real: int, shards: int = 1):
         """Add one dispatch's (B_pad, len(LOOPS)) round counts, whose
-        first `n_real` rows are real graphs."""
+        first `n_real` rows are real graphs, split into `shards` equal
+        row blocks as `core.distributed.batch_sharding` splits them."""
         rows = np.asarray(rows, np.int64).reshape(-1, len(LOOPS))
+        per = rows.shape[0] // shards
+        real = np.clip(n_real - per * np.arange(shards), 0, per)
+        slowest = rows.reshape(shards, per, len(LOOPS)).max(axis=1)
         for j, name in enumerate(LOOPS):
             self.loop_rounds[name] += int(rows[:n_real, j].sum())
             self.loop_lane_rounds[name] += n_real * int(rows[:, j].max())
+            self.loop_chip_rounds[name] += int(real @ slowest[:, j])
 
     @property
     def padding_overhead(self) -> float:
@@ -252,10 +268,12 @@ class _StagingPool:
 @dataclasses.dataclass(frozen=True)
 class ProgramSpec:
     """One compiled-program signature of the service, in auditable form:
-    the jit callable, abstract argument shapes, and the static kwargs —
-    exactly what `_dispatch` would run for that signature. Consumed by
-    the static auditor (`repro.analysis.jaxpr_audit.audit_service`),
-    which traces fn over args and walks the jaxpr."""
+    the jit callable, abstract argument shapes (with the mesh's batch
+    sharding when the service shards), and the static kwargs — exactly
+    what `_dispatch` would run for that signature, so compiling a spec
+    compiles the dispatched program. Consumed by the static auditor
+    (`repro.analysis.jaxpr_audit.audit_service`), which traces fn over
+    args and walks the jaxpr."""
     name: str
     signature: Tuple[int, int, int, int]   # (n_bucket, L_bucket, B_pad, b_cap)
     fn: object                             # the jit-wrapped callable
@@ -446,16 +464,17 @@ class SparsifyService:
             sigs = sorted(sigset)
         mode = ("donated" if self.donate else
                 "sharded" if self.mesh is not None else "plain")
+        sh = None if self.mesh is None else batch_sharding(self.mesh)
         specs = []
         for sig in sigs:
             n_bucket, L_bucket, B_pad, b_cap = sig
-            args = (
-                jax.ShapeDtypeStruct((B_pad, L_bucket), jnp.int32),
-                jax.ShapeDtypeStruct((B_pad, L_bucket), jnp.int32),
-                jax.ShapeDtypeStruct((B_pad, L_bucket), jnp.float32),
-                jax.ShapeDtypeStruct((B_pad, L_bucket), jnp.bool_),
-                jax.ShapeDtypeStruct((B_pad,), jnp.int32),
-            )
+            args = tuple(
+                jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+                for shape, dtype in (((B_pad, L_bucket), jnp.int32),
+                                     ((B_pad, L_bucket), jnp.int32),
+                                     ((B_pad, L_bucket), jnp.float32),
+                                     ((B_pad, L_bucket), jnp.bool_),
+                                     ((B_pad,), jnp.int32)))
             specs.append(ProgramSpec(
                 name=f"lgrass_device_batched[{mode}]"
                      f"(n={n_bucket},L={L_bucket},B={B_pad},b_cap={b_cap})",
@@ -467,12 +486,17 @@ class SparsifyService:
             ))
         return specs
 
+    @property
+    def _shards(self) -> int:
+        """Row blocks of a dispatch: one per device of the mesh."""
+        return 1 if self.mesh is None else mesh_size(self.mesh)
+
     def _pad_batch(self, n_chunk: int) -> int:
         """Batch-axis pad target for a chunk of `n_chunk` graphs: the
         next power of two, rounded up to whole mesh multiples when
         sharding so every shard gets equal rows."""
         if self.mesh is not None:
-            ms = mesh_size(self.mesh)
+            ms = self._shards
             return ms * next_pow2(-(-int(n_chunk) // ms))
         return next_pow2(int(n_chunk))
 
@@ -501,7 +525,8 @@ class SparsifyService:
             arrs = (jnp.array(u), jnp.array(v), jnp.array(w),
                     jnp.array(ev), jnp.array(bb))
             if self.mesh is not None:
-                arrs = shard_batch_leading(arrs, self.mesh)
+                with jax.profiler.TraceAnnotation("svc.shard"):
+                    arrs = shard_batch_leading(arrs, self.mesh)
         with jax.profiler.TraceAnnotation("svc.dispatch"), \
                 warnings.catch_warnings():
             # only edge_valid/budget can alias a same-shape output; XLA's
@@ -526,7 +551,8 @@ class SparsifyService:
             host = jax.device_get(pending.device)
             for row, (i, L) in enumerate(zip(pending.idxs, pending.Ls)):
                 results[i] = _result_from_device(host, row, L)
-            self.stats.count_loops(host["loop_rounds"], len(pending.idxs))
+            self.stats.count_loops(host["loop_rounds"], len(pending.idxs),
+                                   self._shards)
 
     # ---------------------------------------------------------- serving
 

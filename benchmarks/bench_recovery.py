@@ -98,7 +98,7 @@ def _device_tail_batched(d, u, v, edge_valid, budgets, b_cap):
                                                -jnp.inf))
         return _recover_scan(t, bu, bv, dd["beta"], offtree, crossing,
                              order, acc, grp, dirty0, bb, b_cap,
-                             chunk=16)[:2]
+                             chunk=16, batched=True)[:2]
     return jax.vmap(one)(d, u, v, edge_valid, budgets)
 
 

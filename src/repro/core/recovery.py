@@ -139,6 +139,15 @@ def recover_host(
 recover = recover_host
 
 
+def rec_widths(b_cap: int, c: int) -> tuple:
+    """The static accept-buffer widths a replay block's cover table can
+    take: multiples of g = max(b_cap // 8, c) below b_cap, then b_cap
+    itself. A block takes the smallest width that holds every filled
+    buffer slot; with b_cap <= c there is one width, the whole buffer."""
+    g = max(b_cap // 8, c)
+    return tuple(range(g, b_cap, g)) + (b_cap,)
+
+
 def _recover_scan(
     t: LiftingTables,
     u: jax.Array,
@@ -155,6 +164,7 @@ def _recover_scan(
     use_tree_kernel: bool = False,
     chunk: int = 32,
     euler=None,
+    batched: bool = False,
 ):
     """The device replay: a chunked two-level lax.scan over rank slots.
 
@@ -176,15 +186,27 @@ def _recover_scan(
 
     Scheduling: slots are processed in blocks of `chunk`. Per block, ONE
     batched LCA evaluates the cover table of all block edges against
-    (a) the buffer snapshot and (b) every other block edge — exploiting
-    the pdGRASS observation that the sweep's interactions are local. The
-    inner scan then replays the block's decisions with pure table
-    lookups: a buffer slot filled before the block reads column `slot`,
-    a slot filled mid-block by block edge j reads column b_cap + j
-    (`buf_idx` tracks which). Group-flip dirt is a per-*group* flag
+    (a) the buffer's first W slots and (b) every other block edge —
+    exploiting the pdGRASS observation that the sweep's interactions are
+    local. W is the smallest of `rec_widths` that holds the `cnt` slots
+    filled before the block; a `lax.switch` picks the table of that
+    static width. Slots filled during the block hold block edges, so
+    `cnt` at the block's start bounds every buffer column the block
+    reads, and the empty columns it leaves out cover nothing. The
+    filled buffer columns are then read once per block: one reduction
+    over the (C, W) table gives each block edge's cover by the
+    pre-block entries (any, and non-crossing). The inner scan replays
+    the block's decisions from that and from column W + j of the block
+    edges j accepted so far. Group-flip dirt is a per-*group* flag
     updated with O(1) scatters (index L is the never-set parking slot
-    for non-crossing edges). Distances are integers, so chunking changes
-    nothing observable: decisions are bit-identical to the host replay.
+    for non-crossing edges). The block's accepts enter the buffer after
+    its scan, in order. Distances are integers, so chunking and the
+    width change nothing observable: decisions are bit-identical to the
+    host replay.
+
+    `batched` (static) marks a trace under vmap. There a switch on a
+    per-lane `cnt` would run every width, so the table keeps the single
+    width b_cap.
 
     The outer loop is a while_loop gated on `cnt < budget`: once the
     budget is exhausted nothing later in the stream can change any
@@ -199,83 +221,96 @@ def _recover_scan(
     nothing.
 
     Returns (accepted (L,) bool, n_accepted int32, rounds int32: blocks
-    the outer loop ran).
+    the outer loop ran, table_cols int32: the columns W + C of the
+    blocks' cover tables, summed).
     """
     L = u.shape[0]
     if L == 0:  # isolated-node graph: nothing to replay
-        return jnp.zeros((0,), bool), jnp.int32(0), jnp.int32(0)
+        return (jnp.zeros((0,), bool), jnp.int32(0), jnp.int32(0),
+                jnp.int32(0))
     budget = jnp.minimum(jnp.asarray(budget, jnp.int32), jnp.int32(b_cap))
     c = max(min(chunk, L), 1)
     n_blocks = -(-L // c)
     order_pad = block_view(order.astype(jnp.int32), c, 0)
     svalid_pad = block_view(jnp.ones((L,), bool), c, False)
-    occ_iota = jnp.arange(b_cap, dtype=jnp.int32)
     last_slot = jnp.max(jnp.where(offtree[order],
                                   jnp.arange(L, dtype=jnp.int32), -1))
     blocks_needed = jnp.minimum(last_slot // c + 1, n_blocks)
+    widths = (b_cap,) if batched else rec_widths(b_cap, c)
 
-    def inner(carry, xs):
-        buf_u, buf_v, buf_b, buf_nc, buf_idx, cnt, gflag, out = carry
-        e, a0, pair_row, i = xs
-        active = a0 & (cnt < budget)
+    def table(w, ops):
+        """Block edges against the buffer's first `w` slots and the
+        block: (cover by a filled slot, by a filled non-crossing slot,
+        the (C, C) block columns)."""
+        buf_u, buf_v, buf_b, buf_nc, cnt, bx, by, bb = ops
+        tbl = ball_pair_table(
+            t, bx, by, jnp.concatenate([buf_u[:w], bx]),
+            jnp.concatenate([buf_v[:w], by]),
+            jnp.concatenate([buf_b[:w], bb]), use_tree_kernel, euler)
+        pre = tbl[:, :w] & (jnp.arange(w, dtype=jnp.int32) < cnt)
+        return (jnp.any(pre, axis=1), jnp.any(pre & buf_nc[:w], axis=1),
+                tbl[:, w:])
 
-        pair_k = pair_row[buf_idx]       # (b_cap,) per-slot cover bits
-        occ = occ_iota < cnt
-        cov_any = jnp.any(pair_k & occ)
-        cov_nc = jnp.any(pair_k & occ & buf_nc)
-
-        cr = crossing[e]
-        g = group_of_edge[e]
-        gsafe = jnp.where(g < 0, L, g).astype(jnp.int32)
-        dirty_e = dirty0[e] | gflag[gsafe] | cov_nc
-        dec = active & jnp.where(cr & ~dirty_e, phase1_accept[e], ~cov_any)
-
-        # flip w.r.t. phase 1: dirty the rest of the group (O(1) scatter)
-        flip = active & cr & (dec != phase1_accept[e])
-        gflag = gflag.at[gsafe].max(flip)
-
-        out = out.at[e].max(dec)  # max: padding re-visits edge id 0
-        slot = jnp.minimum(cnt, b_cap - 1)
-        x = jnp.where(active, u[e], 0).astype(jnp.int32)
-        y = jnp.where(active, v[e], 0).astype(jnp.int32)
-        buf_u = buf_u.at[slot].set(jnp.where(dec, x, buf_u[slot]))
-        buf_v = buf_v.at[slot].set(jnp.where(dec, y, buf_v[slot]))
-        buf_b = buf_b.at[slot].set(
-            jnp.where(dec, beta[e].astype(jnp.int32), buf_b[slot])
-        )
-        buf_nc = buf_nc.at[slot].set(jnp.where(dec, ~cr, buf_nc[slot]))
-        blk_col = jnp.int32(b_cap) + i
-        buf_idx = buf_idx.at[slot].set(
-            jnp.where(dec, blk_col, buf_idx[slot])
-        )
-        cnt = cnt + dec.astype(jnp.int32)
-        return (buf_u, buf_v, buf_b, buf_nc, buf_idx, cnt, gflag, out), None
-
-    def cond(state):
-        blk, _, _, _, _, cnt, _, _ = state
-        return (blk < blocks_needed) & (cnt < budget)
+    tables = [functools.partial(table, w) for w in widths]
 
     def outer(state):
-        blk, buf_u, buf_v, buf_b, buf_nc, cnt, gflag, out = state
+        blk, buf_u, buf_v, buf_b, buf_nc, cnt, gflag, out, cols = state
         eids = jax.lax.dynamic_index_in_dim(order_pad, blk, keepdims=False)
         svalid = jax.lax.dynamic_index_in_dim(svalid_pad, blk,
                                               keepdims=False)
         a0 = svalid & offtree[eids]
         bx = jnp.where(a0, u[eids], 0).astype(jnp.int32)
         by = jnp.where(a0, v[eids], 0).astype(jnp.int32)
-        # one fused cover table: snapshot buffer ++ block endpoints
-        cols_u = jnp.concatenate([buf_u, bx])
-        cols_v = jnp.concatenate([buf_v, by])
-        cols_b = jnp.concatenate([buf_b, beta[eids].astype(jnp.int32)])
-        pair_tbl = ball_pair_table(t, bx, by, cols_u, cols_v, cols_b,
-                                   use_tree_kernel, euler)
-        (buf_u, buf_v, buf_b, buf_nc, _, cnt, gflag, out), _ = jax.lax.scan(
-            inner,
-            (buf_u, buf_v, buf_b, buf_nc,
-             jnp.arange(b_cap, dtype=jnp.int32), cnt, gflag, out),
-            (eids, a0, pair_tbl, jnp.arange(c, dtype=jnp.int32)),
-        )
-        return (blk + 1, buf_u, buf_v, buf_b, buf_nc, cnt, gflag, out)
+        bb = beta[eids].astype(jnp.int32)
+        blk_nc = ~crossing[eids]
+        ops = (buf_u, buf_v, buf_b, buf_nc, cnt, bx, by, bb)
+        if len(widths) == 1:
+            w_idx = 0
+            pre_any, pre_nc, blk_tbl = tables[0](ops)
+        else:
+            w_idx = jnp.sum(jnp.asarray(widths, jnp.int32) < cnt)
+            pre_any, pre_nc, blk_tbl = jax.lax.switch(w_idx, tables, ops)
+
+        def inner(carry, xs):
+            cnt, gflag, acc = carry
+            e, a, row, p_any, p_nc, i = xs
+            active = a & (cnt < budget)
+            hit = row & acc                  # block edges accepted so far
+            cov_any = p_any | jnp.any(hit)
+            cov_nc = p_nc | jnp.any(hit & blk_nc)
+
+            cr = crossing[e]
+            g = group_of_edge[e]
+            gsafe = jnp.where(g < 0, L, g).astype(jnp.int32)
+            dirty_e = dirty0[e] | gflag[gsafe] | cov_nc
+            dec = active & jnp.where(cr & ~dirty_e, phase1_accept[e],
+                                     ~cov_any)
+
+            # flip w.r.t. phase 1: dirty the rest of the group (O(1) scatter)
+            flip = active & cr & (dec != phase1_accept[e])
+            gflag = gflag.at[gsafe].max(flip)
+            acc = acc.at[i].set(dec)
+            return (cnt + dec.astype(jnp.int32), gflag, acc), None
+
+        (cnt_end, gflag, acc), _ = jax.lax.scan(
+            inner, (cnt, gflag, jnp.zeros((c,), bool)),
+            (eids, a0, blk_tbl, pre_any, pre_nc,
+             jnp.arange(c, dtype=jnp.int32)))
+        # the block's accepts fill slots cnt, cnt + 1, ... in block order;
+        # the rest land on index b_cap and are dropped
+        slot = jnp.where(acc, cnt + jnp.cumsum(acc) - 1, b_cap)
+        buf_u = buf_u.at[slot].set(bx, mode="drop")
+        buf_v = buf_v.at[slot].set(by, mode="drop")
+        buf_b = buf_b.at[slot].set(bb, mode="drop")
+        buf_nc = buf_nc.at[slot].set(blk_nc, mode="drop")
+        out = out.at[eids].max(acc)  # max: padding re-visits edge id 0
+        cols = cols + jnp.asarray(widths, jnp.int32)[w_idx] + c
+        return (blk + 1, buf_u, buf_v, buf_b, buf_nc, cnt_end, gflag, out,
+                cols)
+
+    def cond(state):
+        blk, cnt = state[0], state[5]
+        return (blk < blocks_needed) & (cnt < budget)
 
     init = (
         jnp.int32(0),                          # block index
@@ -286,9 +321,11 @@ def _recover_scan(
         jnp.int32(0),                          # cnt
         jnp.zeros((L + 1,), bool),             # per-group flip flag
         jnp.zeros((L,), bool),                 # out
+        jnp.int32(0),                          # cover-table columns
     )
-    rounds, _, _, _, _, cnt, _, out = jax.lax.while_loop(cond, outer, init)
-    return out, cnt, rounds
+    rounds, _, _, _, _, cnt, _, out, cols = jax.lax.while_loop(
+        cond, outer, init)
+    return out, cnt, rounds, cols
 
 
 def _euler_from_lifting(up: jax.Array, depth_t: jax.Array):
@@ -396,7 +433,7 @@ def recover_device_batched(
             euler = _euler_from_lifting(bup, bdep)
         return _recover_scan(
             t, bu, bv, bbeta, (~btree) & bev, bcross, border, bacc, bgrp,
-            bdirty, bb, b_cap, use_tree_kernel, chunk, euler,
+            bdirty, bb, b_cap, use_tree_kernel, chunk, euler, batched=True,
         )[:2]
 
     if edge_valid is None:  # all-true mask ≡ the unmasked offtree

@@ -114,6 +114,9 @@ class SparsifyResult:
     n_dirty: int
     # rounds each while loop of the device program ran, by `LOOPS` name
     loop_rounds: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # columns (buffer width + block) of the recovery replay's cover
+    # tables, summed over its blocks (0 where the host replays)
+    rec_table_cols: int = 0
 
 
 def _phase1_program(
@@ -335,6 +338,7 @@ def _lgrass_program(
     p1_chunk: int | None = None,
     use_euler_lca: bool = True,
     bfs_engine: str = "doubling",
+    batched: bool = False,
 ):
     """Phase 1 + device recovery fused into one program (Fig. 1b end-to-end).
 
@@ -342,7 +346,7 @@ def _lgrass_program(
     (`phase1_edge_views`), the global criticality order is taken over all
     off-tree edges, and the Algorithm-6 replay runs as a lax.scan — no
     host round-trip anywhere. Only scalars and the final masks leave the
-    device.
+    device. `batched` marks the trace under vmap (`_recover_scan`).
     """
     d, euler, rounds = _phase1_program(
         u, v, w, n, k_cap, parallel, lift_levels, edge_valid, schedule,
@@ -362,11 +366,11 @@ def _lgrass_program(
         order = sort_f32_desc_stable(keys)
     with jax.named_scope("REC"):
         with jax.named_scope("rec"):
-            accepted, n_accepted, rounds["rec"] = _recover_scan(
+            accepted, n_accepted, rounds["rec"], table_cols = _recover_scan(
                 t, u, v, d["beta"], offtree, crossing, order,
                 accept_by_edge, group_of_edge, dirty0,
                 jnp.asarray(budget, jnp.int32), b_cap, use_tree_kernel,
-                chunk, euler,
+                chunk, euler, batched,
             )
         depth_fin = finite_depth(d["depth_t"])
         return dict(
@@ -379,6 +383,7 @@ def _lgrass_program(
             n_dirty=jnp.sum(dirty0.astype(jnp.int32)),
             tree_depth_max=jnp.max(depth_fin),
             loop_rounds=_loop_vector(rounds),
+            rec_table_cols=table_cols,
         )
 
 
@@ -438,7 +443,7 @@ def _lgrass_batched_impl(
         lambda bu, bv, bw, bev, bb: _lgrass_program(
             bu, bv, bw, bb, n, k_cap, parallel, lift_levels, b_cap, bev,
             use_tree_kernel, chunk, schedule, p1_chunk, use_euler_lca,
-            bfs_engine,
+            bfs_engine, batched=True,
         )
     )(u, v, w, edge_valid, budget)
 
@@ -483,6 +488,7 @@ def _result_from_device(d: dict, i: Optional[int], L: int) -> SparsifyResult:
         n_overflow_groups=int(pick(d["n_overflow_groups"])),
         n_dirty=int(pick(d["n_dirty"])),
         loop_rounds=_rounds_by_loop(pick(d["loop_rounds"])),
+        rec_table_cols=int(pick(d["rec_table_cols"])),
     )
 
 

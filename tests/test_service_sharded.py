@@ -14,6 +14,8 @@ outcome, and each test below asserts its own. The cases:
   while loop of the program (`EXPECTED_WHILE`);
 - `program_specs` compiles the program the dispatch runs: the same
   collectives as a compile from the dispatch's own sharded arguments;
+- at a b_cap where one graph's replay switches among table widths, the
+  sharded program keeps the one full width: no conditional in REC;
 - the benchmark cell `svc_case1_b8_x4` rehearsed at a tiny size, and
   refused with its answers broken four ways.
 
@@ -195,6 +197,22 @@ def _():
     assert collectives(alone) == [] != collectives(spec_hlo)
 
 
+@case("no_rec_conditional")
+def _():
+    # a b_cap of 256, where the one-graph program switches among eight
+    # table widths: the sharded batch keeps one width, no conditional in
+    # REC, and the same loop predicates
+    wide = svc.program_specs([SIZE], batch_sizes=(8,), budgets=(150,))[0]
+    assert wide.static_kwargs["b_cap"] == 256, wide.static_kwargs
+    hlo = wide.fn.lower(*wide.args, **wide.static_kwargs).compile().as_text()
+    conds = [op.group(1) for line in hlo.splitlines()
+             if re.search(r"\sconditional\(", line)
+             for op in [OP_NAME.search(line)] if op and "REC" in op.group(1)]
+    assert conds == [], conds
+    assert ([c[:2] for c in collectives(hlo)]
+            == [c[:2] for c in collectives(spec_hlo)]), collectives(hlo)
+
+
 FAULTS = {
     "all_kept": lambda drv, masks: [np.ones_like(m) for m in masks],
     "one_edge_flipped": lambda drv, masks: masks[:-1] + [
@@ -297,6 +315,11 @@ def test_sharded_program_collectives_are_loop_predicates(four_devices):
 def test_program_specs_compile_the_dispatched_program(four_devices):
     assert four_devices["spec_is_dispatch"] == "ok", \
         four_devices["spec_is_dispatch"]
+
+
+def test_sharded_program_keeps_one_rec_table_width(four_devices):
+    assert four_devices["no_rec_conditional"] == "ok", \
+        four_devices["no_rec_conditional"]
 
 
 @pytest.mark.parametrize("kind", ["untraced", "traced", "all_kept",

@@ -1,9 +1,7 @@
 """Batched vs sequential sparsification throughput (the batching win).
 
 8 small graphs, one padded `GraphBatch` dispatch vs 8 sequential
-`lgrass_sparsify` calls, both on the basic (scan) schedule — the right
-engine for one CPU core, as in table3/fig5 (the lockstep schedule's lane
-parallelism only pays on wide hardware). Two numbers:
+`lgrass_sparsify` calls. Two numbers:
 
   * steady state — both paths pre-compiled; the batch wins because one
     vmapped program replaces 8 loop dispatches over tiny operands.
@@ -54,12 +52,11 @@ def run(quick: bool = False):
     batch = GraphBatch.from_graphs(graphs)
 
     def sequential():
-        return [lgrass_sparsify(g, budget=BUDGET, k_cap=K_CAP,
-                                parallel=False) for g in graphs]
+        return [lgrass_sparsify(g, budget=BUDGET, k_cap=K_CAP)
+                for g in graphs]
 
     def batched():
-        return lgrass_sparsify_batch(batch, budget=BUDGET, k_cap=K_CAP,
-                                     parallel=False)
+        return lgrass_sparsify_batch(batch, budget=BUDGET, k_cap=K_CAP)
 
     # warm both paths (compile), and check equivalence while at it
     for a, b in zip(sequential(), batched()):
@@ -77,11 +74,11 @@ def run(quick: bool = False):
     if not quick:
         mixed = _graphs_mixed()
         t0 = time.perf_counter()
-        r_seq = [lgrass_sparsify(g, budget=BUDGET, k_cap=K_CAP,
-                                 parallel=False) for g in mixed]
+        r_seq = [lgrass_sparsify(g, budget=BUDGET, k_cap=K_CAP)
+                 for g in mixed]
         t_cold_seq = time.perf_counter() - t0  # 8 shapes -> 8 compiles
 
-        svc = SparsifyService(k_cap=K_CAP, parallel=False)
+        svc = SparsifyService(k_cap=K_CAP)
         t0 = time.perf_counter()
         r_svc = svc.sparsify(mixed, budget=BUDGET)
         t_cold_svc = time.perf_counter() - t0  # 1 bucket -> 1 compile

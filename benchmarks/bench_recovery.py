@@ -27,17 +27,6 @@ removing the sync + per-graph python, and grow with batch size. On an
 accelerator the gap widens further because the host path's sync cost
 is a real transfer, not a memcpy.
 
-Since the phase-1 chunking PR the e2e rows compare two paths that both
-run the chunked+Euler marking schedule, and the fused device path
-additionally backs its recovery cover tables with the same Euler
-tables — that flip is what moved e2e past parity (~1.33x at smoke
-sizes). The full-size rows were then BFS-bound (diameter ~n feeder
-chains pinned the ratio at ~1.0-1.1x) until the hop-doubling engine
-(benchmarks/bench_bfs.py) collapsed the two traversal passes; both
-paths share that win, so the absolute e2e dropped ~2.7x while the
-host-vs-device ratio moved to the ~1.2x the remaining shared stages
-(MST, marking) allow — bench_bfs records the engine before/after.
-
     PYTHONPATH=src python benchmarks/bench_recovery.py [--smoke]
 """
 import argparse
@@ -51,10 +40,9 @@ import jax.numpy as jnp
 
 from repro.core import lgrass_sparsify_batch
 from repro.core.graph import GraphBatch, feeder_like_graph
-from repro.core.lca import LiftingTables
 from repro.core.marking import phase1_edge_views
-from repro.core.recovery import (_recover_scan, recover_device,
-                                 recover_host)
+from repro.core.recovery import (_euler_from_lifting, _recover_scan,
+                                 recover_device, recover_host)
 from repro.core.sort import sort_f32_desc_stable
 from repro.core.sparsify import (_recovery_tail, phase1_device,
                                  phase1_device_batched, phase1_views_np)
@@ -88,7 +76,7 @@ def _device_tail_batched(d, u, v, edge_valid, budgets, b_cap):
     b_cap is the tight per-batch bound (a pow2 bucket only matters for
     compile sharing across batches, which a benchmark doesn't need)."""
     def one(dd, bu, bv, bev, bb):
-        t = LiftingTables(up=dd["up"], depth=dd["depth_t"])
+        euler = _euler_from_lifting(dd["up"], dd["depth_t"])
         tree, crossing = dd["tree_mask"], dd["crossing"]
         acc, grp, dirty0 = phase1_edge_views(
             dd["perm"], dd["gidx"], dd["accept_sorted"],
@@ -96,7 +84,7 @@ def _device_tail_batched(d, u, v, edge_valid, budgets, b_cap):
         offtree = (~tree) & bev
         order = sort_f32_desc_stable(jnp.where(offtree, dd["crit"],
                                                -jnp.inf))
-        return _recover_scan(t, bu, bv, dd["beta"], offtree, crossing,
+        return _recover_scan(euler, bu, bv, dd["beta"], offtree, crossing,
                              order, acc, grp, dirty0, bb, b_cap,
                              chunk=16, batched=True)[:2]
     return jax.vmap(one)(d, u, v, edge_valid, budgets)
@@ -155,7 +143,7 @@ def run(quick: bool = False):
     budgets = [max(1, round(0.05 * gg.n)) for gg in graphs]
     bcap_b = max(max(budgets), 8)  # tight static bound
     d = phase1_device_batched(ub, vb, jnp.asarray(batch.w, jnp.float32),
-                              evb, batch.n_max, 32, False, None)
+                              evb, batch.n_max, 32)
     jax.block_until_ready(d)
     bv = jnp.asarray(np.asarray(budgets, np.int32))
 
@@ -185,12 +173,10 @@ def run(quick: bool = False):
 
     # --- end-to-end: host-tail path vs fused device path ---
     def e2e_host():
-        return lgrass_sparsify_batch(batch, parallel=False,
-                                     recovery="host")
+        return lgrass_sparsify_batch(batch, recovery="host")
 
     def e2e_device():
-        return lgrass_sparsify_batch(batch, parallel=False,
-                                     recovery="device")
+        return lgrass_sparsify_batch(batch, recovery="device")
 
     for a, b in zip(e2e_host(), e2e_device()):  # warm both + equivalence
         assert np.array_equal(a.edge_mask, b.edge_mask)

@@ -57,7 +57,7 @@ def _traffic(n_requests: int, seed: int):
 def _reference(graphs, budgets):
     from repro.core import lgrass_sparsify
 
-    return [lgrass_sparsify(g, budget=b, parallel=False) if g.m else None
+    return [lgrass_sparsify(g, budget=b) if g.m else None
             for g, b in zip(graphs, budgets)]
 
 
@@ -110,7 +110,7 @@ def _run_mode(mode: str, graphs, budgets, arrivals, rate_hz: float,
     # result scatter) is a real fraction of the chunk — which is exactly
     # the regime the async plane targets. With big chunks the program
     # dominates and every mode converges to the same device-bound wall.
-    kw = dict(parallel=False, max_batch_size=4)
+    kw = dict(max_batch_size=4)
     if mode != "sync":
         kw["async_dispatch"] = True
     if "donate" in mode:

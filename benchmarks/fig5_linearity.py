@@ -16,18 +16,12 @@ from repro.core.sparsify import phase1_device
 
 
 def _time_phase1(g, reps=2):
-    # schedule pinned to the basic scan so the measured engine cannot
-    # drift when pipeline defaults change (it did once: the default is
-    # now the chunked scheduler); linearity of the default engine is
-    # bench_phase1's business, this figure tracks the paper's basic
-    # LGRASS trajectory across PRs
     u = jnp.asarray(g.u, jnp.int32)
     v = jnp.asarray(g.v, jnp.int32)
     w = jnp.asarray(g.w, jnp.float32)
 
     def call():
-        return phase1_device(u, v, w, g.n, 8, False, 10,
-                             schedule="scan")
+        return phase1_device(u, v, w, g.n, 8)
 
     jax.block_until_ready(call())  # compile + warmup
     ts = []

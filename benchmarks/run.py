@@ -1,9 +1,7 @@
-# One function per paper table. Print ``name,us_per_call,derived`` CSV;
+# One function per suite. Print ``name,us_per_call,derived`` CSV;
 # ``--json`` additionally writes a BENCH_*.json document that embeds the
-# pipeline configuration (backend, phase-1 schedule, chunk sizes), so
-# benchmark trajectories across PRs compare like with like — a number
-# measured under schedule="scan" must never be read against one measured
-# under schedule="chunked" without the config saying so.
+# pipeline configuration (backend, chunk sizes), so benchmark
+# trajectories across PRs compare like with like.
 import argparse
 import json
 import sys
@@ -11,16 +9,8 @@ import traceback
 
 
 def _bench_config(quick: bool):
-    """The knobs that determine what the numbers mean.
-
-    `pipeline_defaults` describes what a row gets when its suite does
-    NOT pin an engine — the configuration every default-path row (e.g.
-    the e2e recovery rows) ran under. Rows that deliberately pin a
-    different engine (bench_phase1's scan_basic/scan_parallel/lifting
-    rows, fig5's scan schedule) say so in their
-    name or `derived` field; those annotations, not this block, are
-    authoritative for such rows.
-    """
+    """The knobs that determine what the numbers mean: the block and
+    buffer sizes a row gets where its suite does not pin them."""
     import jax
 
     from repro.core.pow2 import auto_chunk
@@ -30,13 +20,10 @@ def _bench_config(quick: bool):
         "quick": bool(quick),
         "jax": jax.__version__,
         "pipeline_defaults": {
-            "phase1_schedule": "chunked",
             "phase1_chunk_policy": "auto_pow2_sqrt",
             "phase1_chunk_at_4k_edges": auto_chunk(4096),
-            "use_euler_lca": True,
             "recovery_chunk": 32,
             "k_cap": 32,
-            "bfs_engine": "doubling",
         },
     }
 
@@ -46,8 +33,8 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="small sizes (CI)")
     ap.add_argument("--only", default=None,
-                    help="comma list: table3,fig5,kernels,roofline,"
-                         "batch,recovery,phase1,bfs,service,spectral")
+                    help="comma list: fig5,kernels,roofline,"
+                         "batch,recovery,service,spectral")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows + config as JSON "
                          "(e.g. BENCH_pr4.json)")
@@ -56,20 +43,16 @@ def main() -> None:
     from repro.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    from benchmarks import (bench_batch, bench_bfs, bench_kernels,
-                            bench_phase1, bench_recovery, bench_service,
-                            bench_spectral, fig5_linearity, roofline,
-                            table3_execution_time)
+    from benchmarks import (bench_batch, bench_kernels, bench_recovery,
+                            bench_service, bench_spectral, fig5_linearity,
+                            roofline)
 
     suites = {
-        "table3": table3_execution_time.run,
         "fig5": fig5_linearity.run,
         "kernels": bench_kernels.run,
         "roofline": roofline.run,
         "batch": bench_batch.run,
         "recovery": bench_recovery.run,
-        "phase1": bench_phase1.run,
-        "bfs": bench_bfs.run,
         "service": bench_service.run,
         "spectral": bench_spectral.run,
     }

@@ -29,7 +29,7 @@ def main():
           f"n in [{min(g.n for g in graphs)}, {max(g.n for g in graphs)}], "
           f"L in [{min(g.m for g in graphs)}, {max(g.m for g in graphs)}]")
 
-    svc = SparsifyService(parallel=False)  # basic schedule: CPU engine
+    svc = SparsifyService()
     t0 = time.perf_counter()
     results = svc.sparsify(graphs)
     t_serve = time.perf_counter() - t0
@@ -45,7 +45,7 @@ def main():
 
     for g, r in zip(graphs, results):
         assert np.array_equal(
-            r.edge_mask, lgrass_sparsify(g, parallel=False).edge_mask
+            r.edge_mask, lgrass_sparsify(g).edge_mask
         )
     print("all results bit-identical to single-graph lgrass_sparsify")
 

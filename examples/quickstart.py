@@ -16,15 +16,11 @@ def main():
     g = powergrid_like_graph(40, 0.25, seed=0)
     print(f"graph: {g.n} nodes, {g.m} edges")
 
-    # basic schedule: the single-core engine (the lockstep schedule is
-    # for many lanes — see DESIGN.md §3 and the dry-run cells)
     t0 = time.perf_counter()
-    result = lgrass_sparsify(g, k_cap=8, parallel=False,
-                             auto_lift_bound=True)
+    result = lgrass_sparsify(g, k_cap=8)
     t_compile = time.perf_counter() - t0
     t0 = time.perf_counter()
-    result = lgrass_sparsify(g, k_cap=8, parallel=False,
-                             auto_lift_bound=True)   # steady state
+    result = lgrass_sparsify(g, k_cap=8)   # steady state
     t_lgrass = time.perf_counter() - t0
     print(f"LGRASS: kept {int(result.edge_mask.sum())}/{g.m} edges "
           f"({result.n_accepted} off-tree) in {t_lgrass*1e3:.1f} ms "

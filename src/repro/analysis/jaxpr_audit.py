@@ -14,8 +14,8 @@ cond sub-jaxprs) and asserts the pipeline's contracts:
     anywhere in the program may carry a 64-bit dtype, and the top-level
     outputs must be strongly typed (a weak output means a Python
     literal's promotion escaped the program boundary).
-  * **loop budgets** — the while-loop COUNT is pinned per
-    (program, bfs_engine) — the O(log n)/O(diameter) round loops are
+  * **loop budgets** — the while-loop COUNT is pinned per program —
+    the O(log n)/O(diameter) round loops are
     data-bounded by construction, but an accidental extra while is a
     regression this catches — and every scan trip count must be a
     documented O(log n) or O(chunk) constant, never O(L)/O(n)
@@ -64,20 +64,16 @@ FORBIDDEN_PRIMITIVES = frozenset({
 # 64-bit dtypes that may not appear outside the x64 leg.
 _WIDE_DTYPES = ("float64", "int64", "uint64", "complex128")
 
-# The documented while-loop budget per program family × BFS engine
-# (schedule-independent; `parallel` uses a while in both engines):
-#   phase-1 = graph BFS (1 while doubling / 2 while levels for the two
-#   passes) + Borůvka rounds (1) + the pointer jumping of each round's
-#   contraction (1, nested) + MARK scheduler (1); the fused program adds
-#   the recovery outer loop (1). Each counts its rounds in its carry
-#   (`core.sparsify.LOOPS`).
-EXPECTED_WHILE: Dict[Tuple[str, str], int] = {
-    ("phase1", "doubling"): 4,
-    ("phase1", "levels"): 5,
-    ("lgrass", "doubling"): 5,
-    ("lgrass", "levels"): 6,
-    ("recover", "-"): 1,
-    ("probe", "-"): 0,
+# The documented while-loop budget per program family: phase-1 = the
+# graph BFS (1) + Borůvka rounds (1) + the pointer jumping of each
+# round's contraction (1, nested) + the MARK schedule (1); the fused
+# program adds the recovery outer loop (1). Each counts its rounds in
+# its carry (`core.sparsify.LOOPS`).
+EXPECTED_WHILE: Dict[str, int] = {
+    "phase1": 4,
+    "lgrass": 5,
+    "recover": 1,
+    "probe": 0,
 }
 
 
@@ -261,27 +257,24 @@ def check_derived_constants() -> List[str]:
 # standard program set + service audit
 # ---------------------------------------------------------------------
 
-def _lgrass_budget(n: int, L: int, schedule: str,
-                   p1_chunk: Optional[int], chunk: int) -> set:
+def _lgrass_budget(n: int, L: int, p1_chunk: Optional[int],
+                   chunk: int) -> set:
     """The documented scan-trip-count set for the fused pipeline:
     binary-lifting depth (log n), the MARK block size, the recovery
     replay block size — and nothing else."""
     from repro.core.pow2 import auto_chunk, log2_ceil
 
-    allowed = {log2_ceil(n + 1), chunk}
-    if schedule == "chunked":
-        allowed.add(p1_chunk if p1_chunk is not None else auto_chunk(L))
-    return allowed
+    return {log2_ceil(n + 1), chunk,
+            p1_chunk if p1_chunk is not None else auto_chunk(L)}
 
 
 def standard_program_audits(n: int = 64, L: int = 128, B: int = 2,
                             b_cap: int = 8) -> List[AuditReport]:
     """Audit the public jit programs at one representative signature.
 
-    Covers both BFS engines for the fused and phase-1 programs (the
-    serving default "doubling" plus the "levels" fallback), the
-    standalone recovery units, and the spectral-probe estimator —
-    every `@jax.jit` entry point a caller can dispatch.
+    Covers the fused and phase-1 programs, the standalone recovery
+    units, and the spectral-probe estimator — every `@jax.jit` entry
+    point a caller can dispatch.
     """
     from repro.core import spectral_probe as sp
     from repro.core.pow2 import log2_ceil
@@ -298,43 +291,37 @@ def standard_program_audits(n: int = 64, L: int = 128, B: int = 2,
     e1 = (f((L,), i32), f((L,), i32), f((L,), f32))
     eB = (f((B, L), i32), f((B, L), i32), f((B, L), f32), f((B, L), b8))
     lev = log2_ceil(n + 1)
-    reports: List[AuditReport] = []
-
-    for eng in ("doubling", "levels"):
-        reports.append(audit_program(
-            f"phase1_device[{eng}]", phase1_device, e1,
-            dict(n=n, bfs_engine=eng),
-            expected_while=EXPECTED_WHILE[("phase1", eng)],
-            allowed_scan_lengths=_lgrass_budget(n, L, "chunked", None, 32)))
-        reports.append(audit_program(
-            f"phase1_device_batched[{eng}]", phase1_device_batched, eB,
-            dict(n=n, bfs_engine=eng),
-            expected_while=EXPECTED_WHILE[("phase1", eng)],
-            allowed_scan_lengths=_lgrass_budget(n, L, "chunked", None, 32)))
-        reports.append(audit_program(
-            f"lgrass_device[{eng}]", lgrass_device,
-            e1 + (f((), i32),), dict(n=n, b_cap=b_cap, bfs_engine=eng),
-            expected_while=EXPECTED_WHILE[("lgrass", eng)],
-            allowed_scan_lengths=_lgrass_budget(n, L, "chunked", None, 32)))
-        reports.append(audit_program(
-            f"lgrass_device_batched[{eng}]", lgrass_device_batched,
-            eB + (f((B,), i32),), dict(n=n, b_cap=b_cap, bfs_engine=eng),
-            expected_while=EXPECTED_WHILE[("lgrass", eng)],
-            allowed_scan_lengths=_lgrass_budget(n, L, "chunked", None, 32)))
+    scans = _lgrass_budget(n, L, None, 32)
+    reports: List[AuditReport] = [
+        audit_program("phase1_device", phase1_device, e1, dict(n=n),
+                      expected_while=EXPECTED_WHILE["phase1"],
+                      allowed_scan_lengths=scans),
+        audit_program("phase1_device_batched", phase1_device_batched, eB,
+                      dict(n=n), expected_while=EXPECTED_WHILE["phase1"],
+                      allowed_scan_lengths=scans),
+        audit_program("lgrass_device", lgrass_device, e1 + (f((), i32),),
+                      dict(n=n, b_cap=b_cap),
+                      expected_while=EXPECTED_WHILE["lgrass"],
+                      allowed_scan_lengths=scans),
+        audit_program("lgrass_device_batched", lgrass_device_batched,
+                      eB + (f((B,), i32),), dict(n=n, b_cap=b_cap),
+                      expected_while=EXPECTED_WHILE["lgrass"],
+                      allowed_scan_lengths=scans),
+    ]
 
     rec1 = (f((lev, n), i32), f((n,), i32), f((L,), i32), f((L,), i32),
             f((L,), i32), f((L,), b8), f((L,), b8), f((L,), i32),
             f((L,), b8), f((L,), i32), f((L,), b8), f((), i32))
     reports.append(audit_program(
         "recover_device", recover_device, rec1, dict(b_cap=b_cap),
-        expected_while=EXPECTED_WHILE[("recover", "-")],
+        expected_while=EXPECTED_WHILE["recover"],
         allowed_scan_lengths={32}))
     recB = tuple(f((B,) + s.shape, s.dtype) for s in rec1[:-1]) \
         + (f((B,), i32),)
     reports.append(audit_program(
         "recover_device_batched", recover_device_batched, recB,
         dict(b_cap=b_cap),
-        expected_while=EXPECTED_WHILE[("recover", "-")],
+        expected_while=EXPECTED_WHILE["recover"],
         allowed_scan_lengths={32}))
 
     n_iters = 16
@@ -345,7 +332,7 @@ def standard_program_audits(n: int = 64, L: int = 128, B: int = 2,
         "probe_edge_resistance", sp._probe_er_program, probe1,
         dict(n=n, n_probes=8, n_iters=n_iters, method="cheby",
              use_spmv_kernel=False),
-        expected_while=EXPECTED_WHILE[("probe", "-")],
+        expected_while=EXPECTED_WHILE["probe"],
         allowed_scan_lengths={n_iters}))
     probeB = (f((B, L), i32), f((B, L), i32), f((B, L), f32),
               f((B, L), b8), f((B, 2), jnp.uint32), f((), f32),
@@ -355,7 +342,7 @@ def standard_program_audits(n: int = 64, L: int = 128, B: int = 2,
         probeB,
         dict(n=n, n_probes=8, n_iters=n_iters, method="cheby",
              use_spmv_kernel=False),
-        expected_while=EXPECTED_WHILE[("probe", "-")],
+        expected_while=EXPECTED_WHILE["probe"],
         allowed_scan_lengths={n_iters}))
     return reports
 
@@ -375,8 +362,8 @@ def audit_service(svc, sizes=None, batch_sizes=(1,),
         kw = spec.static_kwargs
         reports.append(audit_program(
             spec.name, spec.fn, spec.args, kw,
-            expected_while=EXPECTED_WHILE[("lgrass", kw["bfs_engine"])],
+            expected_while=EXPECTED_WHILE["lgrass"],
             allowed_scan_lengths=_lgrass_budget(
-                kw["n"], spec.args[0].shape[-1], kw["schedule"],
-                kw["p1_chunk"], kw["chunk"])))
+                kw["n"], spec.args[0].shape[-1], kw["p1_chunk"],
+                kw["chunk"])))
     return reports

@@ -1,9 +1,9 @@
 """The paper's own workload: LGRASS graph sparsification cases.
 
-Each "shape" is a graph size; the dry-run lowers the distributed phase-1
-(repro.core.distributed) over the production mesh for each case. Sizes
-come from the generators' own shape functions, so they are what
-`official_case` and `powergrid_like_graph` actually produce.
+Each "shape" is a graph size: the three official IPCC cases and the
+10^6-node grid. Sizes come from the generators' own shape functions, so
+they are what `official_case` and `powergrid_like_graph` actually
+produce.
 """
 import dataclasses
 

@@ -18,7 +18,8 @@ INF_I32 = np.iinfo(np.int32).max
 
 
 def bfs_np(u, v, n, root, edge_mask=None):
-    """Mirror of bfs.bfs — smallest-id-parent, level synchronous."""
+    """The level-synchronous BFS `bfs.bfs_doubling` must equal — smallest-id
+    parent, one frontier relaxation per level."""
     src = np.concatenate([u, v])
     dst = np.concatenate([v, u])
     if edge_mask is not None:
@@ -193,7 +194,7 @@ def tree_adjacency(parent, n):
 
 
 def phase1_np(up, depth_t, su, sv, sbeta, gidx, active, k_cap):
-    """Numpy oracle for phase-1 marking — mirrors every device schedule.
+    """Numpy oracle for phase-1 marking — mirrors `phase1_chunked`.
 
     Inputs are the *sorted-slot* views (marking.GroupLayout order):
     su/sv/sbeta the edge endpoints and ball radii per sorted slot, gidx
@@ -202,7 +203,7 @@ def phase1_np(up, depth_t, su, sv, sbeta, gidx, active, k_cap):
     same-group accept covers its ball pair (tree distances via the
     binary-lifting tables); store at most k_cap accepts per group; an
     accept past k_cap only raises the group's overflow flag, exactly as
-    `phase1_basic`/`phase1_parallel`/`phase1_chunked` do on device.
+    `phase1_chunked` does on device.
 
     Returns (accept (L,) bool per sorted slot, overflow (L,) bool per
     dense group index) — the `Phase1Result` layout.

@@ -1,40 +1,31 @@
-"""BFS engines in JAX (TPU adaptation of LGRASS §4.4).
+"""BFS in JAX (TPU adaptation of LGRASS §4.4).
 
 The paper's parallel BFS uses concurrent queues + atomics on a CPU;
-there is no TPU analogue for dynamic work lists. Two dense engines live
-here, selected by ``engine`` and bit-identical in output
-(tests/test_bfs_doubling.py):
-
-  * ``engine="levels"`` — frontier vectorisation: each level is one
-    edge-parallel relaxation over the full edge list. O(L) work per
-    level, O(diameter) tiny while_loop rounds: the right shape when the
-    diameter is O(sqrt N) (power-grid cases), pathological on
-    chain-heavy feeder inputs where the diameter is O(N) and every
-    round is dispatch-overhead-bound.
-  * ``engine="doubling"`` (default) — hop-doubling: each round fuses an
-    edge-parallel Bellman–Ford relaxation with pointer doubling over
-    the tentative-depth forest, so depth information jumps 2^k-length
-    chains per round instead of one hop. Three pointer families carry
-    the doubling (see ``bfs_doubling``); the loop runs to the
-    relaxation fixpoint, which is reached in O(log n) rounds on
-    chain-like inputs and is *provably exact* on every input: tentative
-    depths are always upper bounds on the true BFS depth, and any
-    relaxation fixpoint of upper bounds equals the true depth. The
-    deterministic smallest-id parent is derived afterwards in ONE
-    edge-parallel pass — exact depths uniquely determine the parent
-    under the shared rule (parent = smallest-id neighbour one level
-    up), so depth AND parent equal the level-sync engine bit for bit.
+there is no TPU analogue for dynamic work lists. The dense engine here
+is hop-doubling (``bfs_doubling``): each round fuses an edge-parallel
+Bellman–Ford relaxation with pointer doubling over the tentative-depth
+forest, so depth information jumps 2^k-length chains per round instead
+of one hop. Three pointer families carry the doubling; the loop runs to
+the relaxation fixpoint, which is reached in O(log n) rounds on
+chain-like inputs, never takes more rounds than the diameter, and is
+*provably exact* on every input: tentative depths are always upper
+bounds on the true BFS depth, and any relaxation fixpoint of upper
+bounds equals the true depth. The deterministic smallest-id parent is
+derived afterwards in ONE edge-parallel pass — exact depths uniquely
+determine the parent under the shared rule (parent = smallest-id
+neighbour one level up), so depth AND parent equal the numpy oracle's
+level-synchronous BFS bit for bit (tests/test_bfs_doubling.py).
 
 For the *tree-restricted* second pass of the pipeline no fixpoint
-iteration is needed at all: ``root_tree`` roots the spanning tree in a
-fixed O(log n)-round program by materialising the Euler tour directly
-from the undirected tree edge list (per-arc successor pointers +
-pointer-doubling list ranking, the same machinery as
+iteration is needed at all: ``root_tree_euler`` roots the spanning tree
+in a fixed O(log n)-round program by materialising the Euler tour
+directly from the undirected tree edge list (per-arc successor pointers
++ pointer-doubling list ranking, the same machinery as
 ``lca.build_euler``) and reading depths off a prefix sum over the tour.
 
-Both engines and the tree path thread the optional edge mask, never
-index with booleans, and keep every shape static — safe under jit AND
-vmap (the padded ``GraphBatch`` pipeline).
+Both thread the optional edge mask, never index with booleans, and keep
+every shape static — safe under jit AND vmap (the padded ``GraphBatch``
+pipeline).
 """
 from __future__ import annotations
 
@@ -48,8 +39,6 @@ import jax.numpy as jnp
 from repro.core.pow2 import log2_ceil as _log2_ceil
 
 INF = jnp.iinfo(jnp.int32).max
-
-BFS_ENGINES = ("doubling", "levels")
 
 
 def packed_key_bound(n: int) -> int:
@@ -89,97 +78,6 @@ def finite_depth(depth: jax.Array) -> jax.Array:
     return jnp.where(depth == INF, 0, depth)
 
 
-def bfs(
-    u: jax.Array,
-    v: jax.Array,
-    n: int,
-    root: jax.Array,
-    edge_mask: Optional[jax.Array] = None,
-    engine: str = "doubling",
-) -> Tuple[jax.Array, jax.Array]:
-    """BFS over the undirected edge list from `root`.
-
-    Args:
-        u, v: (L,) int32 endpoints.
-        n: number of nodes (static).
-        root: scalar int32 root node.
-        edge_mask: optional (L,) bool — True edges participate (used to
-            run BFS restricted to the spanning tree, and to mask padding
-            edges in the batched pipeline).
-        engine: "doubling" (default, O(log n) rounds on chain-like
-            inputs) or "levels" (one round per BFS level). Bit-identical
-            outputs; purely a performance knob.
-
-    Returns:
-        depth:  (n,) int32, INF for unreachable.
-        parent: (n,) int32, -1 for root / unreachable.
-    """
-    return bfs_counted(u, v, n, root, edge_mask, engine)[:2]
-
-
-def bfs_counted(
-    u: jax.Array,
-    v: jax.Array,
-    n: int,
-    root: jax.Array,
-    edge_mask: Optional[jax.Array] = None,
-    engine: str = "doubling",
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """`bfs` plus the int32 number of rounds its while loop ran."""
-    if engine == "doubling":
-        return _bfs_doubling(u, v, n, root, edge_mask)
-    if engine != "levels":
-        raise ValueError(f"unknown BFS engine {engine!r}")
-    return _bfs_levels(u, v, n, root, edge_mask)
-
-
-@functools.partial(jax.jit, static_argnames=("n",))
-def bfs_levels(
-    u: jax.Array,
-    v: jax.Array,
-    n: int,
-    root: jax.Array,
-    edge_mask: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Level-synchronous BFS: one edge-parallel relaxation per level."""
-    return _bfs_levels(u, v, n, root, edge_mask)[:2]
-
-
-def _bfs_levels(u, v, n, root, edge_mask):
-    """`bfs_levels` plus its round count."""
-    src = jnp.concatenate([u, v])
-    dst = jnp.concatenate([v, u])
-    if edge_mask is not None:
-        emask = jnp.concatenate([edge_mask, edge_mask])
-    else:
-        emask = jnp.ones_like(src, dtype=bool)
-
-    depth0 = jnp.full((n,), INF, dtype=jnp.int32).at[root].set(0)
-    parent0 = jnp.full((n,), -1, dtype=jnp.int32)
-    frontier0 = jnp.zeros((n,), dtype=bool).at[root].set(True)
-
-    def cond(state):
-        _, _, frontier, _ = state
-        return jnp.any(frontier)
-
-    def body(state):
-        depth, parent, frontier, level = state
-        active = frontier[src] & emask
-        # candidate parent for each destination: smallest active source id
-        cand = jnp.full((n,), INF, dtype=jnp.int32)
-        cand = cand.at[dst].min(jnp.where(active, src, INF))
-        newly = (cand != INF) & (depth == INF)
-        parent = jnp.where(newly, cand, parent)
-        depth = jnp.where(newly, level + 1, depth)
-        return depth, parent, newly, level + 1
-
-    # the level counter is the round count
-    depth, parent, _, rounds = jax.lax.while_loop(
-        cond, body, (depth0, parent0, frontier0, jnp.int32(0))
-    )
-    return depth, parent, rounds
-
-
 @functools.partial(jax.jit, static_argnames=("n",))
 def bfs_doubling(
     u: jax.Array,
@@ -188,7 +86,12 @@ def bfs_doubling(
     root: jax.Array,
     edge_mask: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Hop-doubling BFS: Bellman–Ford relaxations + pointer doubling.
+    """Hop-doubling BFS over the undirected edge list from `root`.
+
+    Returns depth (n,) int32 (INF for unreachable) and parent (n,)
+    int32 (-1 for root / unreachable). `edge_mask`: optional (L,) bool,
+    True edges participate (a tree-restricted BFS; padding edges in the
+    batched pipeline).
 
     State: tentative depths ``dist`` (INF = not yet bounded) plus three
     pointer families over the tentative-parent forest, each carrying a
@@ -216,8 +119,9 @@ def bfs_doubling(
     exit — ``dist`` *equals* the true BFS depth: standard Bellman–Ford
     induction along shortest paths. Rounds are additionally bounded by
     the diameter (relaxation alone fixes level k by round k), so the
-    engine never runs more rounds than level-sync; on chain-like inputs
-    it runs O(log n). All values stay in [0, n] ∪ {INF}: int32-safe.
+    engine never runs more rounds than a level-synchronous BFS; on
+    chain-like inputs it runs O(log n). All values stay in
+    [0, n] ∪ {INF}: int32-safe.
 
     Per-round cost is kept to ONE scatter: the relaxation minimum and
     the climb's re-anchor witness come out of a single scatter-min of
@@ -231,11 +135,12 @@ def bfs_doubling(
     parent[v] = smallest-id neighbour u with depth[u] == depth[v] - 1 —
     exactly the level-sync rule, evaluated on exact depths.
     """
-    return _bfs_doubling(u, v, n, root, edge_mask)[:2]
+    return bfs_counted(u, v, n, root, edge_mask)[:2]
 
 
-def _bfs_doubling(u, v, n, root, edge_mask):
-    """`bfs_doubling` plus its round count."""
+def bfs_counted(u, v, n, root, edge_mask=None):
+    """`bfs_doubling` plus the int32 number of rounds its while loop
+    ran."""
     src = jnp.concatenate([u, v])
     dst = jnp.concatenate([v, u])
     if edge_mask is not None:
@@ -339,21 +244,20 @@ def _euler_tables(tour: jax.Array, T: jax.Array, depth: jax.Array,
     return tables_from_tour(tour, T, depth, n)
 
 
-@functools.partial(jax.jit, static_argnames=("n", "with_euler"))
+@functools.partial(jax.jit, static_argnames=("n",))
 def root_tree_euler(
     u: jax.Array,
     v: jax.Array,
     n: int,
     root: jax.Array,
     tree_mask: jax.Array,
-    with_euler: bool = True,
 ):
     """Root the spanning tree at `root` in O(log n) rounds — no BFS.
 
     Returns (depth, parent, euler) with (depth, parent) bit-identical
-    to ``bfs(u, v, n, root, edge_mask=tree_mask)``: in a tree the depth
-    is unique and each non-root node has exactly one neighbour one
-    level up, so the smallest-id parent rule is vacuous — rooting IS
+    to ``bfs_doubling(u, v, n, root, edge_mask=tree_mask)``: in a tree
+    the depth is unique and each non-root node has exactly one neighbour
+    one level up, so the smallest-id parent rule is vacuous — rooting IS
     the answer. The construction materialises the Euler tour straight
     from the undirected edge list (``lca.build_euler`` starts from
     parent pointers, which is exactly what we don't have yet):
@@ -374,9 +278,8 @@ def root_tree_euler(
          ranked tour; a down arc (x→y) is one with rank < its reversal
          and assigns parent[y] = x.
 
-    with_euler=True additionally turns the already-ranked tour into the
-    `lca.EulerLCA` sparse tables (`_euler_tables`) — the pipeline's
-    O(1)-LCA backend without a second tour construction. (The tour
+    The already-ranked tour also becomes the `lca.EulerLCA` sparse
+    tables (`_euler_tables`) — the pipeline's O(1)-LCA backend without a second tour construction. (The tour
     enters each node's children after-the-parent circularly instead of
     build_euler's from-the-smallest; both are valid Euler tours, and
     every LCA/distance query answers identically — the range minimum
@@ -392,12 +295,9 @@ def root_tree_euler(
     depth0 = jnp.full((n,), INF, jnp.int32).at[root].set(0)
     parent0 = jnp.full((n,), -1, jnp.int32)
     if L == 0:
-        euler = None
-        if with_euler:
-            P = 2 * n - 1
-            tour0 = jnp.zeros((P,), jnp.int32).at[0].set(root)
-            euler = _euler_tables(tour0, jnp.int32(0), depth0, n)
-        return depth0, parent0, euler
+        tour0 = jnp.zeros((2 * n - 1,), jnp.int32).at[0].set(root)
+        return depth0, parent0, _euler_tables(tour0, jnp.int32(0), depth0,
+                                              n)
     A = 2 * L
     aiota = jnp.arange(A, dtype=jnp.int32)
     tail = jnp.concatenate([u, v]).astype(jnp.int32)
@@ -454,29 +354,12 @@ def root_tree_euler(
     parent = parent0.at[hsafe].set(tail, mode="drop")
     depth = depth0.at[hsafe].set(
         csum[jnp.clip(rank, 0, A - 1)], mode="drop")
-    euler = None
-    if with_euler:
-        # arc of rank r contributes its head at tour position r + 1
-        P = 2 * n - 1
-        wpos = jnp.where(in_tour, jnp.minimum(rank + 1, P), P)
-        tour = (jnp.zeros((P,), jnp.int32).at[0].set(root)
-                .at[wpos].set(head, mode="drop"))
-        euler = _euler_tables(tour, T, depth, n)
-    return depth, parent, euler
-
-
-@functools.partial(jax.jit, static_argnames=("n",))
-def root_tree(
-    u: jax.Array,
-    v: jax.Array,
-    n: int,
-    root: jax.Array,
-    tree_mask: jax.Array,
-) -> Tuple[jax.Array, jax.Array]:
-    """`root_tree_euler` without the LCA tables: (depth, parent) only."""
-    depth, parent, _ = root_tree_euler(u, v, n, root, tree_mask,
-                                       with_euler=False)
-    return depth, parent
+    # arc of rank r contributes its head at tour position r + 1
+    P = 2 * n - 1
+    wpos = jnp.where(in_tour, jnp.minimum(rank + 1, P), P)
+    tour = (jnp.zeros((P,), jnp.int32).at[0].set(root)
+            .at[wpos].set(head, mode="drop"))
+    return depth, parent, _euler_tables(tour, T, depth, n)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))

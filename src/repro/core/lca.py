@@ -1,32 +1,27 @@
-"""Batched LCA via binary lifting (TPU adaptation of LGRASS §3.2/§4.3).
+"""Batched LCA: binary lifting and Euler-tour tables (LGRASS §3.2/§4.3).
 
 The paper uses an online sequential LCA (Schieber–Vishkin flavoured) plus
 the root-subtree shortcut. A sequential O(1)-per-query LCA is the wrong
-shape for a TPU; the data-parallel equivalent is binary lifting — all L
-queries are answered simultaneously with O(log depth) gathers each, which
-is a handful of fully-vectorised rounds over (L,) arrays. The paper's
-root-subtree shortcut *is* kept: queries whose endpoints live in different
-root subtrees return `root` without climbing (`subroot` below), which in
-the IPCC inputs answers the majority of queries in O(1).
+shape for a TPU; here every query of a stage is answered at once with
+dense gathers.
 
-Tables are (LOG, n) int32 in HBM; every query round is a gather — exactly
-the access pattern TPUs stream well.
-
-Two query engines live here:
-
-  * binary lifting (`LiftingTables`, `lca`) — O(log depth) gathers per
-    query, cheap O(n log n) construction (one scan).
-  * Euler tour + sparse-table RMQ (`EulerLCA`, `lca_euler`) — O(1)
-    gathers per query after an O(n log n) device-side construction: the
-    tour is derived from per-arc successor pointers ranked by pointer
-    doubling (the classic list-ranking formulation, fully vectorised),
-    and range-minimum queries over the tour's depth sequence answer LCA
-    with two sparse-table gathers. Worth building once per graph when a
-    stage issues many batched distance queries (the chunked phase-1
-    marking scheduler's cover tables). A distance needs only the LCA's
-    depth, so a second table of depth minima (`dmin`) answers it with
-    two gathers per pair once the endpoints' `first`/`depth` are known
-    (`euler_endpoints`, `euler_distance`).
+  * Euler tour + sparse-table RMQ (`EulerLCA`, `lca_euler`) — the
+    pipeline's LCA: O(1) gathers per query after an O(n log n)
+    device-side construction. The tour is derived from per-arc
+    successor pointers ranked by pointer doubling (the classic
+    list-ranking formulation, fully vectorised), and range-minimum
+    queries over the tour's depth sequence answer LCA with two
+    sparse-table gathers. A distance needs only the LCA's depth, so a
+    second table of depth minima (`dmin`) answers it with two gathers
+    per pair once the endpoints' `first`/`depth` are known
+    (`euler_endpoints`, `euler_distance`) — the cover tables of MARK
+    and REC.
+  * binary lifting (`LiftingTables`) — (LOG, n) int32 ancestor tables,
+    cheap to build (one scan). The pipeline reads them for root-path
+    sums (RES) and root-subtree keys (`subroot`, SORT). Its own LCA
+    queries (`lca`, `tree_distance`, `lca_with_shortcut`, the paper's
+    root-subtree shortcut) are O(log depth) gathers each and off the
+    pipeline; the tests keep them as a second formulation.
 """
 from __future__ import annotations
 
@@ -44,14 +39,12 @@ class LiftingTables(NamedTuple):
     depth: jax.Array  # (n,) int32
 
 
-@functools.partial(jax.jit, static_argnames=("n", "levels"))
-def build_lifting(parent: jax.Array, depth: jax.Array, n: int,
-                  levels: int | None = None) -> LiftingTables:
-    """levels: optional depth bound — must satisfy 2^levels > max(depth).
-    The default ceil(log2(n+1)) is always safe; a measured bound shrinks
-    every LCA climb proportionally (§Perf 'lift_bound': tree depth of the
-    power-grid/random cases is O(sqrt N)/O(log N), far below N)."""
-    log = levels if levels is not None else _log2_ceil(n + 1)
+@functools.partial(jax.jit, static_argnames=("n",))
+def build_lifting(parent: jax.Array, depth: jax.Array,
+                  n: int) -> LiftingTables:
+    """ceil(log2(n+1)) levels of 2^k-th ancestors: enough for any tree
+    depth below n."""
+    log = _log2_ceil(n + 1)
     up0 = jnp.where(parent < 0, jnp.arange(n, dtype=jnp.int32), parent)
 
     def step(carry, _):

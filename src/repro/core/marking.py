@@ -14,31 +14,21 @@ insight is twofold:
 TPU adaptation: instead of per-thread dynamic task queues we keep a
 bounded table of accepted edges per group, (G, K) in HBM, and evaluate the
 cover test *analytically* — dist(x, u_j) <= beta_j via batched LCA — which
-replaces ball materialisation (pointer chasing) with dense gathers. Three
-schedules are provided:
+replaces ball materialisation (pointer chasing) with dense gathers.
 
-  * `phase1_chunked`  — the default: sorted slots are processed in
-    blocks of C. Per block, ONE batched LCA call builds the cover table
-    of all block candidates against (a) each slot's per-group accepted-
-    buffer snapshot and (b) every other block slot; an arithmetic-only
-    inner lax.scan then replays the block's accept/reject decisions with
-    pure table lookups (no per-slot gathers), and the per-(L, K) tables
-    are updated with one batched scatter per block. Crossing slots
-    occupy a prefix of the sorted layout, so the outer while_loop runs
-    ceil(n_crossing / C) blocks — the step count collapses from L to
-    n_crossing / C (pdGRASS's density-aware batching, mapped from
-    thread queues to lane blocks).
-  * `phase1_basic`    — one lax.scan over edges in global criticality
-    order (the paper's "basic LGRASS", Fig. 1b).
-  * `phase1_parallel` — rank-lockstep over groups: at step r every group
-    processes its r-th edge simultaneously (the paper's parallel edge
-    marking, Fig. 2, mapped from thread-parallel to lane-parallel).
-
-All three schedules are bit-identical (groups are independent and each
-schedule preserves the within-group criticality order; tests/
-test_marking_chunked.py sweeps them against the numpy oracle). The
-`run_phase1` dispatcher selects one via `schedule="chunked" | "scan"`
-(the latter picking basic or lockstep via `parallel`).
+`phase1_chunked` processes the sorted slots in blocks of C. Per block,
+ONE batched distance query builds the cover table of all block
+candidates against (a) each slot's per-group accepted-buffer snapshot
+and (b) every other block slot; an arithmetic-only inner lax.scan then
+replays the block's accept/reject decisions with pure table lookups (no
+per-slot gathers), and the per-(L, K) tables are updated with one
+batched scatter per block. Crossing slots occupy a prefix of the sorted
+layout, so the outer while_loop runs ceil(n_crossing / C) blocks — the
+step count collapses from L to n_crossing / C (pdGRASS's density-aware
+batching, mapped from thread queues to lane blocks). Groups are
+independent and each block preserves the within-group criticality
+order, so every block size gives the numpy oracle's decisions
+(tests/test_marking_chunked.py).
 
 Groups whose accepted count exceeds K overflow; the host recovery stage
 (recovery.py) re-checks those exactly, so K is a performance knob, never a
@@ -50,7 +40,7 @@ exactly as the paper keeps that stage sequential (Fig. 1c).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,16 +50,10 @@ from repro.core.lca import (
     LiftingTables,
     euler_distance,
     euler_endpoints,
-    kth_ancestor,
-    lca,
     subroot,
-    tree_distance,
 )
-from repro.core.pow2 import auto_chunk
 from repro.core.sort import (
     block_view,
-    float32_sort_key,
-    radix_argsort_u32,
     radix_argsort_u64pair,
     sort_f32_desc_stable,
 )
@@ -180,14 +164,12 @@ def build_group_layout(
 
 
 def ball_pair_table(
-    t: LiftingTables,
+    euler: EulerLCA,
     xs: jax.Array,
     ys: jax.Array,
     cols_u: jax.Array,
     cols_v: jax.Array,
     cols_b: jax.Array,
-    use_tree_kernel: bool = False,
-    euler: Optional[EulerLCA] = None,
 ) -> jax.Array:
     """Ball-pair cover table for a block of edges vs a set of candidates.
 
@@ -199,14 +181,12 @@ def ball_pair_table(
 
         cover <=> (d(x,u_j) <= b_j and d(y,v_j) <= b_j) or swapped.
 
-    The 4·C·K tree distances are ONE fused batched query — a binary-
-    lifting climb by default, the Euler-tour depth-minimum table when
-    `euler` is given, or the Pallas tree-distance kernel under
-    `use_tree_kernel`. This is where chunked schedules pay for their
-    blocks: the climb's sequential latency is amortised over the whole
-    (C, K) table instead of one edge's row. On the Euler path each
-    endpoint's (first, depth) is gathered once per row and per column
-    (C·K for per-row candidates), so a pair costs two gathers.
+    The 4·C·K tree distances are ONE fused batched query on the
+    Euler-tour depth-minimum table. This is where chunked schedules pay
+    for their blocks: the query is amortised over the whole (C, K)
+    table instead of one edge's row. Each endpoint's (first, depth) is
+    gathered once per row and per column (C·K for per-row candidates),
+    so a pair costs two gathers.
     """
     c = xs.shape[0]
     k = cols_u.shape[-1]
@@ -216,73 +196,20 @@ def ball_pair_table(
             (d[2] <= cols_b) & (d[3] <= cols_b)
         )
 
-    if euler is not None and not use_tree_kernel:
-        # endpoint values once per row and per column, before anything
-        # is broadcast to pair shape: only the two `dmin` reads are per
-        # pair
-        def rows(x, y):
-            return jnp.broadcast_to(jnp.stack([x, y, x, y])[:, :, None],
-                                    (4, c, k))
+    # endpoint values once per row and per column, before anything is
+    # broadcast to pair shape: only the two `dmin` reads are per pair
+    def rows(x, y):
+        return jnp.broadcast_to(jnp.stack([x, y, x, y])[:, :, None],
+                                (4, c, k))
 
-        def cols(u, v):
-            return jnp.broadcast_to(
-                jnp.stack([u, v, v, u]).reshape(4, -1, k), (4, c, k))
+    def cols(u, v):
+        return jnp.broadcast_to(
+            jnp.stack([u, v, v, u]).reshape(4, -1, k), (4, c, k))
 
-        (fx, dx), (fy, dy), (fu, du), (fv, dv) = (
-            euler_endpoints(euler, a) for a in (xs, ys, cols_u, cols_v))
-        return cover(euler_distance(euler, rows(fx, fy), rows(dx, dy),
-                                    cols(fu, fv), cols(du, dv)))
-    if cols_u.ndim == 1:
-        cols_u = jnp.broadcast_to(cols_u[None, :], (c, k))
-        cols_v = jnp.broadcast_to(cols_v[None, :], (c, k))
-        cols_b = jnp.broadcast_to(cols_b[None, :], (c, k))
-    qa = jnp.broadcast_to(jnp.stack([xs, ys, xs, ys])[:, :, None],
-                          (4, c, k))
-    qb = jnp.stack([cols_u, cols_v, cols_v, cols_u])
-    if use_tree_kernel:
-        from repro.kernels.ops import tree_dist_pairs
-
-        d = tree_dist_pairs(t.up, t.depth, qa.ravel(),
-                            jnp.broadcast_to(qb, (4, c, k)).ravel())
-        d = d.reshape(4, c, k)
-    else:
-        d = tree_distance(t, qa, qb)
-    return cover(d)
-
-
-def _ball_pair_covered(
-    t: LiftingTables,
-    x: jax.Array,
-    y: jax.Array,
-    row_u: jax.Array,
-    row_v: jax.Array,
-    row_b: jax.Array,
-    cnt: jax.Array,
-) -> jax.Array:
-    """Paired-ball cover test against a (…, K) accepted-edge table.
-
-    covered <=> exists j < cnt:
-        (d(x,u_j) <= b_j and d(y,v_j) <= b_j) or
-        (d(x,v_j) <= b_j and d(y,u_j) <= b_j)
-
-    Distances are tree hop distances via batched LCA — this is Alg. 3's
-    check, evaluated analytically instead of via materialised ball sets.
-    """
-    k = row_u.shape[-1]
-    xb = jnp.broadcast_to(x[..., None], row_u.shape)
-    yb = jnp.broadcast_to(y[..., None], row_u.shape)
-
-    def dist(a, b):
-        w = lca(t, a, b)
-        return t.depth[a] + t.depth[b] - 2 * t.depth[w]
-
-    dxu = dist(xb, row_u)
-    dxv = dist(xb, row_v)
-    dyu = dist(yb, row_u)
-    dyv = dist(yb, row_v)
-    pair = ((dxu <= row_b) & (dyv <= row_b)) | ((dxv <= row_b) & (dyu <= row_b))
-    valid = jnp.arange(k, dtype=jnp.int32) < cnt[..., None]
-    return jnp.any(pair & valid, axis=-1)
+    (fx, dx), (fy, dy), (fu, du), (fv, dv) = (
+        euler_endpoints(euler, a) for a in (xs, ys, cols_u, cols_v))
+    return cover(euler_distance(euler, rows(fx, fy), rows(dx, dy),
+                                cols(fu, fv), cols(du, dv)))
 
 
 class Phase1Result(NamedTuple):
@@ -327,134 +254,15 @@ def phase1_edge_views(
     return accept_by_edge, group_of_edge, dirty0
 
 
-@functools.partial(jax.jit, static_argnames=("k_cap",))
-def phase1_basic(
-    t: LiftingTables,
-    su: jax.Array,
-    sv: jax.Array,
-    sbeta: jax.Array,
-    layout: GroupLayout,
-    k_cap: int = 32,
-) -> Phase1Result:
-    """Sequential greedy (basic LGRASS): one lax.scan over sorted slots."""
-    m = su.shape[0]
-    if m == 0:
-        return _empty_phase1()
-    acc_u = jnp.zeros((m, k_cap), jnp.int32)
-    acc_v = jnp.zeros((m, k_cap), jnp.int32)
-    acc_b = jnp.full((m, k_cap), -1, jnp.int32)
-    cnt = jnp.zeros((m,), jnp.int32)
-    ovf = jnp.zeros((m,), bool)
-
-    def step(carry, i):
-        acc_u, acc_v, acc_b, cnt, ovf = carry
-        g = layout.gidx[i]
-        act = layout.active[i]
-        x = jnp.where(act, su[i], 0)
-        y = jnp.where(act, sv[i], 0)
-        cov = _ball_pair_covered(t, x, y, acc_u[g], acc_v[g], acc_b[g], cnt[g])
-        accept = act & ~cov
-        full = cnt[g] >= k_cap
-        ovf = ovf.at[g].set(ovf[g] | (accept & full))
-        slot = jnp.minimum(cnt[g], k_cap - 1)
-        store = accept & ~full
-        acc_u = acc_u.at[g, slot].set(jnp.where(store, x, acc_u[g, slot]))
-        acc_v = acc_v.at[g, slot].set(jnp.where(store, y, acc_v[g, slot]))
-        acc_b = acc_b.at[g, slot].set(
-            jnp.where(store, sbeta[i], acc_b[g, slot])
-        )
-        cnt = cnt.at[g].add(store.astype(jnp.int32))
-        return (acc_u, acc_v, acc_b, cnt, ovf), accept
-
-    (acc_u, acc_v, acc_b, cnt, ovf), accept = jax.lax.scan(
-        step, (acc_u, acc_v, acc_b, cnt, ovf), jnp.arange(m, dtype=jnp.int32)
-    )
-    return Phase1Result(accept=accept, group_overflow=ovf,
-                        rounds=jnp.int32(m))
-
-
-@functools.partial(jax.jit, static_argnames=("k_cap",))
-def phase1_parallel(
-    t: LiftingTables,
-    su: jax.Array,
-    sv: jax.Array,
-    sbeta: jax.Array,
-    layout: GroupLayout,
-    k_cap: int = 32,
-) -> Phase1Result:
-    """Rank-lockstep greedy (parallel LGRASS): all groups advance together.
-
-    Step r processes the r-th edge of every group as one vectorised lane
-    batch — the TPU analogue of the paper's dynamic task dispatch. Total
-    steps = max group size; each step is O(G * K * log N) dense work.
-    """
-    m = su.shape[0]
-    if m == 0:
-        return _empty_phase1()
-    garange = jnp.arange(m, dtype=jnp.int32)
-    lane_live = garange < layout.n_groups
-    # Trip count: longest *active* group only. Inactive slots (tree /
-    # non-crossing / padding) all share the (UMAX, UMAX) tail group whose
-    # lane never fires (`layout.active` is False there), so letting its
-    # size — O(L) — drive the loop would only add no-op rounds.
-    group_active = layout.active[jnp.minimum(layout.group_start, m - 1)]
-    max_r = jnp.max(
-        jnp.where(lane_live & group_active, layout.group_size, 0)
-    )
-
-    acc_u = jnp.zeros((m, k_cap), jnp.int32)
-    acc_v = jnp.zeros((m, k_cap), jnp.int32)
-    acc_b = jnp.full((m, k_cap), -1, jnp.int32)
-    cnt = jnp.zeros((m,), jnp.int32)
-    ovf = jnp.zeros((m,), bool)
-    out = jnp.zeros((m,), bool)
-
-    def cond(state):
-        r = state[0]
-        return r < max_r
-
-    def body(state):
-        r, acc_u, acc_v, acc_b, cnt, ovf, out = state
-        gs = layout.group_start[garange]
-        i = jnp.minimum(gs + r, m - 1)
-        lane_act = lane_live & (r < layout.group_size[garange])
-        lane_act = lane_act & layout.active[i]
-        x = jnp.where(lane_act, su[i], 0)
-        y = jnp.where(lane_act, sv[i], 0)
-        cov = _ball_pair_covered(t, x, y, acc_u, acc_v, acc_b, cnt)
-        accept = lane_act & ~cov
-        full = cnt >= k_cap
-        ovf = ovf | (accept & full)
-        slot = jnp.minimum(cnt, k_cap - 1)
-        store = accept & ~full
-        acc_u = acc_u.at[garange, slot].set(jnp.where(store, x, acc_u[garange, slot]))
-        acc_v = acc_v.at[garange, slot].set(jnp.where(store, y, acc_v[garange, slot]))
-        acc_b = acc_b.at[garange, slot].set(
-            jnp.where(store, sbeta[i], acc_b[garange, slot])
-        )
-        cnt = cnt + store.astype(jnp.int32)
-        write_i = jnp.where(lane_act, i, m)  # dropped when inactive
-        out = out.at[write_i].set(accept, mode="drop")
-        return r + 1, acc_u, acc_v, acc_b, cnt, ovf, out
-
-    rounds, acc_u, acc_v, acc_b, cnt, ovf, out = jax.lax.while_loop(
-        cond, body, (jnp.int32(0), acc_u, acc_v, acc_b, cnt, ovf, out)
-    )
-    return Phase1Result(accept=out, group_overflow=ovf, rounds=rounds)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("k_cap", "chunk", "use_tree_kernel"))
+@functools.partial(jax.jit, static_argnames=("k_cap", "chunk"))
 def phase1_chunked(
-    t: LiftingTables,
+    euler: EulerLCA,
     su: jax.Array,
     sv: jax.Array,
     sbeta: jax.Array,
     layout: GroupLayout,
     k_cap: int = 32,
     chunk: int = 32,
-    use_tree_kernel: bool = False,
-    euler: Optional[EulerLCA] = None,
 ) -> Phase1Result:
     """Two-level chunked greedy — the recovery-style replay for phase 1.
 
@@ -478,11 +286,11 @@ def phase1_chunked(
     tree / padding slots share the (UMAX, UMAX) tail group, which sorts
     last), so the outer while_loop runs ceil(n_crossing / chunk) blocks
     — never the full L. Decisions are integer comparisons throughout,
-    hence bit-identical to `phase1_basic` / `phase1_parallel` / the
-    numpy oracle (tests/test_marking_chunked.py).
+    hence bit-identical to the numpy oracle at every block size
+    (tests/test_marking_chunked.py).
 
-    `euler`: optional Euler-tour O(1)-LCA tables (lca.py) backing the
-    distance queries — O(1) gathers per query instead of O(log n).
+    `euler`: the Euler-tour O(1)-LCA tables (lca.py) the distance
+    queries read.
     """
     m = su.shape[0]
     if m == 0:
@@ -520,12 +328,11 @@ def phase1_chunked(
         g, act = pick(g_pad), pick(act_pad)
         x, y, b = pick(x_pad), pick(y_pad), pick(b_pad)
         cnt0 = cnt[g]
-        pair_buf = ball_pair_table(t, x, y, acc_u[g], acc_v[g], acc_b[g],
-                                   use_tree_kernel, euler)
+        pair_buf = ball_pair_table(euler, x, y, acc_u[g], acc_v[g],
+                                   acc_b[g])
         cov_buf = jnp.any(pair_buf & (kiota[None, :] < cnt0[:, None]),
                           axis=1)
-        pair_blk = ball_pair_table(t, x, y, x, y, b, use_tree_kernel,
-                                   euler)
+        pair_blk = ball_pair_table(euler, x, y, x, y, b)
         same_prior = (g[:, None] == g[None, :]) & (
             ciota[None, :] < ciota[:, None]
         )
@@ -554,35 +361,3 @@ def phase1_chunked(
     )
     rounds, _, _, _, _, ovf, out = jax.lax.while_loop(cond, body, init)
     return Phase1Result(accept=out[:m], group_overflow=ovf, rounds=rounds)
-
-
-def run_phase1(
-    t: LiftingTables,
-    su: jax.Array,
-    sv: jax.Array,
-    sbeta: jax.Array,
-    layout: GroupLayout,
-    k_cap: int = 32,
-    schedule: str = "chunked",
-    parallel: bool = True,
-    chunk: Optional[int] = None,
-    use_tree_kernel: bool = False,
-    euler: Optional[EulerLCA] = None,
-) -> Phase1Result:
-    """Schedule dispatcher — the one entry every pipeline goes through.
-
-    schedule="chunked" (default) runs `phase1_chunked` with an automatic
-    pow2 block size (`core.pow2.auto_chunk`, ~sqrt(L)) unless `chunk`
-    pins one; schedule="scan" keeps the legacy per-slot engines, with
-    `parallel` picking rank-lockstep vs the basic sequential scan. All
-    choices are bit-identical; this is purely a performance knob.
-    """
-    if schedule == "chunked":
-        c = auto_chunk(int(su.shape[0])) if chunk is None else int(chunk)
-        return phase1_chunked(t, su, sv, sbeta, layout, k_cap=k_cap,
-                              chunk=c, use_tree_kernel=use_tree_kernel,
-                              euler=euler)
-    if schedule != "scan":
-        raise ValueError(f"unknown phase-1 schedule {schedule!r}")
-    fn = phase1_parallel if parallel else phase1_basic
-    return fn(t, su, sv, sbeta, layout, k_cap=k_cap)

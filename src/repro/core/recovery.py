@@ -27,10 +27,9 @@ Two implementations of the identical semantics live here:
     budget-bounded (b_cap,) buffer; the ball-pair coverage test is
     vectorised via analytic tree distances (`x in B(c, beta)` iff
     `tree_dist(x, c) <= beta`, so no ball is ever materialised) —
-    answered by Euler-tour O(1)-LCA tables rebuilt on device from
-    up[0] by default (`use_euler_lca`, the same backend the fused
-    program shares), or by binary-lifting climbs — with one batched
-    LCA per block of `chunk` edges
+    answered by the Euler-tour O(1)-LCA tables (the fused program's
+    own; the standalone entries rebuild them on device from up[0]) —
+    with one batched LCA per block of `chunk` edges
     (marking.ball_pair_table, the cover-table helper shared with the
     chunked phase-1 scheduler that later ported this exact scheme)
     answering every block-vs-buffer and block-vs-block query at once;
@@ -52,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import _host as H
-from repro.core.lca import LiftingTables, build_euler
+from repro.core.lca import EulerLCA, build_euler
 from repro.core.marking import ball_pair_table
 from repro.core.sort import block_view
 
@@ -135,10 +134,6 @@ def recover_host(
     return out
 
 
-# Backwards-compatible name (distributed tests drive the oracle directly).
-recover = recover_host
-
-
 def rec_widths(b_cap: int, c: int) -> tuple:
     """The static accept-buffer widths a replay block's cover table can
     take: multiples of g = max(b_cap // 8, c) below b_cap, then b_cap
@@ -149,7 +144,7 @@ def rec_widths(b_cap: int, c: int) -> tuple:
 
 
 def _recover_scan(
-    t: LiftingTables,
+    euler: EulerLCA,
     u: jax.Array,
     v: jax.Array,
     beta: jax.Array,
@@ -161,17 +156,14 @@ def _recover_scan(
     dirty0: jax.Array,
     budget: jax.Array,
     b_cap: int,
-    use_tree_kernel: bool = False,
     chunk: int = 32,
-    euler=None,
     batched: bool = False,
 ):
     """The device replay: a chunked two-level lax.scan over rank slots.
 
-    `euler`: optional lca.EulerLCA tables — when given (the fused
-    program passes the ones it already built for chunked marking), the
-    per-block cover tables answer each distance in O(1) gathers instead
-    of O(log n) lifting climbs; decisions are identical integers.
+    `euler`: the lca.EulerLCA tables the per-block cover tables read
+    (the fused program passes the ones it built for marking), O(1)
+    gathers a distance.
 
     `order` is a full (L,) permutation — (crit desc, id asc) with tree /
     padding slots forced to -inf keys, so they trail every off-tree edge
@@ -244,9 +236,9 @@ def _recover_scan(
         the (C, C) block columns)."""
         buf_u, buf_v, buf_b, buf_nc, cnt, bx, by, bb = ops
         tbl = ball_pair_table(
-            t, bx, by, jnp.concatenate([buf_u[:w], bx]),
+            euler, bx, by, jnp.concatenate([buf_u[:w], bx]),
             jnp.concatenate([buf_v[:w], by]),
-            jnp.concatenate([buf_b[:w], bb]), use_tree_kernel, euler)
+            jnp.concatenate([buf_b[:w], bb]))
         pre = tbl[:, :w] & (jnp.arange(w, dtype=jnp.int32) < cnt)
         return (jnp.any(pre, axis=1), jnp.any(pre & buf_nc[:w], axis=1),
                 tbl[:, w:])
@@ -346,9 +338,7 @@ def _euler_from_lifting(up: jax.Array, depth_t: jax.Array):
     return build_euler(parent, depth_t, root, n)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("b_cap", "use_tree_kernel", "chunk",
-                                    "use_euler_lca"))
+@functools.partial(jax.jit, static_argnames=("b_cap", "chunk"))
 def recover_device(
     up: jax.Array,
     depth_t: jax.Array,
@@ -364,9 +354,7 @@ def recover_device(
     budget: jax.Array,
     b_cap: int,
     edge_valid: jax.Array | None = None,
-    use_tree_kernel: bool = False,
     chunk: int = 32,
-    use_euler_lca: bool = True,
 ):
     """Standalone jitted recovery tail (the unit bench_recovery.py times).
 
@@ -374,28 +362,20 @@ def recover_device(
     full (L,) sort permutation and `budget` is a device scalar. Returns
     (accepted (L,) bool, n_accepted int32 scalar).
 
-    use_euler_lca (default on) reconstructs the tree from `up[0]` and
-    builds the Euler-tour O(1)-LCA tables on device, so the cover
-    tables stop climbing the lifting tables — the same backend the
-    fused `lgrass_device` replay uses (decisions are identical
-    integers; parity vs `recover_host` in tests/test_recovery_device.py).
-    The Pallas kernel path takes precedence, as everywhere else.
+    The tree is reconstructed from `up[0]` and the Euler-tour O(1)-LCA
+    tables built on device, the backend the fused `lgrass_device`
+    replay reads (decisions are identical integers; parity vs
+    `recover_host` in tests/test_recovery_device.py).
     """
-    t = LiftingTables(up=up, depth=depth_t)
-    euler = None
-    if use_euler_lca and not use_tree_kernel:
-        euler = _euler_from_lifting(up, depth_t)
     offtree = ~tree_mask if edge_valid is None else (~tree_mask) & edge_valid
     return _recover_scan(
-        t, u, v, beta, offtree, crossing, order, phase1_accept,
-        group_of_edge, dirty0, jnp.asarray(budget, jnp.int32), b_cap,
-        use_tree_kernel, chunk, euler,
+        _euler_from_lifting(up, depth_t), u, v, beta, offtree, crossing,
+        order, phase1_accept, group_of_edge, dirty0,
+        jnp.asarray(budget, jnp.int32), b_cap, chunk,
     )[:2]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("b_cap", "use_tree_kernel", "chunk",
-                                    "use_euler_lca"))
+@functools.partial(jax.jit, static_argnames=("b_cap", "chunk"))
 def recover_device_batched(
     up: jax.Array,
     depth_t: jax.Array,
@@ -411,9 +391,7 @@ def recover_device_batched(
     budget: jax.Array,
     b_cap: int,
     edge_valid: jax.Array | None = None,
-    use_tree_kernel: bool = False,
     chunk: int = 32,
-    use_euler_lca: bool = True,
 ):
     """`recover_device` vmapped over a leading batch axis.
 
@@ -427,13 +405,10 @@ def recover_device_batched(
     """
     def one(bup, bdep, bu, bv, bbeta, btree, bcross, border, bacc, bgrp,
             bdirty, bb, bev):
-        t = LiftingTables(up=bup, depth=bdep)
-        euler = None
-        if use_euler_lca and not use_tree_kernel:
-            euler = _euler_from_lifting(bup, bdep)
         return _recover_scan(
-            t, bu, bv, bbeta, (~btree) & bev, bcross, border, bacc, bgrp,
-            bdirty, bb, b_cap, use_tree_kernel, chunk, euler, batched=True,
+            _euler_from_lifting(bup, bdep), bu, bv, bbeta, (~btree) & bev,
+            bcross, border, bacc, bgrp, bdirty, bb, b_cap, chunk,
+            batched=True,
         )[:2]
 
     if edge_valid is None:  # all-true mask ≡ the unmasked offtree

@@ -20,7 +20,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.lca import LiftingTables, lca, tree_distance_with_lca
+from repro.core.lca import LiftingTables
 
 
 class ResistanceTables(NamedTuple):

@@ -1,12 +1,15 @@
 """LGRASS end-to-end pipeline (Fig. 1b/1c): the public sparsifier API.
 
-    EFF  -> graph BFS + depth-scaled effective weights      (bfs.py)
+    EFF  -> hop-doubling graph BFS + depth-scaled weights   (bfs.py)
     MST  -> Borůvka maximum spanning tree                   (mst.py)
-    LCA  -> binary lifting + root-subtree shortcut          (lca.py)
+    LCA  -> Euler-tour rooting + O(1) sparse-table LCA      (bfs.py, lca.py)
     RES  -> root-path resistance sums -> criticality        (resistance.py)
     SORT -> 4-pass radix sort on IEEE-754 keys              (sort.py)
-    MARK -> per-group greedy, basic or lockstep-parallel    (marking.py)
+    MARK -> per-group greedy in blocks of sorted slots      (marking.py)
     REC  -> greedy replay of non-crossing edges             (recovery.py)
+
+Each stage has one engine; the block sizes (`p1_chunk`, `chunk`) and
+the buffer bounds (`k_cap`, `b_cap`) change no decision.
 
 All stages are jit-compiled device programs. `lgrass_device` fuses the
 whole pipeline — phase 1 *and* the Algorithm-6 recovery replay — into a
@@ -37,7 +40,6 @@ import numpy as np
 from repro.core import _host as H
 from repro.core.baseline import default_budget
 from repro.core.bfs import (
-    bfs,
     bfs_counted,
     effective_weights,
     finite_depth,
@@ -45,25 +47,15 @@ from repro.core.bfs import (
     select_root,
 )
 from repro.core.graph import Graph
-from repro.core.lca import (
-    LiftingTables,
-    build_euler,
-    build_lifting,
-    lca_euler,
-    lca_with_shortcut,
-)
+from repro.core.lca import build_lifting, lca_euler
 from repro.core.marking import (
-    GroupLayout,
-    Phase1Result,
     build_group_layout,
     group_keys,
-    phase1_basic,
+    phase1_chunked,
     phase1_edge_views,
-    phase1_parallel,
-    run_phase1,
 )
 from repro.core.mst import boruvka_mst_counted
-from repro.core.pow2 import log2_ceil, next_pow2
+from repro.core.pow2 import auto_chunk, next_pow2
 from repro.core.recovery import _recover_scan, recover_host
 from repro.core.resistance import (
     criticality,
@@ -80,13 +72,15 @@ B_CAP_FLOOR = 8
 STAGES = ("EFF", "MST", "LCA", "RES", "SORT", "MARK", "REC")
 
 # The device programs' while loops, in the order of their `loop_rounds`
-# vector: the graph BFS (EFF); the tree BFS (LCA, "levels" engine only,
-# 0 under "doubling"); the Borůvka rounds and the pointer-jumping rounds
-# of their contractions, summed (MST); the MARK schedule's steps; the
-# recovery replay's blocks (REC, 0 where the host replays). Each loop
-# but "mst_jump", which runs inside "mst", is called under a named
-# scope of its own name, so its `while` instruction's op_name ends in
-# "<loop>/while" (jit(...) components may come between).
+# vector: the graph BFS (EFF); "tree", a slot that stays 0 (the tree is
+# rooted by its Euler tour, with no loop; the slot is kept while
+# chipbench/test_chipbench_stages.py pins this set); the Borůvka rounds
+# and the pointer-jumping rounds of their contractions, summed (MST);
+# the MARK schedule's steps; the recovery replay's blocks (REC, 0 where
+# the host replays). Each loop but "mst_jump", which runs inside "mst",
+# is called under a named scope of its own name, so its `while`
+# instruction's op_name ends in "<loop>/while" (jit(...) components may
+# come between).
 LOOPS = ("bfs", "tree", "mst", "mst_jump", "mark", "rec")
 
 
@@ -125,14 +119,8 @@ def _phase1_program(
     w: jax.Array,
     n: int,
     k_cap: int,
-    parallel: bool,
-    lift_levels: int | None,
     edge_valid: jax.Array | None,
-    schedule: str = "chunked",
     p1_chunk: int | None = None,
-    use_euler_lca: bool = True,
-    use_tree_kernel: bool = False,
-    bfs_engine: str = "doubling",
 ):
     """EFF→MST→LCA→RES→SORT→MARK(phase 1), optionally padding-masked.
 
@@ -141,35 +129,19 @@ def _phase1_program(
     is threaded so padding edges can never enter the tree or a crossing
     group, and all real-slot outputs are bit-identical to an unpadded run
     of the same graph (binary-lifting depth only grows with n, and extra
-    levels are provable no-ops for both LCA climbs and root-path sums).
+    levels are provable no-ops for root-path sums and subtree keys).
 
-    schedule/p1_chunk select the MARK engine (marking.run_phase1):
-    "chunked" (default, block size p1_chunk or auto-pow2 ~sqrt(L)) or
-    "scan" (the legacy engines; `parallel` picks lockstep vs basic). All
-    schedules are bit-identical. use_euler_lca additionally builds the
-    Euler-tour O(1)-LCA tables once and backs the chunked cover tables
-    with them; use_tree_kernel routes those tables through the Pallas
-    tree-distance kernel instead.
-
-    bfs_engine picks the two traversal passes' implementation
-    (bfs.py): "doubling" (default) runs the graph pass as the
-    O(log n)-round hop-doubling engine and replaces the tree pass with
-    the Euler-tour rooting (`root_tree_euler` — no BFS at all);
-    "levels" keeps both passes level-synchronous. On the pipeline's
-    legal inputs (connected graphs, graph.py's contract) outputs are
-    bit-identical and this is purely a performance knob
-    (tests/test_bfs_doubling.py; diameter-bound feeder chains are
-    where "doubling" wins). The BFS engines themselves agree on ANY
-    input including disconnected forests, but downstream LCA values
-    for *unreachable* endpoints are backend-dependent garbage under
-    every backend, so full-pipeline parity is only promised where the
-    pipeline is defined.
+    The graph BFS is the hop-doubling engine (bfs.py): exact on any
+    input, O(log n) rounds on chain-like ones. The tree is rooted by
+    its Euler tour (`root_tree_euler`), which also gives the O(1)-LCA
+    tables the cover tables of MARK and REC read. MARK runs
+    `phase1_chunked` in blocks of p1_chunk slots (an auto pow2 ~sqrt(L)
+    when None); every block size gives the same decisions.
     """
     with jax.named_scope("EFF"):
         root = select_root(u, v, n, edge_valid)
         with jax.named_scope("bfs"):
-            depth_g, _, bfs_rounds = bfs_counted(u, v, n, root,
-                                                 edge_valid, bfs_engine)
+            depth_g, _, bfs_rounds = bfs_counted(u, v, n, root, edge_valid)
         eff = effective_weights(u, v, w, depth_g, n, edge_valid)
 
     with jax.named_scope("MST"):
@@ -184,33 +156,13 @@ def _phase1_program(
                 u, v, rank_eff, n, edge_valid)
     rounds = dict(bfs=bfs_rounds, mst=mst_rounds, mst_jump=jump_rounds)
 
-    # the Pallas kernel path takes precedence inside ball_pair_table, so
-    # skip the (then-unused) Euler build when it is selected. Built for
-    # ANY schedule: the fused recovery replay consumes it too.
-    want_euler = use_euler_lca and not use_tree_kernel
-    euler = None
     with jax.named_scope("LCA"):
-        if bfs_engine == "doubling":
-            # exact O(log n) tree rooting via the Euler tour — the tree's
-            # depth/parent are unique, so no fixpoint iteration is
-            # needed; the rooted tour doubles as the O(1)-LCA tables (no
-            # second tour construction via build_euler)
-            depth_t, parent_t, euler = root_tree_euler(
-                u, v, n, root, tree_mask, with_euler=want_euler)
-        else:
-            with jax.named_scope("tree"):
-                depth_t, parent_t, rounds["tree"] = bfs_counted(
-                    u, v, n, root, tree_mask, bfs_engine)
-            if want_euler:
-                euler = build_euler(parent_t, depth_t, root, n)
-        t = build_lifting(parent_t, depth_t, n, levels=lift_levels)
-        if euler is not None:
-            # O(1) gathers per edge instead of L-wide lifting climbs; the
-            # LCA of two reachable nodes is backend-independent, so every
-            # downstream value is bit-identical
-            elca = lca_euler(euler, u, v)
-        else:
-            elca = lca_with_shortcut(t, root, u, v)
+        # exact O(log n) tree rooting via the Euler tour — the tree's
+        # depth/parent are unique, so no fixpoint iteration is needed;
+        # the rooted tour doubles as the O(1)-LCA tables
+        depth_t, parent_t, euler = root_tree_euler(u, v, n, root, tree_mask)
+        t = build_lifting(parent_t, depth_t, n)
+        elca = lca_euler(euler, u, v)
 
     with jax.named_scope("RES"):
         inv_w = node_parent_inv_w(u, v, w, tree_mask, parent_t, n)
@@ -228,10 +180,9 @@ def _phase1_program(
         su, sv, sbeta = u[layout.perm], v[layout.perm], beta[layout.perm]
 
     with jax.named_scope("MARK"), jax.named_scope("mark"):
-        p1 = run_phase1(t, su, sv, sbeta, layout, k_cap=k_cap,
-                        schedule=schedule, parallel=parallel,
-                        chunk=p1_chunk, use_tree_kernel=use_tree_kernel,
-                        euler=euler if schedule == "chunked" else None)
+        c = auto_chunk(int(su.shape[0])) if p1_chunk is None else p1_chunk
+        p1 = phase1_chunked(euler, su, sv, sbeta, layout, k_cap=k_cap,
+                            chunk=c)
     rounds["mark"] = p1.rounds
     d = dict(
         tree_mask=tree_mask,
@@ -250,33 +201,21 @@ def _phase1_program(
     return d, euler, rounds
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n", "k_cap", "parallel", "lift_levels",
-                                    "schedule", "p1_chunk", "use_euler_lca",
-                                    "use_tree_kernel", "bfs_engine"))
+@functools.partial(jax.jit, static_argnames=("n", "k_cap", "p1_chunk"))
 def phase1_device(
     u: jax.Array,
     v: jax.Array,
     w: jax.Array,
     n: int,
     k_cap: int = 32,
-    parallel: bool = True,
-    lift_levels: int | None = None,
-    schedule: str = "chunked",
     p1_chunk: int | None = None,
-    use_euler_lca: bool = True,
-    use_tree_kernel: bool = False,
-    bfs_engine: str = "doubling",
 ):
     """The phase-1 device program: EFF→MST→LCA→RES→SORT→MARK.
 
     Returns everything the host recovery tail needs, and the phase-1
-    loops' `loop_rounds`. This function is the unit the multi-pod dry-run
-    lowers and compiles.
+    loops' `loop_rounds`.
     """
-    return _phase1_outputs(u, v, w, n, k_cap, parallel, lift_levels, None,
-                           schedule, p1_chunk, use_euler_lca,
-                           use_tree_kernel, bfs_engine)
+    return _phase1_outputs(u, v, w, n, k_cap, None, p1_chunk)
 
 
 def _phase1_outputs(*args):
@@ -287,10 +226,7 @@ def _phase1_outputs(*args):
     return d
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n", "k_cap", "parallel", "lift_levels",
-                                    "schedule", "p1_chunk", "use_euler_lca",
-                                    "use_tree_kernel", "bfs_engine"))
+@functools.partial(jax.jit, static_argnames=("n", "k_cap", "p1_chunk"))
 def phase1_device_batched(
     u: jax.Array,
     v: jax.Array,
@@ -298,13 +234,7 @@ def phase1_device_batched(
     edge_valid: jax.Array,
     n: int,
     k_cap: int = 32,
-    parallel: bool = True,
-    lift_levels: int | None = None,
-    schedule: str = "chunked",
     p1_chunk: int | None = None,
-    use_euler_lca: bool = True,
-    use_tree_kernel: bool = False,
-    bfs_engine: str = "doubling",
 ):
     """`phase1_device` vmapped over a leading batch axis.
 
@@ -314,10 +244,7 @@ def phase1_device_batched(
     """
     return jax.vmap(
         lambda bu, bv, bw, bev: _phase1_outputs(
-            bu, bv, bw, n, k_cap, parallel, lift_levels, bev,
-            schedule, p1_chunk, use_euler_lca, use_tree_kernel,
-            bfs_engine
-        )
+            bu, bv, bw, n, k_cap, bev, p1_chunk)
     )(u, v, w, edge_valid)
 
 
@@ -328,16 +255,10 @@ def _lgrass_program(
     budget: jax.Array,
     n: int,
     k_cap: int,
-    parallel: bool,
-    lift_levels: int | None,
     b_cap: int,
     edge_valid: jax.Array | None,
-    use_tree_kernel: bool,
     chunk: int = 32,
-    schedule: str = "chunked",
     p1_chunk: int | None = None,
-    use_euler_lca: bool = True,
-    bfs_engine: str = "doubling",
     batched: bool = False,
 ):
     """Phase 1 + device recovery fused into one program (Fig. 1b end-to-end).
@@ -349,9 +270,7 @@ def _lgrass_program(
     device. `batched` marks the trace under vmap (`_recover_scan`).
     """
     d, euler, rounds = _phase1_program(
-        u, v, w, n, k_cap, parallel, lift_levels, edge_valid, schedule,
-        p1_chunk, use_euler_lca, use_tree_kernel, bfs_engine)
-    t = LiftingTables(up=d["up"], depth=d["depth_t"])
+        u, v, w, n, k_cap, edge_valid, p1_chunk)
     tree_mask = d["tree_mask"]
     crossing = d["crossing"]
     with jax.named_scope("MARK"):
@@ -367,10 +286,9 @@ def _lgrass_program(
     with jax.named_scope("REC"):
         with jax.named_scope("rec"):
             accepted, n_accepted, rounds["rec"], table_cols = _recover_scan(
-                t, u, v, d["beta"], offtree, crossing, order,
+                euler, u, v, d["beta"], offtree, crossing, order,
                 accept_by_edge, group_of_edge, dirty0,
-                jnp.asarray(budget, jnp.int32), b_cap, use_tree_kernel,
-                chunk, euler, batched,
+                jnp.asarray(budget, jnp.int32), b_cap, chunk, batched,
             )
         depth_fin = finite_depth(d["depth_t"])
         return dict(
@@ -388,10 +306,8 @@ def _lgrass_program(
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n", "k_cap", "parallel", "lift_levels",
-                                    "b_cap", "use_tree_kernel", "chunk",
-                                    "schedule", "p1_chunk", "use_euler_lca",
-                                    "bfs_engine"))
+                   static_argnames=("n", "k_cap", "b_cap", "chunk",
+                                    "p1_chunk"))
 def lgrass_device(
     u: jax.Array,
     v: jax.Array,
@@ -399,15 +315,9 @@ def lgrass_device(
     budget: jax.Array,
     n: int,
     k_cap: int = 32,
-    parallel: bool = True,
-    lift_levels: int | None = None,
     b_cap: int = B_CAP_FLOOR,
-    use_tree_kernel: bool = False,
     chunk: int = 32,
-    schedule: str = "chunked",
     p1_chunk: int | None = None,
-    use_euler_lca: bool = True,
-    bfs_engine: str = "doubling",
 ):
     """The full device program: phase 1 fused with the recovery replay.
 
@@ -416,9 +326,8 @@ def lgrass_device(
     stats and the `loop_rounds` vector only — the first point data
     leaves the device.
     """
-    return _lgrass_program(u, v, w, budget, n, k_cap, parallel,
-                           lift_levels, b_cap, None, use_tree_kernel, chunk,
-                           schedule, p1_chunk, use_euler_lca, bfs_engine)
+    return _lgrass_program(u, v, w, budget, n, k_cap, b_cap, None, chunk,
+                           p1_chunk)
 
 
 def _lgrass_batched_impl(
@@ -429,28 +338,19 @@ def _lgrass_batched_impl(
     budget: jax.Array,
     n: int,
     k_cap: int = 32,
-    parallel: bool = True,
-    lift_levels: int | None = None,
     b_cap: int = B_CAP_FLOOR,
-    use_tree_kernel: bool = False,
     chunk: int = 32,
-    schedule: str = "chunked",
     p1_chunk: int | None = None,
-    use_euler_lca: bool = True,
-    bfs_engine: str = "doubling",
 ):
     return jax.vmap(
         lambda bu, bv, bw, bev, bb: _lgrass_program(
-            bu, bv, bw, bb, n, k_cap, parallel, lift_levels, b_cap, bev,
-            use_tree_kernel, chunk, schedule, p1_chunk, use_euler_lca,
-            bfs_engine, batched=True,
+            bu, bv, bw, bb, n, k_cap, b_cap, bev, chunk, p1_chunk,
+            batched=True,
         )
     )(u, v, w, edge_valid, budget)
 
 
-_BATCHED_STATICS = ("n", "k_cap", "parallel", "lift_levels", "b_cap",
-                    "use_tree_kernel", "chunk", "schedule", "p1_chunk",
-                    "use_euler_lca", "bfs_engine")
+_BATCHED_STATICS = ("n", "k_cap", "b_cap", "chunk", "p1_chunk")
 
 lgrass_device_batched = jax.jit(
     _lgrass_batched_impl, static_argnames=_BATCHED_STATICS)
@@ -501,16 +401,10 @@ def lgrass_sparsify(
     g: Graph,
     budget: Optional[int] = None,
     k_cap: int = 32,
-    parallel: bool = True,
-    auto_lift_bound: bool = False,
     recovery: str = "device",
     b_cap: Optional[int] = None,
-    use_tree_kernel: bool = False,
     chunk: int = 32,
-    schedule: str = "chunked",
     p1_chunk: Optional[int] = None,
-    use_euler_lca: bool = True,
-    bfs_engine: str = "doubling",
 ) -> SparsifyResult:
     """Run LGRASS on a host graph; returns the sparsifier edge mask.
 
@@ -519,24 +413,9 @@ def lgrass_sparsify(
     Algorithm 6 with the numpy oracle (`recover_host`). Both are
     bit-identical (tests/test_recovery_device.py).
 
-    schedule/p1_chunk: the phase-1 marking engine — "chunked" (default;
-    block size p1_chunk, or an auto pow2 ~sqrt(L)) or "scan" (legacy
-    per-slot engines, `parallel` picking lockstep vs basic). All
-    schedules are bit-identical (tests/test_marking_chunked.py);
-    use_euler_lca (default on) backs the chunked cover tables with the
-    Euler-tour O(1) LCA built once per graph — measured faster than the
-    lifting climbs at every size on CPU, including the build.
-
-    bfs_engine: the traversal engine for both BFS passes — "doubling"
-    (default: hop-doubling graph BFS + Euler-tour tree rooting,
-    O(log n) rounds on chain-like inputs) or "levels" (the legacy
-    level-synchronous passes). Bit-identical outputs
-    (tests/test_bfs_doubling.py); benchmarks/bench_bfs.py measures the
-    difference on the diameter-bound feeder family.
-
-    auto_lift_bound: measure the tree depth first (one extra BFS) and
-    build depth-bounded lifting tables — identical output, ~log(N)/log(D)
-    less LCA gather traffic (§Perf 'lift_bound').
+    p1_chunk: the MARK block size (default: an auto pow2 ~sqrt(L));
+    chunk: the recovery replay's block size. Neither changes a decision
+    (tests/test_marking_chunked.py).
 
     b_cap: static accept-buffer bound for device recovery; defaults to a
     pow2 bucket of `budget` so nearby budgets share compiled programs.
@@ -545,48 +424,20 @@ def lgrass_sparsify(
     budget = default_budget(n) if budget is None else int(budget)
     if recovery == "device":
         fn, args, kwargs = lgrass_program(
-            g, budget, b_cap=b_cap, k_cap=k_cap, parallel=parallel,
-            use_tree_kernel=use_tree_kernel, chunk=chunk,
-            schedule=schedule, p1_chunk=p1_chunk,
-            use_euler_lca=use_euler_lca, bfs_engine=bfs_engine)
+            g, budget, b_cap=b_cap, k_cap=k_cap, chunk=chunk,
+            p1_chunk=p1_chunk)
     elif recovery == "host":
         with jax.profiler.TraceAnnotation("lgrass.upload"):
             args = (jnp.asarray(g.u, jnp.int32), jnp.asarray(g.v, jnp.int32),
                     jnp.asarray(g.w, jnp.float32))
-        fn, kwargs = phase1_device, dict(
-            n=n, k_cap=k_cap, parallel=parallel, schedule=schedule,
-            p1_chunk=p1_chunk, use_euler_lca=use_euler_lca,
-            use_tree_kernel=use_tree_kernel, bfs_engine=bfs_engine)
+        fn, kwargs = phase1_device, dict(n=n, k_cap=k_cap, p1_chunk=p1_chunk)
     else:
         raise ValueError(f"unknown recovery mode {recovery!r}")
 
-    lift_levels = None
-    if auto_lift_bound:
-        # estimate from graph BFS depth ×4 (tree paths stretch); the
-        # post-hoc check below guarantees correctness regardless.
-        u, v = args[0], args[1]
-        root = select_root(u, v, n)
-        depth_g, _ = bfs(u, v, n, root, engine=bfs_engine)
-        # finite_depth: unreachable (INF) depths must not inflate the
-        # estimate — the shared bfs.py guard, not an ad-hoc mask
-        dmax = int(jax.device_get(jnp.max(finite_depth(depth_g))))
-        safe = 1
-        while (1 << safe) <= 4 * max(dmax, 1):
-            safe += 1
-        lift_levels = min(safe, log2_ceil(n + 1))
-
-    kwargs["lift_levels"] = lift_levels
-    while True:
-        with jax.profiler.TraceAnnotation("lgrass.dispatch"):
-            out = fn(*args, **kwargs)
-        with jax.profiler.TraceAnnotation("lgrass.fetch"):
-            d = jax.device_get(out)
-        lift = kwargs["lift_levels"]
-        depth_max = (d["tree_depth_max"] if recovery == "device"
-                     else d["depth_t"].max())
-        if lift is None or int(depth_max) < (1 << lift):
-            break
-        kwargs["lift_levels"] = None  # bound violated: redo safely
+    with jax.profiler.TraceAnnotation("lgrass.dispatch"):
+        out = fn(*args, **kwargs)
+    with jax.profiler.TraceAnnotation("lgrass.fetch"):
+        d = jax.device_get(out)
     if recovery == "host":
         return _recovery_tail(g, d, budget)
     with jax.profiler.TraceAnnotation("lgrass.unpack"):
@@ -597,15 +448,9 @@ def lgrass_program(
     g: Graph,
     budget: Optional[int] = None,
     b_cap: Optional[int] = None,
-    lift_levels: Optional[int] = None,
     k_cap: int = 32,
-    parallel: bool = True,
-    use_tree_kernel: bool = False,
     chunk: int = 32,
-    schedule: str = "chunked",
     p1_chunk: Optional[int] = None,
-    use_euler_lca: bool = True,
-    bfs_engine: str = "doubling",
 ):
     """The fused dispatch `lgrass_sparsify(g, budget, ...)` makes with
     recovery="device": (jitted program, device arguments, static keyword
@@ -620,11 +465,8 @@ def lgrass_program(
     with jax.profiler.TraceAnnotation("lgrass.upload"):
         args = (jnp.asarray(g.u, jnp.int32), jnp.asarray(g.v, jnp.int32),
                 jnp.asarray(g.w, jnp.float32), jnp.int32(budget))
-    kwargs = dict(n=g.n, k_cap=k_cap, parallel=parallel,
-                  lift_levels=lift_levels, b_cap=b_cap,
-                  use_tree_kernel=use_tree_kernel, chunk=chunk,
-                  schedule=schedule, p1_chunk=p1_chunk,
-                  use_euler_lca=use_euler_lca, bfs_engine=bfs_engine)
+    kwargs = dict(n=g.n, k_cap=k_cap, b_cap=b_cap, chunk=chunk,
+                  p1_chunk=p1_chunk)
     return lgrass_device, args, kwargs
 
 
@@ -706,15 +548,10 @@ def lgrass_sparsify_batch(
     graphs,
     budget: Optional[int] = None,
     k_cap: int = 32,
-    parallel: bool = True,
     recovery: str = "device",
     b_cap: Optional[int] = None,
-    use_tree_kernel: bool = False,
     chunk: int = 32,
-    schedule: str = "chunked",
     p1_chunk: Optional[int] = None,
-    use_euler_lca: bool = True,
-    bfs_engine: str = "doubling",
 ) -> list:
     """Run LGRASS on many graphs with ONE device compile + dispatch.
 
@@ -755,15 +592,9 @@ def lgrass_sparsify_batch(
             jnp.asarray(np.asarray(budgets, np.int32)),
             batch.n_max,
             k_cap,
-            parallel,
-            None,
             b_cap,
-            use_tree_kernel,
             chunk,
-            schedule,
             p1_chunk,
-            use_euler_lca,
-            bfs_engine,
         ))
         return [_result_from_device(d, i, g.m)
                 for i, g in enumerate(batch.graphs)]
@@ -777,13 +608,7 @@ def lgrass_sparsify_batch(
         jnp.asarray(batch.edge_valid, bool),
         batch.n_max,
         k_cap,
-        parallel,
-        None,
-        schedule,
         p1_chunk,
-        use_euler_lca,
-        use_tree_kernel,
-        bfs_engine,
     ))
     results = []
     for i, (g, b) in enumerate(zip(batch.graphs, budgets)):

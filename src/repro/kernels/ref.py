@@ -51,15 +51,3 @@ def laplacian_spmv_ref(u: jax.Array, v: jax.Array, w: jax.Array,
     d = x[u] - x[v]
     c = w.astype(x.dtype)[:, None] * d
     return jnp.zeros_like(x).at[u].add(c).at[v].add(-c)
-
-
-def tree_dist_pairs_ref(up: jax.Array, depth: jax.Array, a: jax.Array,
-                        b: jax.Array) -> jax.Array:
-    """Binary-lifting tree distance: the kernel's ground truth IS the
-    production plain-gather formulation (core/lca.py), so the kernel is
-    validated against the exact code the pipeline runs — one algorithm,
-    two executions."""
-    from repro.core.lca import LiftingTables, tree_distance
-
-    return tree_distance(LiftingTables(up=up, depth=depth),
-                         a.astype(jnp.int32), b.astype(jnp.int32))

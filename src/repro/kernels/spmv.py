@@ -6,7 +6,7 @@ of C edges builds the signed incidence slab Sᵀ = onehot(u) − onehot(v)
 ((n, C), VPU compares), and two MXU matmuls do the gather AND the
 scatter: dᵀ = xᵀ Sᵀ pulls both endpoints' probe rows in one contraction,
 and accᵀ += (w ⊙ dᵀ) S pushes the weighted differences back — no
-data-dependent addressing anywhere (the one-hot idiom of tree_dist.py).
+data-dependent addressing anywhere.
 The (P, n) accumulator lives in VMEM scratch across the sequential grid
 and flushes once on the last block. Zero-weight columns (edge padding,
 masked batch slots) contribute exactly nothing, so the caller only has

@@ -25,7 +25,6 @@ are the inputs to benchmarks/roofline.py and EXPERIMENTS.md.
 Usage:
     python -m repro.launch.dryrun --all [--mesh both] [--force]
     python -m repro.launch.dryrun --arch mamba2-370m --shape train_4k
-    python -m repro.launch.dryrun --lgrass            # paper's own cells
 """
 import argparse
 import json
@@ -298,94 +297,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     return rec
 
 
-def run_lgrass_cell(case_name: str, multi_pod: bool, outdir: str,
-                    force: bool = False, k_cap: int = 32,
-                    lift_levels: Optional[int] = None,
-                    tag_suffix: str = "") -> Optional[Dict]:
-    """Dry-run of the paper's own workload: distributed phase-1 marking.
-
-    k_cap: accept-table width (correctness-neutral; recovery rechecks
-    overflowed groups). lift_levels: depth-bounded lifting-table height —
-    the host pipeline computes ceil(log2(max_depth+1)) from the tree BFS
-    and slices the (LOG, n) table before dispatch; dry-run cells take it
-    as a parameter (§Perf opt 'lift_bound').
-    """
-    import jax
-    import jax.numpy as jnp
-    from repro.configs.lgrass import CASES
-    from repro.core.distributed import make_phase1_sharded
-    from repro.launch.mesh import (HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16,
-                                   make_production_mesh)
-
-    mesh_name = "multipod512" if multi_pod else "pod256"
-    tag = f"lgrass_{case_name}_{mesh_name}{tag_suffix}"
-    path = os.path.join(outdir, f"{tag}.json")
-    if os.path.exists(path) and not force:
-        with open(path) as f:
-            return json.load(f)
-
-    t0 = time.time()
-    case = CASES[case_name]
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    shard_axes = tuple(mesh.axis_names)
-    n_shards = 1
-    for a in shard_axes:
-        n_shards *= mesh.shape[a]
-    n, L = case.n_nodes, case.n_edges
-    log = lift_levels or max(1, (n + 1).bit_length())
-    lloc = (L + n_shards - 1) // n_shards
-    total = lloc * n_shards
-
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    rep = NamedSharding(mesh, P())
-    shd = NamedSharding(mesh, P(shard_axes))
-    sds = lambda shp, dt, sh: jax.ShapeDtypeStruct(shp, dt, sharding=sh)
-
-    fn = make_phase1_sharded(mesh, shard_axes, k_cap=k_cap)
-    lowered = fn.lower(
-        sds((log, n), jnp.int32, rep),
-        sds((n,), jnp.int32, rep),
-        sds((total,), jnp.int32, shd),
-        sds((total,), jnp.int32, shd),
-        sds((total,), jnp.int32, shd),
-        sds((total,), jnp.int32, shd),
-        sds((total,), jnp.int32, shd),
-        sds((total,), jnp.bool_, shd),
-    )
-    compiled = lowered.compile()
-    from repro.launch.hlo_analysis import analyze
-    mem = compiled.memory_analysis()
-    hlo = analyze(compiled.as_text())
-    chips = 512 if multi_pod else 256
-    flops = float(hlo["flops"])
-    bytes_ = float(hlo["mem_bytes"])
-    coll_bytes = float(hlo["collective_bytes"])
-    rec = dict(
-        cell=tag, arch="lgrass", shape=case_name, mesh=mesh_name,
-        kind="sparsify", chips=chips, k_cap=k_cap, lift_levels=log,
-        compile_s=round(time.time() - t0, 1),
-        hlo_flops_per_device=flops, hlo_bytes_per_device=bytes_,
-        collective_bytes_per_device=coll_bytes,
-        collectives=hlo["collective_by_kind"],
-        memory=dict(
-            argument_bytes=int(mem.argument_size_in_bytes),
-            output_bytes=int(mem.output_size_in_bytes),
-            temp_bytes=int(mem.temp_size_in_bytes),
-            code_bytes=int(mem.generated_code_size_in_bytes)),
-        t_compute_s=flops / PEAK_FLOPS_BF16,
-        t_memory_s=bytes_ / HBM_BW,
-        t_collective_s=coll_bytes / ICI_BW_PER_LINK,
-        dominant="memory" if bytes_ / HBM_BW > coll_bytes / ICI_BW_PER_LINK
-        else "collective",
-    )
-    os.makedirs(outdir, exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(rec, f, indent=2)
-    print(f"[dryrun] {tag}: ok in {rec['compile_s']}s "
-          f"bytes/dev={bytes_:.3e}")
-    return rec
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
@@ -393,7 +304,6 @@ def main(argv=None):
     ap.add_argument("--mesh", choices=["single", "multi", "both"],
                     default="both")
     ap.add_argument("--all", action="store_true")
-    ap.add_argument("--lgrass", action="store_true")
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--out", default=os.path.abspath(ARTIFACT_DIR))
     args = ap.parse_args(argv)
@@ -403,11 +313,6 @@ def main(argv=None):
               "both": [False, True]}[args.mesh]
 
     cells = []
-    if args.lgrass or args.all:
-        from repro.configs.lgrass import CASES
-        for c in CASES:
-            for mp in meshes:
-                cells.append(("lgrass", c, mp))
     if args.all:
         for a in ARCHS:
             for s in SHAPES:
@@ -422,10 +327,7 @@ def main(argv=None):
     failures = []
     for a, s, mp in cells:
         try:
-            if a == "lgrass":
-                run_lgrass_cell(s, mp, args.out, args.force)
-            else:
-                run_cell(a, s, mp, args.out, args.force)
+            run_cell(a, s, mp, args.out, args.force)
         except Exception as e:
             failures.append((a, s, mp, repr(e)))
             print(f"[dryrun] {a}_{s}_{'multi' if mp else 'single'}: "

@@ -22,17 +22,11 @@ This module adds the traffic-facing policy:
     with trivial placeholder graphs (dropped from the results); chunk
     sizes 5, 7, 12 share the B=8/8/16 programs instead of compiling
     three times.
-  * **schedule policy** — the phase-1 marking engine is a per-service
-    config (`schedule="chunked"` by default) and its block size is
-    resolved *per bucket* from the padded edge count
-    (`core.pow2.auto_chunk`), so every graph in a bucket shares one
-    compiled block size and `warmup` compiles exactly the programs
+  * **block-size policy** — the phase-1 marking block size is a
+    compiled-program key, resolved *per bucket* from the padded edge
+    count (`core.pow2.auto_chunk`), so every graph in a bucket shares
+    one compiled block size and `warmup` compiles exactly the programs
     traffic will request.
-  * **BFS-engine policy** — the traversal engine (`bfs_engine=
-    "doubling"` by default: hop-doubling graph BFS + Euler-tour tree
-    rooting, O(log n) rounds on diameter-bound inputs) is a compiled-
-    program key like the block size, resolved per bucket through one
-    hook (`_bfs_engine`) that both the request path and `warmup` use.
   * **warmup** — `warmup(sizes)` pre-compiles the bucket programs for
     anticipated request shapes off the request path; compile counts and
     wall-clock are surfaced in `ServiceStats`.
@@ -307,27 +301,21 @@ class SparsifyService:
     def __init__(
         self,
         k_cap: int = 32,
-        parallel: bool = True,
         max_batch_size: int = 64,
         min_n_bucket: int = 16,
         min_L_bucket: int = 32,
         recovery: str = "device",
-        schedule: str = "chunked",
         p1_chunk: Optional[int] = None,
-        bfs_engine: str = "doubling",
         async_dispatch: bool = False,
         donate: bool = False,
         mesh=None,
     ):
         self.k_cap = k_cap
-        self.parallel = parallel
         self.max_batch_size = max_batch_size
         self.min_n_bucket = min_n_bucket
         self.min_L_bucket = min_L_bucket
         self.recovery = recovery
-        self.schedule = schedule
         self.p1_chunk = p1_chunk
-        self.bfs_engine = bfs_engine
         self.async_dispatch = async_dispatch
         self.donate = donate
         self.mesh = mesh
@@ -348,7 +336,7 @@ class SparsifyService:
 
     # ---------------------------------------------------------- policies
 
-    def _p1_chunk(self, L_bucket: int) -> Optional[int]:
+    def _p1_chunk(self, L_bucket: int) -> int:
         """Per-bucket phase-1 block size policy.
 
         The scheduler's auto policy (`core.pow2.auto_chunk`) is a
@@ -357,25 +345,9 @@ class SparsifyService:
         size, and `warmup` compiles exactly the program traffic will
         request. An explicit `p1_chunk` pins all buckets instead.
         """
-        if self.schedule != "chunked":
-            return None
         if self.p1_chunk is not None:
             return self.p1_chunk
         return auto_chunk(L_bucket)
-
-    def _bfs_engine(self, n_bucket: int) -> str:
-        """Per-bucket BFS-engine policy.
-
-        The engine is a compiled-program key, so — exactly like the
-        phase-1 block size — it is resolved through this one hook from
-        the bucket, and `warmup` resolves through the same hook: warmed
-        programs are the ones traffic requests. The default policy is
-        uniform ("doubling" everywhere: it is never more loop rounds
-        than level-sync and collapses diameter-bound buckets to
-        O(log n)); subclasses with measured per-size preferences can
-        override on `n_bucket`.
-        """
-        return self.bfs_engine
 
     def _bucket(self, n: int, L: int) -> Tuple[int, int]:
         """The bucketing policy, from raw sizes — the single source both
@@ -413,15 +385,9 @@ class SparsifyService:
         return dict(
             n=n_bucket,
             k_cap=self.k_cap,
-            parallel=self.parallel,
-            lift_levels=None,
             b_cap=b_cap,
-            use_tree_kernel=False,
             chunk=32,
-            schedule=self.schedule,
             p1_chunk=self._p1_chunk(L_bucket),
-            use_euler_lca=True,
-            bfs_engine=self._bfs_engine(n_bucket),
         )
 
     @property
@@ -657,12 +623,10 @@ class SparsifyService:
         out = lgrass_sparsify_batch(
             batch,
             budget=list(resolved) + [None] * n_fill,
-            k_cap=self.k_cap, parallel=self.parallel,
+            k_cap=self.k_cap,
             recovery=self.recovery,
             b_cap=b_cap,
-            schedule=self.schedule,
             p1_chunk=self._p1_chunk(L_bucket),
-            bfs_engine=self._bfs_engine(n_bucket),
         )
         for i, r in zip(chunk, out):  # placeholder tail dropped
             results[i] = r

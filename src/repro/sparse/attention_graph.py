@@ -70,7 +70,7 @@ def plan_block_mask(block_feats: np.ndarray, keep_frac: float = 0.15,
     """LGRASS-sparsified causal block mask."""
     g = build_block_graph(block_feats, window=window)
     budget = max(1, int(keep_frac * g.n))
-    res = lgrass_sparsify(g, budget=budget, parallel=False)
+    res = lgrass_sparsify(g, budget=budget)
     nb = g.n
     mask = np.zeros((nb, nb), bool)
     np.fill_diagonal(mask, True)

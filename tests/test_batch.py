@@ -4,8 +4,8 @@ The contract under test: every graph in a padded batch yields an
 `edge_mask` BIT-IDENTICAL to (a) its single-graph `lgrass_sparsify` run
 and (b) the `baseline_sparsify` oracle — padding must be invisible.
 Covers three graph families (random lognormal, random ties, power-grid),
-mixed sizes in one batch, both marking schedules, per-graph budgets, the
-k_cap overflow/dirty recovery path, and the serving bucketing layer.
+mixed sizes in one batch, per-graph budgets, the k_cap overflow/dirty
+recovery path, and the serving bucketing layer.
 """
 import numpy as np
 import pytest
@@ -58,12 +58,13 @@ def test_graphbatch_rejects_too_small_bucket():
         GraphBatch.from_graphs([])
 
 
-@pytest.mark.parametrize("parallel", [True, False])
-def test_batch_bit_identical_to_single_and_baseline(parallel):
+@pytest.mark.parametrize("k_cap", [1, 32])
+def test_batch_bit_identical_to_single_and_baseline(k_cap):
+    """k_cap=1 overflows groups, so the dirty replay decides them."""
     graphs = _mixed_families()
-    results = lgrass_sparsify_batch(graphs, budget=8, parallel=parallel)
+    results = lgrass_sparsify_batch(graphs, budget=8, k_cap=k_cap)
     for i, (g, r) in enumerate(zip(graphs, results)):
-        single = lgrass_sparsify(g, budget=8, parallel=parallel)
+        single = lgrass_sparsify(g, budget=8, k_cap=k_cap)
         base = baseline_sparsify(g, budget=8)
         assert np.array_equal(r.edge_mask, single.edge_mask), i
         assert np.array_equal(r.tree_mask, single.tree_mask), i
@@ -138,11 +139,11 @@ def test_next_pow2():
 
 def test_sparsify_service_buckets_and_order():
     graphs = _mixed_families()
-    svc = SparsifyService(min_n_bucket=16, min_L_bucket=32, parallel=False)
+    svc = SparsifyService(min_n_bucket=16, min_L_bucket=32)
     results = svc.sparsify(graphs, budget=8)
     # request order preserved, results exact
     for g, r in zip(graphs, results):
-        single = lgrass_sparsify(g, budget=8, parallel=False)
+        single = lgrass_sparsify(g, budget=8)
         assert np.array_equal(r.edge_mask, single.edge_mask)
     # bucketing bounds the number of dispatched shapes
     assert svc.stats.n_graphs == len(graphs)
@@ -156,30 +157,30 @@ def test_sparsify_service_buckets_and_order():
 
 def test_sparsify_service_chunks_large_batches():
     graphs = [random_connected_graph(20, 30, seed=s) for s in range(5)]
-    svc = SparsifyService(max_batch_size=2, parallel=False)
+    svc = SparsifyService(max_batch_size=2)
     results = svc.sparsify(graphs, budget=4)
     assert svc.stats.n_dispatches == 3  # 5 graphs, one bucket, chunks of 2
     for g, r in zip(graphs, results):
         assert np.array_equal(
             r.edge_mask,
-            lgrass_sparsify(g, budget=4, parallel=False).edge_mask,
+            lgrass_sparsify(g, budget=4).edge_mask,
         )
 
 
 def test_sparsify_service_ndarray_budget():
     graphs = [random_connected_graph(20, 30, seed=s) for s in range(3)]
-    svc = SparsifyService(parallel=False)
+    svc = SparsifyService()
     results = svc.sparsify(graphs, budget=np.array([2, 3, 4]))
     for g, b, r in zip(graphs, (2, 3, 4), results):
         assert np.array_equal(
             r.edge_mask,
-            lgrass_sparsify(g, budget=b, parallel=False).edge_mask,
+            lgrass_sparsify(g, budget=b).edge_mask,
         )
     # numpy scalar broadcasts like a python int
     r0 = svc.sparsify(graphs[:1], budget=np.int64(4))[0]
     assert np.array_equal(
         r0.edge_mask,
-        lgrass_sparsify(graphs[0], budget=4, parallel=False).edge_mask,
+        lgrass_sparsify(graphs[0], budget=4).edge_mask,
     )
 
 
@@ -187,7 +188,7 @@ def test_sparsify_service_pads_batch_axis():
     """Odd chunk sizes are padded to pow2 with placeholder rows that must
     not leak into the results (and keep compiled shapes shared)."""
     graphs = [random_connected_graph(20, 30, seed=s) for s in range(3)]
-    svc = SparsifyService(parallel=False)
+    svc = SparsifyService()
     results = svc.sparsify(graphs, budget=4)   # one chunk of 3 -> B=4
     assert len(results) == len(graphs)
     assert svc.stats.n_dispatches == 1
@@ -196,7 +197,7 @@ def test_sparsify_service_pads_batch_axis():
     for g, r in zip(graphs, results):
         assert np.array_equal(
             r.edge_mask,
-            lgrass_sparsify(g, budget=4, parallel=False).edge_mask,
+            lgrass_sparsify(g, budget=4).edge_mask,
         )
 
 
@@ -206,7 +207,7 @@ def test_sparsify_service_warmup_precompiles():
     exact. Warmup never touches the request-path stats."""
     from repro.core.sparsify import lgrass_device_batched
 
-    svc = SparsifyService(parallel=False)
+    svc = SparsifyService()
     size_before = lgrass_device_batched._cache_size()
     n_disp = svc.warmup([(20, 30), (22, 31)])  # same pow2 bucket
     assert n_disp == 1
@@ -220,25 +221,24 @@ def test_sparsify_service_warmup_precompiles():
     [r] = svc.sparsify([g])
     assert lgrass_device_batched._cache_size() == size_warm  # cache hit
     assert np.array_equal(
-        r.edge_mask, lgrass_sparsify(g, parallel=False).edge_mask
+        r.edge_mask, lgrass_sparsify(g).edge_mask
     )
 
 
 def test_sparsify_service_host_recovery_mode():
     """The oracle tail stays available behind recovery='host'."""
     graphs = [random_connected_graph(20, 30, seed=s) for s in range(2)]
-    svc = SparsifyService(parallel=False, recovery="host")
+    svc = SparsifyService(recovery="host")
     for g, r in zip(graphs, svc.sparsify(graphs, budget=4)):
         assert np.array_equal(
             r.edge_mask,
-            lgrass_sparsify(g, budget=4, parallel=False,
-                            recovery="host").edge_mask,
+            lgrass_sparsify(g, budget=4, recovery="host").edge_mask,
         )
 
 
 def test_sparsify_service_mixed_budgets():
     graphs = _mixed_families()[:3]
-    svc = SparsifyService(parallel=False)
+    svc = SparsifyService()
     results = svc.sparsify(graphs, budget=[None, 5, None])
     assert np.array_equal(
         results[0].edge_mask, lgrass_sparsify(graphs[0]).edge_mask

@@ -1,18 +1,19 @@
 """The packed-key switch boundary (`bfs.PACKED_KEY_MAX_N`): at the
 largest int32-safe n the doubling engine runs its packed single-scatter
 relaxation; one node more and it falls back to the unpacked two-scatter
-pass. Both sides of the switch must be bit-identical to the
-level-synchronous engine — the graphs are sparse chains anchored at the
-TOP of the id range so the packed keys actually reach their maxima."""
+pass. Both sides of the switch must be bit-identical to the numpy
+oracle's level-synchronous BFS — the graphs are sparse chains anchored
+at the TOP of the id range so the packed keys actually reach their
+maxima."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import _host as H
 from repro.core.bfs import (
     EULER_PACK_MAX_N,
     PACKED_KEY_MAX_N,
     bfs_doubling,
-    bfs_levels,
     packed_key_bound,
 )
 
@@ -43,9 +44,8 @@ def test_engines_bit_identical_across_switch(n):
     u, v = _top_chain_graph(n)
     root = jnp.int32(n - 1)
     dd, pd = bfs_doubling(u, v, n, root)
-    dl, pl = bfs_levels(u, v, n, root)
+    dl, pl = H.bfs_np(np.asarray(u), np.asarray(v), n, n - 1)
     dd, pd = np.asarray(dd), np.asarray(pd)
-    dl, pl = np.asarray(dl), np.asarray(pl)
     assert np.array_equal(dd, dl)
     assert np.array_equal(pd, pl)
     # sanity on the expected structure, not just mutual agreement
@@ -61,11 +61,11 @@ def test_engines_bit_identical_across_switch(n):
 @pytest.mark.parametrize("n", [PACKED_KEY_MAX_N, PACKED_KEY_MAX_N + 1])
 def test_edge_mask_respected_across_switch(n):
     u, v = _top_chain_graph(n)
-    # mask off the spur: node 0 must become unreachable on both engines
-    mask = jnp.asarray(np.arange(len(u)) != len(u) - 1)
+    # mask off the spur: node 0 must become unreachable
+    mask = np.arange(len(u)) != len(u) - 1
     root = jnp.int32(n - 1)
-    dd, pd = bfs_doubling(u, v, n, root, edge_mask=mask)
-    dl, pl = bfs_levels(u, v, n, root, edge_mask=mask)
+    dd, pd = bfs_doubling(u, v, n, root, edge_mask=jnp.asarray(mask))
+    dl, pl = H.bfs_np(np.asarray(u), np.asarray(v), n, n - 1, mask)
     assert np.array_equal(np.asarray(dd), np.asarray(dl))
     assert np.array_equal(np.asarray(pd), np.asarray(pl))
     assert np.asarray(dd)[0] == INT32_MAX

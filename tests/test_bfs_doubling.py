@@ -1,9 +1,8 @@
-"""BFS engine parity: the hop-doubling engine and the Euler-tour tree
-rooting must be BIT-IDENTICAL (depth AND parent) to the level-sync
-engine and the numpy oracle across graph families — including the
+"""BFS parity: the hop-doubling engine and the Euler-tour tree rooting
+must be BIT-IDENTICAL (depth AND parent) to the numpy oracle's
+level-synchronous BFS across graph families — including the
 padded-batch vmap path, tree-restricted masks, and disconnected
-forests — and the full pipeline must produce identical sparsifiers
-under either engine.
+forests — and the full pipeline must equal the baseline greedy.
 
 Shapes are reused across cases so the sweep costs a handful of XLA
 compiles, not one per case.
@@ -17,12 +16,10 @@ import jax.numpy as jnp
 from repro.core import _host as H
 from repro.core import baseline_sparsify, lgrass_sparsify, lgrass_sparsify_batch
 from repro.core.bfs import (
-    bfs,
     bfs_doubling,
-    bfs_levels,
     effective_weights,
     finite_depth,
-    root_tree,
+    root_tree_euler,
     select_root,
 )
 from repro.core.graph import (
@@ -56,42 +53,32 @@ def _disconnected(seed=0):
     )
 
 
-def _assert_engines_match(g, emask=None):
+def _assert_bfs_matches_oracle(g, emask=None):
     root = H.select_root_np(g.u, g.v, g.n)
     dn, pn = H.bfs_np(g.u, g.v, g.n, root, emask)
     u = jnp.asarray(g.u, jnp.int32)
     v = jnp.asarray(g.v, jnp.int32)
     em = None if emask is None else jnp.asarray(emask)
-    dl, pl = bfs(u, v, g.n, jnp.int32(root), em, engine="levels")
-    dd, pd = bfs(u, v, g.n, jnp.int32(root), em, engine="doubling")
-    assert np.array_equal(np.asarray(dl), dn)
-    assert np.array_equal(np.asarray(pl), pn)
+    dd, pd = bfs_doubling(u, v, g.n, jnp.int32(root), em)
     assert np.array_equal(np.asarray(dd), dn)
     assert np.array_equal(np.asarray(pd), pn)
     return root, dn, pn
 
 
 @pytest.mark.parametrize("seed", [0, 1])
-def test_bfs_engines_match_oracle_all_families(seed):
+def test_bfs_matches_oracle_all_families(seed):
     for _, g in _families(seed=seed):
-        _assert_engines_match(g)
-
-
-def test_bfs_unknown_engine_raises():
-    g = random_connected_graph(10, 15, seed=0)
-    with pytest.raises(ValueError):
-        bfs(jnp.asarray(g.u), jnp.asarray(g.v), g.n, jnp.int32(0),
-            engine="nope")
+        _assert_bfs_matches_oracle(g)
 
 
 def test_bfs_doubling_shuffled_ids():
     """Node ids decorrelated from the chain layout: the monotone-id
     chains stop helping and the re-anchored climb must carry
-    convergence — output parity is engine-independent either way."""
+    convergence — the output still equals the oracle's."""
     g0 = feeder_like_graph(200, 120, span=10, seed=3)
     perm = np.random.default_rng(7).permutation(g0.n).astype(np.int32)
     g = Graph(n=g0.n, u=perm[g0.u], v=perm[g0.v], w=g0.w)
-    _assert_engines_match(g)
+    _assert_bfs_matches_oracle(g)
 
 
 def _tree_mask_from_bfs(g, root, pn):
@@ -111,14 +98,14 @@ def _tree_mask_from_bfs(g, root, pn):
 
 @pytest.mark.parametrize("seed", [0, 2])
 def test_tree_restricted_masks_and_root_tree(seed):
-    """Both engines under a tree edge mask ≡ oracle ≡ `root_tree` (the
+    """The BFS under a tree edge mask ≡ oracle ≡ `root_tree_euler` (the
     O(log n) Euler rooting the pipeline's second pass uses)."""
     for _, g in _families(seed=seed):
         root = H.select_root_np(g.u, g.v, g.n)
         _, pn = H.bfs_np(g.u, g.v, g.n, root)
         tmask = _tree_mask_from_bfs(g, root, pn)
-        _, dt, pt = _assert_engines_match(g, tmask)
-        de, pe = root_tree(
+        _, dt, pt = _assert_bfs_matches_oracle(g, tmask)
+        de, pe, _ = root_tree_euler(
             jnp.asarray(g.u, jnp.int32), jnp.asarray(g.v, jnp.int32),
             g.n, jnp.int32(root), jnp.asarray(tmask))
         assert np.array_equal(np.asarray(de), dt)
@@ -126,11 +113,11 @@ def test_tree_restricted_masks_and_root_tree(seed):
 
 
 def test_disconnected_forest_parity_and_finite_weights():
-    """Regression: unreachable nodes keep INF depth under every engine,
+    """Regression: unreachable nodes keep INF depth,
     and `effective_weights` clamps them instead of multiplying
     float32(2^31-1) into the weights (device and numpy mirror agree)."""
     g = _disconnected()
-    root, dn, _ = _assert_engines_match(g)
+    root, dn, _ = _assert_bfs_matches_oracle(g)
     # exactly the non-root component is unreachable
     assert (dn == np.iinfo(np.int32).max).sum() in (30, 60)
     u = jnp.asarray(g.u, jnp.int32)
@@ -149,18 +136,20 @@ def test_disconnected_forest_parity_and_finite_weights():
     assert np.array_equal(
         np.asarray(finite_depth(jnp.asarray(dn))), np.where(
             dn == np.iinfo(np.int32).max, 0, dn))
-    # root_tree on a spanning forest tours only the root's component
+    # root_tree_euler on a spanning forest tours only the root's component
     _, pn = H.bfs_np(g.u, g.v, g.n, root)
     tmask = _tree_mask_from_bfs(g, root, pn)
     dtn, ptn = H.bfs_np(g.u, g.v, g.n, root, tmask)
-    de, pe = root_tree(u, v, g.n, jnp.int32(root), jnp.asarray(tmask))
+    de, pe, _ = root_tree_euler(u, v, g.n, jnp.int32(root),
+                                jnp.asarray(tmask))
     assert np.array_equal(np.asarray(de), dtn)
     assert np.array_equal(np.asarray(pe), ptn)
 
 
 def test_padded_batch_vmap_parity():
-    """Both engines vmapped over a padded GraphBatch: real-slot outputs
-    equal the unpadded per-graph runs; padded nodes stay unreachable."""
+    """The BFS vmapped over a padded GraphBatch, jitted and traced
+    inline: real-slot outputs equal the unpadded per-graph oracle runs;
+    padded nodes stay unreachable."""
     graphs = [
         random_connected_graph(40, 90, seed=0),
         feeder_like_graph(50, 25, span=6, seed=1),
@@ -172,21 +161,20 @@ def test_padded_batch_vmap_parity():
     evb = jnp.asarray(batch.edge_valid)
     roots = jnp.asarray(
         [H.select_root_np(g.u, g.v, g.n) for g in graphs], jnp.int32)
-    for fn in (bfs_doubling, bfs_levels):
-        dB, pB = jax.vmap(
-            lambda a, b, r, m: fn(a, b, 64, r, m))(ub, vb, roots, evb)
-        for i, g in enumerate(graphs):
-            dn, pn = H.bfs_np(g.u, g.v, g.n, int(roots[i]))
-            assert np.array_equal(np.asarray(dB[i])[:g.n], dn)
-            assert np.array_equal(np.asarray(pB[i])[:g.n], pn)
-            # padding nodes can never be reached from the real graph
-            assert np.all(np.asarray(dB[i])[g.n:] == np.iinfo(np.int32).max)
+    dB, pB = jax.vmap(
+        lambda a, b, r, m: bfs_doubling(a, b, 64, r, m))(ub, vb, roots, evb)
+    for i, g in enumerate(graphs):
+        dn, pn = H.bfs_np(g.u, g.v, g.n, int(roots[i]))
+        assert np.array_equal(np.asarray(dB[i])[:g.n], dn)
+        assert np.array_equal(np.asarray(pB[i])[:g.n], pn)
+        # padding nodes can never be reached from the real graph
+        assert np.all(np.asarray(dB[i])[g.n:] == np.iinfo(np.int32).max)
 
 
 def test_bfs_doubling_unpacked_key_branch():
     """n past the int32 packing bound ((n+1)^2 >= 2^31) exercises the
     two-scatter relax/witness fallback: a small graph embedded in a
-    huge sparse id space, parity vs levels and the oracle."""
+    huge sparse id space, parity vs the oracle."""
     n = 46_400  # (n+1)^2 > 2^31 -> packed=False
     rng = np.random.default_rng(11)
     ids = np.sort(rng.choice(n, size=600, replace=False)).astype(np.int32)
@@ -203,7 +191,7 @@ def test_bfs_doubling_unpacked_key_branch():
         vv.append(key[1])
     g = Graph(n=n, u=np.array(uu, np.int32), v=np.array(vv, np.int32),
               w=np.ones(len(uu), np.float32))
-    _assert_engines_match(g)
+    _assert_bfs_matches_oracle(g)
 
 
 def test_select_root_unchanged_by_engine_refactor():
@@ -215,29 +203,23 @@ def test_select_root_unchanged_by_engine_refactor():
 
 @pytest.mark.parametrize("family_seed", [0, 1])
 def test_pipeline_identical_under_both_engines(family_seed):
-    """lgrass_sparsify(bfs_engine=...) — the whole sparsifier is
-    bit-identical under either engine, and equals the baseline."""
+    """The whole sparsifier equals the baseline greedy under either
+    recovery tail, with the same tree and counters."""
     g = random_connected_graph(36, 80, seed=family_seed)
     base = baseline_sparsify(g, budget=7)
-    for recovery in ("device", "host"):
-        rd = lgrass_sparsify(g, budget=7, recovery=recovery,
-                             bfs_engine="doubling")
-        rl = lgrass_sparsify(g, budget=7, recovery=recovery,
-                             bfs_engine="levels")
-        assert np.array_equal(rd.edge_mask, rl.edge_mask)
-        assert np.array_equal(rd.edge_mask, base.edge_mask)
-        assert np.array_equal(rd.tree_mask, rl.tree_mask)
-        assert rd.n_groups == rl.n_groups
-        assert rd.n_dirty == rl.n_dirty
+    rd = lgrass_sparsify(g, budget=7, recovery="device")
+    rh = lgrass_sparsify(g, budget=7, recovery="host")
+    for r in (rd, rh):
+        assert np.array_equal(r.edge_mask, base.edge_mask)
+    assert np.array_equal(rd.tree_mask, rh.tree_mask)
+    assert rd.n_groups == rh.n_groups
+    assert rd.n_dirty == rh.n_dirty
 
 
 def test_pipeline_feeder_engine_parity():
     """The diameter-bound family the doubling engine targets."""
     g = feeder_like_graph(96, 48, span=6, seed=5)
-    rd = lgrass_sparsify(g, budget=6, bfs_engine="doubling")
-    rl = lgrass_sparsify(g, budget=6, bfs_engine="levels")
-    assert np.array_equal(rd.edge_mask, rl.edge_mask)
-    assert np.array_equal(rd.edge_mask,
+    assert np.array_equal(lgrass_sparsify(g, budget=6).edge_mask,
                           baseline_sparsify(g, budget=6).edge_mask)
 
 
@@ -247,20 +229,6 @@ def test_batched_pipeline_engine_parity():
         feeder_like_graph(50, 25, span=6, seed=1),
         powergrid_like_graph(6, 0.4, seed=2),
     ]
-    rd = lgrass_sparsify_batch(graphs, budget=6, bfs_engine="doubling")
-    rl = lgrass_sparsify_batch(graphs, budget=6, bfs_engine="levels")
-    for g, a, b in zip(graphs, rd, rl):
-        assert np.array_equal(a.edge_mask, b.edge_mask)
+    for g, r in zip(graphs, lgrass_sparsify_batch(graphs, budget=6)):
         assert np.array_equal(
-            a.edge_mask, baseline_sparsify(g, budget=6).edge_mask)
-
-
-def test_auto_lift_bound_with_doubling_engine():
-    """auto_lift_bound path runs its estimate BFS through the selected
-    engine and the shared finite-depth guard."""
-    g = feeder_like_graph(80, 40, span=6, seed=7)
-    r1 = lgrass_sparsify(g, budget=5, auto_lift_bound=True,
-                         bfs_engine="doubling")
-    r2 = lgrass_sparsify(g, budget=5, auto_lift_bound=False,
-                         bfs_engine="levels")
-    assert np.array_equal(r1.edge_mask, r2.edge_mask)
+            r.edge_mask, baseline_sparsify(g, budget=6).edge_mask)

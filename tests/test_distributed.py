@@ -1,6 +1,6 @@
 """Multi-device tests (subprocess with XLA_FLAGS=8 fake devices):
-sharded LGRASS phase-1 equivalence, elastic re-meshing, compressed psum,
-and a reduced-mesh dry-run through the real launch machinery."""
+elastic re-meshing, compressed psum, and a reduced-mesh dry-run through
+the real launch machinery."""
 import os
 import subprocess
 import sys
@@ -21,54 +21,6 @@ def _run(code: str, timeout=600):
                        env=env)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
     return r.stdout
-
-
-def test_sharded_phase1_equals_local():
-    out = _run("""
-        import jax, numpy as np, jax.numpy as jnp
-        from repro.core import random_connected_graph
-        from repro.core.distributed import lgrass_phase1_distributed
-        from repro.core.sparsify import phase1_device
-        for seed in (0, 3):
-            g = random_connected_graph(60, 140, seed=seed)
-            mesh = jax.make_mesh((8,), ('data',))
-            acc, dirty, d = lgrass_phase1_distributed(g, mesh, ('data',))
-            u = jnp.asarray(g.u, jnp.int32); v = jnp.asarray(g.v, jnp.int32)
-            w = jnp.asarray(g.w, jnp.float32)
-            ds = jax.device_get(phase1_device(u, v, w, g.n, 32, True))
-            ref = np.zeros(g.m, bool); ref[ds['perm']] = ds['accept_sorted']
-            assert np.array_equal(acc, ref), seed
-        print('OK')
-    """)
-    assert "OK" in out
-
-
-def test_distributed_sparsify_equals_oracle():
-    out = _run("""
-        import jax, numpy as np
-        from repro.core import random_connected_graph, baseline_sparsify
-        from repro.core.distributed import lgrass_phase1_distributed
-        from repro.core import _host as H
-        from repro.core.recovery import recover
-        g = random_connected_graph(50, 120, seed=5)
-        b = baseline_sparsify(g, budget=10)
-        mesh = jax.make_mesh((8,), ('data',))
-        acc, dirty, d = lgrass_phase1_distributed(g, mesh, ('data',))
-        tree = d['tree_mask'].astype(bool)
-        crossing = d['crossing'].astype(bool)
-        perm = d['perm'].astype(np.int64)
-        group = np.full(g.m, -1, np.int64)
-        group[perm] = d['gidx'].astype(np.int64)
-        group[~crossing] = -1
-        keys = np.where(~tree, d['crit'], np.float32(-np.inf))
-        order = H.desc_stable_order_np(keys)[: int((~tree).sum())]
-        final = recover(g.n, g.u.astype(np.int64), g.v.astype(np.int64),
-                        tree, d['parent_t'], d['depth_t'], d['up'],
-                        d['beta'], crossing, order, acc, group, dirty, 10)
-        assert np.array_equal(tree | final, b.edge_mask)
-        print('OK')
-    """)
-    assert "OK" in out
 
 
 def test_elastic_remesh_and_compressed_psum():
@@ -126,9 +78,6 @@ def test_reduced_mesh_dryrun_machinery():
         rec2 = D.run_cell('granite-moe-3b-a800m', 'decode_32k', False,
                           '/tmp/ci_dry', force=True)
         assert rec2['memory']['temp_bytes'] > 0
-        rec3 = D.run_lgrass_cell('case1_4k', True, '/tmp/ci_dry',
-                                 force=True)
-        assert rec3['hlo_bytes_per_device'] > 0
         print('OK')
     """, timeout=900)
     assert "OK" in out

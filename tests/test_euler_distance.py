@@ -1,14 +1,16 @@
 """Euler-tour tree distances from the depth-minimum table (`lca.dmin`).
 
-`tree_distance_euler` and the Euler branch of `marking.ball_pair_table`
+`tree_distance_euler` and the cover tables of `marking.ball_pair_table`
 answer a pair's distance with two `dmin` reads once each endpoint's
 (first, depth) is known. They must equal the position-table formula
 `depth[a] + depth[b] - 2 * depth[lca_euler(a, b)]` bit for bit — on
 every node pair of random trees, a chain, a star and padded forests
 (off-tour nodes: first = P - 1, INF depth, so the int32 sum wraps),
-single and under `vmap` over lanes of different sizes. `dmin` itself is
-checked against a brute-force range minimum, and a structural guard
-pins the cover table at two per-pair gathers.
+single and under `vmap` over lanes of different sizes — and equal the
+numpy lifting-table distances (`_host.tree_dist_np`) on the BFS trees
+of random graphs. `dmin` itself is checked against a brute-force range
+minimum, and a structural guard pins the cover table at two per-pair
+gathers.
 """
 import numpy as np
 import pytest
@@ -17,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.jaxpr_audit import collect_eqns
+from repro.core import _host as H
 from repro.core.bfs import root_tree_euler
 from repro.core.lca import (build_euler, lca_euler, tables_from_tour,
                             tree_distance_euler)
@@ -107,6 +110,43 @@ def test_tree_distance_euler_all_pairs_bit_identical(kind, n_real, n, seed):
         assert int(e.first[n - 1]) == 2 * n - 2
 
 
+def _bfs_tree(n, extra, seed):
+    """A random connected graph's BFS tree: the numpy lifting table and
+    depths, and the Euler tables `build_euler` makes from it."""
+    from repro.core.graph import random_connected_graph
+
+    g = random_connected_graph(n, extra, seed=seed)
+    u64, v64 = g.u.astype(np.int64), g.v.astype(np.int64)
+    root = H.select_root_np(u64, v64, g.n)
+    depth, parent = H.bfs_np(u64, v64, g.n, root)
+    up = H.build_lifting_np(parent, depth, g.n)
+    e = build_euler(jnp.asarray(parent), jnp.asarray(depth),
+                    jnp.int32(root), g.n)
+    return up, depth, e
+
+
+@pytest.mark.parametrize("n,m", [(40, 64), (60, 300), (100, 257)])
+def test_tree_distance_euler_matches_lifting_oracle(n, m):
+    """Random query pairs on random BFS trees: the Euler distance equals
+    the numpy binary-lifting distance exactly."""
+    up, depth, e = _bfs_tree(n, 2 * n, seed=n)
+    rng = np.random.default_rng(m)
+    a = rng.integers(0, n, m).astype(np.int32)
+    b = rng.integers(0, n, m).astype(np.int32)
+    got = np.asarray(tree_distance_euler(e, jnp.asarray(a), jnp.asarray(b)))
+    assert np.array_equal(got, H.tree_dist_np(up, depth, a, b))
+
+
+def test_tree_distance_euler_identical_and_adjacent():
+    """Edge cases: d(x, x) = 0; d(child, parent) = 1."""
+    up, depth, e = _bfs_tree(30, 40, seed=5)
+    nodes = jnp.arange(30, dtype=jnp.int32)
+    assert np.all(np.asarray(tree_distance_euler(e, nodes, nodes)) == 0)
+    parents = jnp.asarray(up[0], jnp.int32)
+    d = np.asarray(tree_distance_euler(e, nodes, parents))
+    assert np.all(d == (np.asarray(depth) > 0).astype(int))
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_root_tree_euler_padded_forest_bit_identical(seed):
     """Tables built by `bfs.root_tree_euler` (another tour order) over a
@@ -153,7 +193,7 @@ def test_ball_pair_table_euler_bit_identical(kind, n_real, n, seed):
         cu, cv = (jnp.asarray(rng.integers(0, n, shape), jnp.int32)
                   for _ in range(2))
         cb = jnp.asarray(rng.integers(-1, 6, shape), jnp.int32)
-        got = ball_pair_table(None, xs, ys, cu, cv, cb, euler=e)
+        got = ball_pair_table(e, xs, ys, cu, cv, cb)
         assert got.shape == (c, k)
         assert np.array_equal(np.asarray(got),
                               np.asarray(_ref_cover(e, xs, ys, cu, cv, cb)))
@@ -173,8 +213,7 @@ def test_ball_pair_table_euler_vmap_lanes_bit_identical():
     cu, cv = (jnp.asarray(rng.integers(0, n, (B, k)), jnp.int32)
               for _ in range(2))
     cb = jnp.asarray(rng.integers(-1, 5, (B, k)), jnp.int32)
-    got = jax.vmap(lambda el, *q: ball_pair_table(None, *q, euler=el))(
-        e, xs, ys, cu, cv, cb)
+    got = jax.vmap(ball_pair_table)(e, xs, ys, cu, cv, cb)
     ref = jax.vmap(_ref_cover)(e, xs, ys, cu, cv, cb)
     assert np.array_equal(np.asarray(got), np.asarray(ref))
 
@@ -224,10 +263,8 @@ def test_cover_table_has_two_per_pair_gathers(c, k, per_row):
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     cols = i32(c, k) if per_row else i32(k)
 
-    def fn(el, xs, ys, cu, cv, cb):
-        return ball_pair_table(None, xs, ys, cu, cv, cb, euler=el)
-
-    closed = jax.make_jaxpr(fn)(e, i32(c), i32(c), cols, cols, cols)
+    closed = jax.make_jaxpr(ball_pair_table)(e, i32(c), i32(c), cols,
+                                             cols, cols)
     sizes = [int(np.prod(o.aval.shape))
              for q in collect_eqns(closed) if q.primitive.name == "gather"
              for o in q.outvars]

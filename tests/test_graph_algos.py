@@ -6,7 +6,7 @@ import pytest
 
 from _prop import cases, integers, sampled_from
 from repro.core import _host as H
-from repro.core.bfs import bfs, effective_weights, select_root
+from repro.core.bfs import bfs_doubling, effective_weights, select_root
 from repro.core.graph import random_connected_graph
 from repro.core.lca import (build_lifting, lca, lca_with_shortcut, subroot,
                             tree_distance)
@@ -29,7 +29,7 @@ def test_bfs_matches_oracle(seed):
     g, u, v, w = _setup(seed=seed)
     root = int(select_root(u, v, g.n))
     assert root == H.select_root_np(g.u, g.v, g.n)
-    d, p = bfs(u, v, g.n, jnp.int32(root))
+    d, p = bfs_doubling(u, v, g.n, jnp.int32(root))
     dn, pn = H.bfs_np(g.u, g.v, g.n, root)
     assert np.array_equal(np.asarray(d), dn)
     assert np.array_equal(np.asarray(p), pn)
@@ -43,7 +43,7 @@ def test_bfs_matches_oracle(seed):
 def test_boruvka_equals_kruskal(seed, weight):
     g, u, v, w = _setup(n=40, m=90, seed=seed, weight=weight)
     root = int(select_root(u, v, g.n))
-    d, _ = bfs(u, v, g.n, jnp.int32(root))
+    d, _ = bfs_doubling(u, v, g.n, jnp.int32(root))
     eff = effective_weights(u, v, w, d, g.n)
     perm = sort_f32_desc_stable(eff)
     rank = np.empty(g.m, np.int32)
@@ -57,7 +57,7 @@ def test_boruvka_equals_kruskal(seed, weight):
 def test_lca_brute_force():
     g, u, v, w = _setup(n=50, m=100, seed=3)
     root = int(select_root(u, v, g.n))
-    d, p = bfs(u, v, g.n, jnp.int32(root))
+    d, p = bfs_doubling(u, v, g.n, jnp.int32(root))
     t = build_lifting(p, d, g.n)
     dn, pn = np.asarray(d), np.asarray(p)
 
@@ -92,13 +92,13 @@ def test_resistance_vs_dense_laplacian():
     """R_tree from root-path sums == pseudo-inverse of the tree Laplacian."""
     g, u, v, w = _setup(n=30, m=60, seed=4)
     root = int(select_root(u, v, g.n))
-    d0, _ = bfs(u, v, g.n, jnp.int32(root))
+    d0, _ = bfs_doubling(u, v, g.n, jnp.int32(root))
     eff = effective_weights(u, v, w, d0, g.n)
     perm = sort_f32_desc_stable(eff)
     rank = np.empty(g.m, np.int32)
     rank[np.asarray(perm)] = np.arange(g.m)
     tmask = boruvka_mst(u, v, jnp.asarray(rank), g.n)
-    dt, pt = bfs(u, v, g.n, jnp.int32(root), edge_mask=tmask)
+    dt, pt = bfs_doubling(u, v, g.n, jnp.int32(root), edge_mask=tmask)
     t = build_lifting(pt, dt, g.n)
     inv_w = node_parent_inv_w(u, v, w, tmask, pt, g.n)
     r = root_path_sums(t, inv_w)
@@ -125,7 +125,7 @@ def test_resistance_vs_dense_laplacian():
 def test_subroot_depth1():
     g, u, v, w = _setup(n=40, m=80, seed=5)
     root = int(select_root(u, v, g.n))
-    d, p = bfs(u, v, g.n, jnp.int32(root))
+    d, p = bfs_doubling(u, v, g.n, jnp.int32(root))
     t = build_lifting(p, d, g.n)
     nodes = jnp.arange(g.n, dtype=jnp.int32)
     sr = np.asarray(subroot(t, nodes))

@@ -22,9 +22,15 @@ F32 = jax.ShapeDtypeStruct((16,), jnp.float32)
 
 # --------------------------------------------------- standard program set
 
+PROGRAMS = ["phase1_device", "phase1_device_batched", "lgrass_device",
+            "lgrass_device_batched", "recover_device",
+            "recover_device_batched", "probe_edge_resistance",
+            "probe_edge_resistance_batched"]
+
+
 def test_standard_programs_all_clean():
     reports = standard_program_audits()
-    assert len(reports) >= 10
+    assert [r.name for r in reports] == PROGRAMS
     bad = {r.name: r.findings for r in reports if not r.ok}
     assert bad == {}
     # every serving-path program is a single dispatch
@@ -32,12 +38,50 @@ def test_standard_programs_all_clean():
 
 
 def test_loop_budgets_are_engine_dependent():
+    """One documented while budget a program family, the counts the
+    programs trace: the fused program is phase 1 plus the replay."""
     byname = {r.name: r for r in standard_program_audits()}
-    assert byname["lgrass_device[doubling]"].n_while == \
-        EXPECTED_WHILE[("lgrass", "doubling")]
-    assert byname["lgrass_device[levels]"].n_while == \
-        EXPECTED_WHILE[("lgrass", "levels")]
-    assert byname["probe_edge_resistance"].n_while == 0
+    assert EXPECTED_WHILE == {"phase1": 4, "lgrass": 5, "recover": 1,
+                              "probe": 0}
+    for name in PROGRAMS:
+        family = name.split("_")[0]
+        assert byname[name].n_while == EXPECTED_WHILE[family], name
+
+
+# The compile-cache key of each public program: its static arguments,
+# every one a size or a block/buffer bound (the arrays come first).
+STATICS = {
+    "phase1_device": (3, ("n", "k_cap", "p1_chunk")),
+    "lgrass_device": (4, ("n", "k_cap", "b_cap", "chunk", "p1_chunk")),
+    "lgrass_device_batched": (5, ("n", "k_cap", "b_cap", "chunk",
+                                  "p1_chunk")),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STATICS))
+def test_program_statics_are_the_documented_set(name):
+    """The program takes its arrays and exactly the documented static
+    arguments, and none of those reaches the traced program as an
+    input: each is part of the compile-cache key."""
+    import inspect
+
+    from repro.core import sparsify
+
+    fn = getattr(sparsify, name)
+    n_arrays, statics = STATICS[name]
+    params = list(inspect.signature(fn).parameters)
+    assert tuple(params[n_arrays:]) == statics
+    f = jax.ShapeDtypeStruct
+    batch = (2,) if name.endswith("_batched") else ()
+    arrays = [f(batch + (32,), jnp.int32), f(batch + (32,), jnp.int32),
+              f(batch + (32,), jnp.float32)]
+    if batch:
+        arrays.append(f(batch + (32,), jnp.bool_))
+    if name.startswith("lgrass"):
+        arrays.append(f(batch, jnp.int32))
+    kw = dict(n=16, k_cap=32, b_cap=8, chunk=32, p1_chunk=None)
+    traced = fn.trace(*arrays, **{k: kw[k] for k in statics})
+    assert len(traced.jaxpr.jaxpr.invars) == n_arrays
 
 
 def test_derived_constants_agree():

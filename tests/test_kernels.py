@@ -96,49 +96,6 @@ def test_radix_argsort_kernel_matches_core():
     assert np.array_equal(srt, np.sort(np.asarray(keys)))
 
 
-def _random_lifting(n, extra, seed):
-    from repro.core import _host as H
-    from repro.core.graph import random_connected_graph
-
-    g = random_connected_graph(n, extra, seed=seed)
-    u64, v64 = g.u.astype(np.int64), g.v.astype(np.int64)
-    root = H.select_root_np(u64, v64, g.n)
-    depth, parent = H.bfs_np(u64, v64, g.n, root)
-    up = H.build_lifting_np(parent, depth, g.n)
-    return up, depth
-
-
-@pytest.mark.parametrize("n,m,block", [(40, 64, 64), (60, 300, 128),
-                                       (100, 257, 128)])
-def test_tree_dist_kernel(n, m, block):
-    """Kernel == plain-gather ref == numpy host mirror, exactly (int ops)."""
-    from repro.core import _host as H
-
-    up, depth = _random_lifting(n, 2 * n, seed=n)
-    rng = np.random.default_rng(m)
-    a = jnp.asarray(rng.integers(0, n, m), jnp.int32)
-    b = jnp.asarray(rng.integers(0, n, m), jnp.int32)
-    upj, dj = jnp.asarray(up), jnp.asarray(depth)
-    out = ops.tree_dist_pairs(upj, dj, a, b, block=block, interpret=True)
-    want_ref = ref.tree_dist_pairs_ref(upj, dj, a, b)
-    want_np = H.tree_dist_np(up, depth, np.asarray(a), np.asarray(b))
-    assert np.array_equal(np.asarray(out), np.asarray(want_ref))
-    assert np.array_equal(np.asarray(out), want_np)
-
-
-def test_tree_dist_kernel_identical_and_adjacent():
-    """Edge cases: d(x, x) = 0; d(child, parent) = 1."""
-    up, depth = _random_lifting(30, 40, seed=5)
-    nodes = jnp.arange(30, dtype=jnp.int32)
-    upj, dj = jnp.asarray(up), jnp.asarray(depth)
-    assert np.all(np.asarray(
-        ops.tree_dist_pairs(upj, dj, nodes, nodes, interpret=True)) == 0)
-    parents = jnp.asarray(up[0], jnp.int32)
-    d = np.asarray(ops.tree_dist_pairs(upj, dj, nodes, parents,
-                                       interpret=True))
-    assert np.all(d == (np.asarray(depth) > 0).astype(int))
-
-
 @pytest.mark.parametrize("l,w", [(100, 1), (1024, 2), (2000, 4)])
 def test_bitmap_intersect(l, w):
     rng = np.random.default_rng(l + w)
@@ -224,19 +181,13 @@ def test_spmv_kernel_through_estimator():
     np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-3)
 
 
-@pytest.mark.parametrize("kernel", ["tree_dist", "spmv"])
+@pytest.mark.parametrize("kernel", ["spmv"])
 def test_kernel_vmem_guard_raises_at_trace_time(kernel):
     """A size past the kernel's VMEM regime is refused while tracing,
     with a message naming the kernel — never handed to the compiler."""
     i32 = jax.ShapeDtypeStruct((256,), jnp.int32)
-    if kernel == "tree_dist":
-        n = 1 << 17
-        args = (jax.ShapeDtypeStruct((18, n), jnp.int32),
-                jax.ShapeDtypeStruct((n,), jnp.int32), i32, i32)
-        fn = ops.tree_dist_pairs
-    else:
-        args = (i32, i32, jax.ShapeDtypeStruct((256,), jnp.float32),
-                jax.ShapeDtypeStruct((1 << 16, 64), jnp.float32))
-        fn = ops.laplacian_spmv_edges
+    args = (i32, i32, jax.ShapeDtypeStruct((256,), jnp.float32),
+            jax.ShapeDtypeStruct((1 << 16, 64), jnp.float32))
+    fn = ops.laplacian_spmv_edges
     with pytest.raises(ValueError, match=fn.__name__):
         jax.eval_shape(lambda *a: fn(*a, interpret=False), *args)

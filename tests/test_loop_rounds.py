@@ -53,16 +53,19 @@ def test_rec_rounds_stay_within_the_offtree_blocks(budget):
 
 
 def test_every_loop_counted_and_tree_only_under_levels():
+    """Every loop of the program counts its rounds; the tree is rooted
+    by its Euler tour, with no loop, so its slot stays 0. The graph
+    BFS runs no more rounds than a level-synchronous one: one a level,
+    and one to find none left."""
+    from repro.core import _host as H
+
     g = _grids(1)[0]
-    dbl = lgrass_sparsify(g).loop_rounds
-    lev = lgrass_sparsify(g, bfs_engine="levels").loop_rounds
-    assert list(dbl) == list(LOOPS) == list(lev)
-    assert dbl["tree"] == 0 and lev["tree"] > 0
-    assert all(dbl[k] > 0 for k in LOOPS if k != "tree")
-    # level-sync BFS: one round per level, and one to find none left
-    assert lev["bfs"] >= dbl["bfs"]
-    assert {k: lev[k] for k in ("mst", "mst_jump", "mark", "rec")} == \
-        {k: dbl[k] for k in ("mst", "mst_jump", "mark", "rec")}
+    rounds = lgrass_sparsify(g).loop_rounds
+    assert list(rounds) == list(LOOPS)
+    assert rounds["tree"] == 0
+    assert all(rounds[k] > 0 for k in LOOPS if k != "tree")
+    depth, _ = H.bfs_np(g.u, g.v, g.n, H.select_root_np(g.u, g.v, g.n))
+    assert rounds["bfs"] <= int(depth.max()) + 1
 
 
 def test_host_recovery_reports_phase1_loops():

@@ -1,12 +1,11 @@
-"""Chunked phase-1 marking: every schedule must be BIT-IDENTICAL.
+"""Chunked phase-1 marking: every block size must be BIT-IDENTICAL.
 
-The contract: `phase1_chunked` (any block size), the legacy scan
-schedules (`phase1_basic`, `phase1_parallel`), the numpy oracle
+The contract: `phase1_chunked` at any block size, the numpy oracle
 (`_host.phase1_np`) and the batched vmapped path all produce the same
 per-slot accept decisions and per-group overflow flags — across graph
-families (feeder included), chunk sizes {1, 3, C > L, pow2}, the
-k_cap=1 overflow regime, and the Euler-LCA / Pallas-kernel distance
-backends. Plus the degenerate-layout regressions: L == 0 and
+families (feeder included), chunk sizes {1, 3, C > L, pow2} and the
+k_cap=1 overflow regime — and the whole pipeline lands on the baseline
+greedy. Plus the degenerate-layout regressions: L == 0 and
 zero-crossing inputs must flow through marking AND recovery without
 NaN/garbage.
 
@@ -45,13 +44,10 @@ def _oracle(d, k_cap=32):
 
 
 def _assert_schedules_agree(g, k_cap=32):
-    """scan(basic) == scan(lockstep) == chunked(all sizes) == oracle."""
-    ref = _phase1(g, k_cap=k_cap, schedule="scan", parallel=False)
-    par = _phase1(g, k_cap=k_cap, schedule="scan", parallel=True)
-    assert np.array_equal(ref["accept_sorted"], par["accept_sorted"])
-    assert np.array_equal(ref["group_overflow"], par["group_overflow"])
+    """Every block size (and the default) agrees, and with the oracle."""
+    ref = _phase1(g, k_cap=k_cap)
     for c in CHUNKS:
-        chk = _phase1(g, k_cap=k_cap, schedule="chunked", p1_chunk=c)
+        chk = _phase1(g, k_cap=k_cap, p1_chunk=c)
         assert np.array_equal(ref["accept_sorted"], chk["accept_sorted"]), c
         assert np.array_equal(ref["group_overflow"],
                               chk["group_overflow"]), c
@@ -102,24 +98,24 @@ def test_chunked_e2e_matches_baseline(p1_chunk):
     schedule must still land exactly on the baseline greedy."""
     g = random_connected_graph(45, 90, seed=1, weight="ties")
     base = baseline_sparsify(g, budget=8)
-    dev = lgrass_sparsify(g, budget=8, schedule="chunked",
-                          p1_chunk=p1_chunk)
+    dev = lgrass_sparsify(g, budget=8, p1_chunk=p1_chunk)
     assert np.array_equal(dev.edge_mask, base.edge_mask)
-    host = lgrass_sparsify(g, budget=8, schedule="chunked",
-                           p1_chunk=p1_chunk, recovery="host")
+    host = lgrass_sparsify(g, budget=8, p1_chunk=p1_chunk,
+                           recovery="host")
     assert np.array_equal(dev.edge_mask, host.edge_mask)
 
 
 def test_chunked_batched_matches_scan_batched():
-    """The vmapped batched path: chunked == scan == baseline per graph."""
+    """The vmapped batched path: the default blocks == one-slot blocks
+    (the per-slot scan order) == baseline per graph."""
     graphs = [
         random_connected_graph(30, 60, seed=0, weight="lognormal"),
         powergrid_like_graph(6, 0.4, seed=3),
         feeder_like_graph(64, 32, span=5, seed=1),
         random_connected_graph(45, 110, seed=1, weight="ties"),
     ]
-    chk = lgrass_sparsify_batch(graphs, budget=6, schedule="chunked")
-    scn = lgrass_sparsify_batch(graphs, budget=6, schedule="scan")
+    chk = lgrass_sparsify_batch(graphs, budget=6)
+    scn = lgrass_sparsify_batch(graphs, budget=6, p1_chunk=1)
     for g, a, b in zip(graphs, chk, scn):
         assert np.array_equal(a.edge_mask, b.edge_mask)
         assert np.array_equal(
@@ -141,11 +137,10 @@ def test_chunked_batched_phase1_views_match_single():
     d = phase1_device_batched(
         jnp.asarray(batch.u, jnp.int32), jnp.asarray(batch.v, jnp.int32),
         jnp.asarray(batch.w, jnp.float32),
-        jnp.asarray(batch.edge_valid, bool), batch.n_max,
-        schedule="chunked", p1_chunk=8)
+        jnp.asarray(batch.edge_valid, bool), batch.n_max, p1_chunk=8)
     d = {k: np.asarray(val) for k, val in d.items()}
     for i, g in enumerate(graphs):
-        single = _phase1(g, schedule="chunked", p1_chunk=8)
+        single = _phase1(g, p1_chunk=8)
         # sorted-slot outputs: real slots lead (padding sorts last)
         assert np.array_equal(d["accept_sorted"][i][: g.m],
                               single["accept_sorted"])
@@ -153,39 +148,25 @@ def test_chunked_batched_phase1_views_match_single():
 
 
 def test_chunked_euler_lca_backend_parity():
-    """The Euler-tour O(1)-LCA distance backend (the default) must be
-    bit-identical to the binary-lifting climbs inside the chunked cover
-    tables — use_euler_lca=False pins the lifting side explicitly since
-    the default is True."""
+    """The Euler-tour O(1)-LCA distance tables behind the cover tables
+    land on the baseline greedy, random and feeder graphs alike."""
     for seed in (0, 4):
         g = random_connected_graph(36, 80, seed=seed)
-        lift = lgrass_sparsify(g, budget=7, schedule="chunked",
-                               use_euler_lca=False)
-        eul = lgrass_sparsify(g, budget=7, schedule="chunked",
-                              use_euler_lca=True)
-        assert np.array_equal(lift.edge_mask, eul.edge_mask)
+        assert np.array_equal(lgrass_sparsify(g, budget=7).edge_mask,
+                              baseline_sparsify(g, budget=7).edge_mask)
     g = feeder_like_graph(96, 48, span=6, seed=1)
-    assert np.array_equal(
-        lgrass_sparsify(g, budget=6, schedule="chunked",
-                        use_euler_lca=False).edge_mask,
-        lgrass_sparsify(g, budget=6, schedule="chunked",
-                        use_euler_lca=True).edge_mask)
+    assert np.array_equal(lgrass_sparsify(g, budget=6).edge_mask,
+                          baseline_sparsify(g, budget=6).edge_mask)
 
 
 def test_chunked_tree_kernel_backend_parity():
-    """Pallas tree-distance kernel (interpret mode on CPU) backing the
-    chunked cover tables: bit-identical through the fused program."""
+    """The cover tables' distances through the fused program, host and
+    device recovery alike, equal the baseline greedy's."""
     g = random_connected_graph(24, 40, seed=5)
-    ref = lgrass_sparsify(g, budget=5, schedule="chunked")
-    ker = lgrass_sparsify(g, budget=5, schedule="chunked",
-                          use_tree_kernel=True)
-    assert np.array_equal(ref.edge_mask, ker.edge_mask)
-
-
-def test_unknown_schedule_raises():
-    g = random_connected_graph(20, 30, seed=0)
-    with pytest.raises(ValueError):
-        lgrass_sparsify(g, budget=3, schedule="lockstep")
+    base = baseline_sparsify(g, budget=5).edge_mask
+    for recovery in ("device", "host"):
+        assert np.array_equal(
+            lgrass_sparsify(g, budget=5, recovery=recovery).edge_mask, base)
 
 
 # --- degenerate GroupLayout regressions (L == 0 / zero crossing) --------
@@ -204,39 +185,44 @@ def _chain_noncrossing():
     return Graph(n=6, u=u, v=v, w=np.ones(7, np.float32))
 
 
-@pytest.mark.parametrize("schedule", ["chunked", "scan"])
-def test_degenerate_star_all_tree(schedule):
+# the default blocks, and one-slot blocks (the per-slot scan order)
+P1_CHUNKS = [pytest.param(None, id="chunked"),
+             pytest.param(1, id="p1_chunk=1")]
+
+
+@pytest.mark.parametrize("p1_chunk", P1_CHUNKS)
+def test_degenerate_star_all_tree(p1_chunk):
     """Every edge is a tree edge: no crossing groups, nothing accepted,
     and the final mask is exactly the tree."""
     g = _star_graph()
     base = baseline_sparsify(g, budget=2)
-    r = lgrass_sparsify(g, budget=2, schedule=schedule)
+    r = lgrass_sparsify(g, budget=2, p1_chunk=p1_chunk)
     assert np.array_equal(r.edge_mask, base.edge_mask)
     assert r.edge_mask.all() and r.n_accepted == 0
     assert r.n_overflow_groups == 0 and r.n_dirty == 0
 
 
-@pytest.mark.parametrize("schedule", ["chunked", "scan"])
-def test_degenerate_all_noncrossing(schedule):
+@pytest.mark.parametrize("p1_chunk", P1_CHUNKS)
+def test_degenerate_all_noncrossing(p1_chunk):
     """Zero crossing edges: the whole layout is the inactive tail group;
     recovery alone must decide the chords, matching the baseline."""
     g = _chain_noncrossing()
     base = baseline_sparsify(g, budget=2)
     for recovery in ("device", "host"):
-        r = lgrass_sparsify(g, budget=2, schedule=schedule,
+        r = lgrass_sparsify(g, budget=2, p1_chunk=p1_chunk,
                             recovery=recovery)
         assert np.array_equal(r.edge_mask, base.edge_mask)
 
 
-@pytest.mark.parametrize("schedule", ["chunked", "scan"])
-def test_degenerate_zero_edges(schedule):
+@pytest.mark.parametrize("p1_chunk", P1_CHUNKS)
+def test_degenerate_zero_edges(p1_chunk):
     """L == 0 (isolated node): the empty-layout branch must flow through
     marking AND recovery — this used to raise IndexError in
     build_group_layout (`.at[0]` on an empty array)."""
     g = Graph(n=1, u=np.zeros(0, np.int32), v=np.zeros(0, np.int32),
               w=np.zeros(0, np.float32))
     for recovery in ("device", "host"):
-        r = lgrass_sparsify(g, budget=1, schedule=schedule,
+        r = lgrass_sparsify(g, budget=1, p1_chunk=p1_chunk,
                             recovery=recovery)
         assert r.edge_mask.shape == (0,)
         assert r.n_accepted == 0 and r.n_groups == 0
@@ -249,7 +235,7 @@ def test_degenerate_no_garbage_reaches_recovery():
     from repro.core.sparsify import phase1_views_np
 
     for g in (_star_graph(), _chain_noncrossing()):
-        d = _phase1(g, schedule="chunked")
+        d = _phase1(g)
         tree, crossing, accept, group, dirty0, order = phase1_views_np(
             d, g.m)
         offtree = ~tree

@@ -60,10 +60,12 @@ def test_device_recovery_parity_sweep(seed, weight, budget):
     _assert_triple(g, budget)
 
 
-@pytest.mark.parametrize("parallel", [True, False])
-def test_device_recovery_both_schedules(parallel):
+@pytest.mark.parametrize("k_cap", [1, 32])
+def test_device_recovery_both_schedules(k_cap):
+    """The default accept tables and one slot a group, where groups
+    overflow and recovery replays their dirty edges."""
     g = random_connected_graph(45, 90, seed=1, weight="ties")
-    _assert_triple(g, 8, parallel=parallel)
+    _assert_triple(g, 8, k_cap=k_cap)
 
 
 def test_device_recovery_powergrid():
@@ -123,9 +125,8 @@ def test_device_recovery_batched_matches_host_tail():
 
 def test_recover_device_standalone_from_phase1():
     """Drive `recover_device` directly from phase-1 outputs (the unit
-    bench_recovery.py times) and compare against the host oracle — on
-    both distance backends: the default Euler path (tables rebuilt on
-    device from up[0]) and the legacy lifting climbs."""
+    bench_recovery.py times; its Euler tables rebuilt on device from
+    up[0]) and compare against the host oracle."""
     g = random_connected_graph(36, 80, seed=7)
     budget = 7
     u = jnp.asarray(g.u, jnp.int32)
@@ -137,25 +138,23 @@ def test_recover_device_standalone_from_phase1():
         d, g.m)
     want = lgrass_sparsify(g, budget=budget, recovery="host").accepted_mask
 
-    for use_euler in (True, False):
-        got, n_acc = recover_device(
-            jnp.asarray(d["up"]), jnp.asarray(d["depth_t"]), u, v,
-            jnp.asarray(d["beta"]), jnp.asarray(tree),
-            jnp.asarray(crossing),
-            jnp.asarray(full_order.astype(np.int32)), jnp.asarray(accept),
-            jnp.asarray(group.astype(np.int32)), jnp.asarray(dirty0),
-            jnp.int32(budget), b_cap=8, use_euler_lca=use_euler,
-        )
-        assert np.array_equal(np.asarray(got), want), use_euler
-        assert int(n_acc) == int(want.sum())
+    got, n_acc = recover_device(
+        jnp.asarray(d["up"]), jnp.asarray(d["depth_t"]), u, v,
+        jnp.asarray(d["beta"]), jnp.asarray(tree),
+        jnp.asarray(crossing),
+        jnp.asarray(full_order.astype(np.int32)), jnp.asarray(accept),
+        jnp.asarray(group.astype(np.int32)), jnp.asarray(dirty0),
+        jnp.int32(budget), b_cap=8,
+    )
+    assert np.array_equal(np.asarray(got), want)
+    assert int(n_acc) == int(want.sum())
 
 
 def test_recover_device_batched_standalone_euler_parity():
     """`recover_device_batched` driven from batched phase-1 outputs:
-    each lane rebuilds its own Euler tables from up[0] (the ROADMAP
-    'standalone recovery still climbs the lifting tables' fix) and must
-    agree with the per-graph host oracle on every lane — padded shapes
-    and all — and with the lifting backend bit for bit."""
+    each lane rebuilds its own Euler tables from up[0] and must agree
+    with the per-graph host oracle on every lane — padded shapes and
+    all."""
     graphs = [
         feeder_like_graph(80, 40, span=6, seed=11),
         random_connected_graph(45, 110, seed=12, weight="ties"),
@@ -182,27 +181,22 @@ def test_recover_device_batched_standalone_euler_parity():
         tree[i], crossing[i], accept[i] = t_, c_, a_
         group[i], dirty0[i], order[i] = g_, dd_, o_.astype(np.int32)
 
-    outs = {}
-    for use_euler in (True, False):
-        got, cnt = recover_device_batched(
-            jnp.asarray(d["up"]), jnp.asarray(d["depth_t"]),
-            jnp.asarray(batch.u, jnp.int32),
-            jnp.asarray(batch.v, jnp.int32),
-            jnp.asarray(d["beta"]), jnp.asarray(tree),
-            jnp.asarray(crossing), jnp.asarray(order),
-            jnp.asarray(accept), jnp.asarray(group), jnp.asarray(dirty0),
-            jnp.asarray(np.asarray(budgets, np.int32)), b_cap=16,
-            edge_valid=jnp.asarray(batch.edge_valid),
-            use_euler_lca=use_euler,
-        )
-        outs[use_euler] = (np.asarray(got), np.asarray(cnt))
-    assert np.array_equal(outs[True][0], outs[False][0])
-    assert np.array_equal(outs[True][1], outs[False][1])
+    got, cnt = recover_device_batched(
+        jnp.asarray(d["up"]), jnp.asarray(d["depth_t"]),
+        jnp.asarray(batch.u, jnp.int32),
+        jnp.asarray(batch.v, jnp.int32),
+        jnp.asarray(d["beta"]), jnp.asarray(tree),
+        jnp.asarray(crossing), jnp.asarray(order),
+        jnp.asarray(accept), jnp.asarray(group), jnp.asarray(dirty0),
+        jnp.asarray(np.asarray(budgets, np.int32)), b_cap=16,
+        edge_valid=jnp.asarray(batch.edge_valid),
+    )
+    got, cnt = np.asarray(got), np.asarray(cnt)
     for i, (g, b) in enumerate(zip(graphs, budgets)):
         want = lgrass_sparsify(g, budget=b, recovery="host").accepted_mask
-        assert np.array_equal(outs[True][0][i][: g.m], want), i
-        assert int(outs[True][1][i]) == int(want.sum())
-        assert not outs[True][0][i][g.m:].any()  # padding never accepted
+        assert np.array_equal(got[i][: g.m], want), i
+        assert int(cnt[i]) == int(want.sum())
+        assert not got[i][g.m:].any()  # padding never accepted
 
 
 def test_feeder_like_graph_clamps_unreachable_chords():
@@ -236,13 +230,9 @@ def test_recover_device_budget_clamped_to_b_cap():
 
 
 def test_device_recovery_tree_kernel_parity():
-    """The Pallas tree-distance kernel path (interpret mode on CPU) is
-    bit-identical inside the fused program."""
-    g = random_connected_graph(24, 40, seed=5)
-    host = lgrass_sparsify(g, budget=5, recovery="host")
-    dev = lgrass_sparsify(g, budget=5, recovery="device",
-                          use_tree_kernel=True)
-    assert np.array_equal(host.edge_mask, dev.edge_mask)
+    """The fused program's cover-table distances on a small random
+    graph: device and host recovery equal the baseline greedy."""
+    _assert_triple(random_connected_graph(24, 40, seed=5), 5)
 
 
 # --- cover tables only as wide as the filled accept buffer -------------

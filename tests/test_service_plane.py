@@ -40,7 +40,7 @@ def _mixed_graphs():
 
 def _reference(graphs, budgets):
     return [
-        lgrass_sparsify(g, budget=b, parallel=False) if g.m else None
+        lgrass_sparsify(g, budget=b) if g.m else None
         for g, b in zip(graphs, budgets)
     ]
 
@@ -71,7 +71,7 @@ def test_service_mode_parity(mode):
     graphs, budgets = _mixed_graphs()
     ref = _reference(graphs, budgets)
     svc = SparsifyService(
-        parallel=False, max_batch_size=3,
+        max_batch_size=3,
         async_dispatch=(mode != "sync"),
         donate=(mode == "async_donate"),
     )
@@ -89,7 +89,7 @@ def test_service_sharded_parity(mode):
     graphs, budgets = _mixed_graphs()
     ref = _reference(graphs, budgets)
     svc = SparsifyService(
-        parallel=False, max_batch_size=4, mesh=batch_mesh(),
+        max_batch_size=4, mesh=batch_mesh(),
         async_dispatch=(mode != "sync"), donate=(mode == "async_donate"),
     )
     for _ in range(2):
@@ -103,7 +103,7 @@ def test_service_sharded_pad_batch_mesh_multiple():
     size so every shard gets equal rows."""
     mesh = batch_mesh()
     ms = mesh_size(mesh)
-    svc = SparsifyService(parallel=False, mesh=mesh)
+    svc = SparsifyService(mesh=mesh)
     for n_chunk in (1, 2, ms - 1, ms, ms + 1, 3 * ms):
         B = svc._pad_batch(n_chunk)
         assert B >= n_chunk and B % ms == 0, (n_chunk, B)
@@ -114,7 +114,7 @@ def test_service_single_device_mesh_path():
     results identical, pad target unchanged (pow2)."""
     graphs, budgets = _mixed_graphs()
     ref = _reference(graphs, budgets)
-    svc = SparsifyService(parallel=False, mesh=batch_mesh(1),
+    svc = SparsifyService(mesh=batch_mesh(1),
                           async_dispatch=True)
     results = svc.sparsify(graphs, budget=budgets)
     _assert_request_order_parity(graphs, budgets, results, ref)
@@ -132,12 +132,11 @@ def test_host_recovery_rejects_serving_modes():
         SparsifyService(recovery="nope")
     # plain host mode still serves
     g = random_connected_graph(20, 30, seed=3)
-    svc = SparsifyService(parallel=False, recovery="host")
+    svc = SparsifyService(recovery="host")
     [r] = svc.sparsify([g], budget=4)
     assert np.array_equal(
         r.edge_mask,
-        lgrass_sparsify(g, budget=4, parallel=False,
-                        recovery="host").edge_mask,
+        lgrass_sparsify(g, budget=4, recovery="host").edge_mask,
     )
 
 
@@ -150,11 +149,11 @@ def test_trivial_graph_direct_and_batched():
     import dataclasses
     g5 = dataclasses.replace(trivial_graph(), n=5)  # isolated nodes
     for g in (g1, g5):
-        r = lgrass_sparsify(g, parallel=False)
+        r = lgrass_sparsify(g)
         assert r.edge_mask.shape == (0,) and r.n_accepted == 0
     batch = GraphBatch.from_graphs([g1, g5])
     assert batch.L_max == 0
-    for r in lgrass_sparsify_batch(batch, parallel=False):
+    for r in lgrass_sparsify_batch(batch):
         assert r.edge_mask.shape == (0,) and r.n_accepted == 0
 
 
@@ -165,11 +164,11 @@ def test_trivial_graph_service_regression():
     whose placeholder must be the (n=1, m=0) trivial graph (the old
     (n=2, m=1) filler crashed min_n_bucket=1 buckets with
     'bucket too small')."""
-    svc = SparsifyService(parallel=False)
+    svc = SparsifyService()
     assert svc.sparsify([]) == []
 
     g = random_connected_graph(20, 30, seed=1)
-    ref = lgrass_sparsify(g, budget=5, parallel=False)
+    ref = lgrass_sparsify(g, budget=5)
     results = svc.sparsify([trivial_graph(), g, trivial_graph()],
                            budget=[None, 5, None])
     assert results[0].edge_mask.shape == (0,)
@@ -178,8 +177,7 @@ def test_trivial_graph_service_regression():
 
     # the placeholder-fill regression: 3 trivial graphs in a (1, 1)
     # bucket force a placeholder row into the smallest possible bucket
-    svc_min = SparsifyService(parallel=False, min_n_bucket=1,
-                              min_L_bucket=1)
+    svc_min = SparsifyService(min_n_bucket=1, min_L_bucket=1)
     out = svc_min.sparsify([trivial_graph()] * 3)
     assert [r.edge_mask.shape for r in out] == [(0,)] * 3
     # warmup accepts trivial sizes too
@@ -198,7 +196,7 @@ def test_padding_overhead_split_pinned():
     graphs = [random_connected_graph(20, 30, seed=s) for s in range(3)]
     graphs.append(random_connected_graph(40, 70, seed=9))
     assert [g.m for g in graphs] == [49, 49, 49, 109]
-    svc = SparsifyService(parallel=False)
+    svc = SparsifyService()
     svc.sparsify(graphs, budget=4)
     s = svc.stats
     assert s.n_dispatches == 2
@@ -229,7 +227,7 @@ def test_on_path_compile_accounting():
     compiles; a request whose explicit budget exceeds the bucket default
     widens b_cap into a program warmup never compiled => counted ONCE."""
     graphs = [random_connected_graph(20, 30, seed=s) for s in range(3)]
-    svc = SparsifyService(parallel=False)
+    svc = SparsifyService()
     svc.warmup([(graphs[0].n, graphs[0].m)],   # B_pad 4, default b_cap
                batch_sizes=(3,))
     res = svc.sparsify(graphs)                 # one chunk of 3 -> B=4
@@ -243,7 +241,7 @@ def test_on_path_compile_accounting():
     assert svc.stats.n_on_path_compiles == 1
 
     # warming the wide-budget program up front keeps the path clean
-    svc2 = SparsifyService(parallel=False)
+    svc2 = SparsifyService()
     svc2.warmup([(graphs[0].n, graphs[0].m)], batch_sizes=(1, 3),
                 budgets=[30])
     svc2.sparsify(graphs, budget=30)
@@ -259,7 +257,7 @@ def test_warmup_warms_the_traffic_program_variant():
                                      lgrass_device_batched_donated)
 
     g = random_connected_graph(20, 30, seed=3)
-    svc = SparsifyService(parallel=False, async_dispatch=True, donate=True)
+    svc = SparsifyService(async_dispatch=True, donate=True)
     before_plain = lgrass_device_batched._cache_size()
     before_don = lgrass_device_batched_donated._cache_size()
     svc.warmup([(g.n, g.m)])
@@ -269,7 +267,7 @@ def test_warmup_warms_the_traffic_program_variant():
     assert lgrass_device_batched_donated._cache_size() == before_don + 1
     assert svc.stats.n_on_path_compiles == 0
     assert np.array_equal(
-        r.edge_mask, lgrass_sparsify(g, parallel=False).edge_mask)
+        r.edge_mask, lgrass_sparsify(g).edge_mask)
 
 
 # ------------------------------------------------------- staging pool
@@ -280,8 +278,8 @@ def test_staging_pool_steady_state_no_growth():
     state), and results stay exact throughout."""
     graphs, budgets = _mixed_graphs()
     ref = _reference(graphs, budgets)
-    svc = SparsifyService(parallel=False, max_batch_size=3,
-                          async_dispatch=True, donate=True)
+    svc = SparsifyService(max_batch_size=3, async_dispatch=True,
+                          donate=True)
     _assert_request_order_parity(
         graphs, budgets, svc.sparsify(graphs, budget=budgets), ref)
     sets_after_first = svc._pool.n_buffer_sets
@@ -297,11 +295,10 @@ def test_async_budget_isolation_across_chunks():
     refill must not leak into the earlier in-flight dispatch."""
     graphs = [random_connected_graph(20, 30, seed=s) for s in range(6)]
     budgets = [2, 3, 4, 5, 6, 7]
-    svc = SparsifyService(parallel=False, max_batch_size=2,
-                          async_dispatch=True)
+    svc = SparsifyService(max_batch_size=2, async_dispatch=True)
     for _ in range(2):
         results = svc.sparsify(graphs, budget=budgets)
         for g, b, r in zip(graphs, budgets, results):
-            single = lgrass_sparsify(g, budget=b, parallel=False)
+            single = lgrass_sparsify(g, budget=b)
             assert np.array_equal(r.edge_mask, single.edge_mask), b
             assert r.n_accepted == single.n_accepted, b

@@ -168,7 +168,7 @@ spec_hlo = spec.fn.lower(*spec.args, **spec.static_kwargs).compile().as_text()
 @case("contract")
 def _():
     got = collectives(spec_hlo)
-    assert len(got) == EXPECTED_WHILE[("lgrass", "doubling")], got
+    assert len(got) == EXPECTED_WHILE["lgrass"], got
     for opcode, rtype, op_name in got:
         assert (opcode, rtype) == ("all-reduce", "pred[]"), got
         assert op_name.endswith("/while"), got

@@ -15,10 +15,10 @@ from repro.core import (baseline_sparsify, lgrass_sparsify,
 def test_lgrass_equals_baseline(seed, weight):
     g = random_connected_graph(45, 90, seed=seed, weight=weight)
     b = baseline_sparsify(g, budget=8)
-    for parallel in (True, False):
-        r = lgrass_sparsify(g, budget=8, parallel=parallel)
+    for recovery in ("device", "host"):
+        r = lgrass_sparsify(g, budget=8, recovery=recovery)
         assert np.array_equal(b.edge_mask, r.edge_mask), (
-            f"seed={seed} weight={weight} parallel={parallel}")
+            f"seed={seed} weight={weight} recovery={recovery}")
 
 
 def test_lgrass_overflow_recovery_exact():

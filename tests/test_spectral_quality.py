@@ -116,41 +116,26 @@ def test_effective_resistance_rayleigh_monotone():
 
 
 def test_quality_identical_across_schedules():
-    """Every engine configuration is bit-identical, so its spectral
-    quality must be exactly equal — a cheap guard that an
-    engine-specific bug cannot pass the parity tier by breaking both
-    sides equally. The matrix covers the marking schedule, both BFS
-    engines, the Euler-tour vs lifting LCA, and the batched dispatch
-    (each graph's lane vs its own single-graph run)."""
+    """Every block size and recovery tail is bit-identical, so its
+    spectral quality must be exactly equal — a cheap guard that a
+    schedule-specific bug cannot pass the parity tier by breaking both
+    sides equally. The matrix covers the MARK block size (one-slot
+    blocks, 4, the default), the host and device recovery tails, and
+    the batched dispatch (each graph's lane vs its own single-graph
+    run)."""
     from repro.core import lgrass_sparsify_batch
 
     gs = [random_connected_graph(30, 70, seed=11),
           feeder_like_graph(32, 16, span=6, seed=11)]
     for g in gs:
-        ref = lgrass_sparsify(g, budget=8, schedule="scan",
-                              parallel=False).edge_mask
+        ref = lgrass_sparsify(g, budget=8, p1_chunk=1).edge_mask
         q_ref = _quality(g, ref)
-        for bfs_engine in ("doubling", "levels"):
-            for use_euler_lca in (True, False):
-                m = lgrass_sparsify(
-                    g, budget=8, schedule="chunked", p1_chunk=4,
-                    bfs_engine=bfs_engine,
-                    use_euler_lca=use_euler_lca).edge_mask
-                cfg = (bfs_engine, use_euler_lca)
-                assert np.array_equal(ref, m), cfg
-                assert q_ref == _quality(g, m), cfg
+        for cfg in (dict(p1_chunk=4), dict(), dict(recovery="host")):
+            m = lgrass_sparsify(g, budget=8, **cfg).edge_mask
+            assert np.array_equal(ref, m), cfg
+            assert q_ref == _quality(g, m), cfg
     # batched: one vmapped dispatch, every lane == its single-graph run
-    for bfs_engine in ("doubling", "levels"):
-        for use_euler_lca in (True, False):
-            batched = lgrass_sparsify_batch(
-                gs, budget=8, bfs_engine=bfs_engine,
-                use_euler_lca=use_euler_lca)
-            for g, res in zip(gs, batched):
-                single = lgrass_sparsify(
-                    g, budget=8, bfs_engine=bfs_engine,
-                    use_euler_lca=use_euler_lca)
-                cfg = (bfs_engine, use_euler_lca)
-                assert np.array_equal(res.edge_mask,
-                                      single.edge_mask), cfg
-                assert _quality(g, res.edge_mask) == _quality(
-                    g, single.edge_mask), cfg
+    for g, res in zip(gs, lgrass_sparsify_batch(gs, budget=8)):
+        single = lgrass_sparsify(g, budget=8)
+        assert np.array_equal(res.edge_mask, single.edge_mask)
+        assert _quality(g, res.edge_mask) == _quality(g, single.edge_mask)

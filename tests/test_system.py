@@ -27,8 +27,8 @@ def test_end_to_end_case1_reduced():
 
 def test_larger_graph_runs_and_is_consistent():
     g = random_connected_graph(400, 1200, seed=21)
-    r1 = lgrass_sparsify(g, budget=30, parallel=True)
-    r2 = lgrass_sparsify(g, budget=30, parallel=False)
+    r1 = lgrass_sparsify(g, budget=30)
+    r2 = lgrass_sparsify(g, budget=30, recovery="host")
     assert np.array_equal(r1.edge_mask, r2.edge_mask)
     assert r1.n_accepted <= 30
 

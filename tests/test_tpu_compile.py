@@ -13,7 +13,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.graph import OFFICIAL_CASE_SHAPES, powergrid_shape
-from repro.core.pow2 import log2_ceil
 
 
 @pytest.fixture(scope="module")
@@ -60,17 +59,6 @@ def _assert_mosaic(compiled):
     assert compiled.memory_analysis() is not None
 
 
-def test_tree_dist_compiles_at_regime_top(one_chip, compile_quietly):
-    from repro.kernels import ops
-
-    n, m = 32768, 8192
-    s = lambda shape: _spec(one_chip, shape, jnp.int32)  # noqa: E731
-    fn = jax.jit(lambda up, d, a, b: ops.tree_dist_pairs(
-        up, d, a, b, interpret=False))
-    _assert_mosaic(fn.lower(s((log2_ceil(n + 1), n)), s((n,)), s((m,)),
-                            s((m,))).compile())
-
-
 def test_spmv_compiles_at_regime_top(one_chip, compile_quietly):
     from repro.kernels import ops
 
@@ -83,12 +71,13 @@ def test_spmv_compiles_at_regime_top(one_chip, compile_quietly):
         _spec(one_chip, (n, p), jnp.float32)).compile())
 
 
-@pytest.mark.parametrize("use_tree_kernel", [False, True])
+@pytest.mark.parametrize("pallas", [False])
 def test_lgrass_device_compiles_at_case1(one_chip, compile_quietly,
-                                         monkeypatch, use_tree_kernel):
+                                         monkeypatch, pallas):
     """The fused program as the chip runs it: the backend picks the radix
     sort and compiled kernels there, so steer both here, where the
-    backend is the CPU."""
+    backend is the CPU. No Pallas kernel is on the sparsifier's path,
+    so the compiled program holds no Mosaic call."""
     import repro.core.sort as sort
     import repro.kernels.ops as ops
     from repro.core.baseline import default_budget
@@ -103,8 +92,7 @@ def test_lgrass_device_compiles_at_case1(one_chip, compile_quietly,
     compiled = lgrass_device.lower(
         _spec(one_chip, (m,), jnp.int32), _spec(one_chip, (m,), jnp.int32),
         _spec(one_chip, (m,), jnp.float32), _spec(one_chip, (), jnp.int32),
-        n=n, b_cap=_bucket_b_cap([default_budget(n)]),
-        use_tree_kernel=use_tree_kernel).compile()
+        n=n, b_cap=_bucket_b_cap([default_budget(n)])).compile()
     jax.clear_caches()
-    assert ("tpu_custom_call" in compiled.as_text()) == use_tree_kernel
+    assert ("tpu_custom_call" in compiled.as_text()) == pallas
     assert compiled.memory_analysis().temp_size_in_bytes > 0
